@@ -88,9 +88,8 @@ def _emit() -> None:
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from jax.sharding import PartitionSpec as P
+    from jax.sharding import AxisType, PartitionSpec as P
 
-    from repro import compat
     from repro.core import (baselines, gossip, gradient_push,
                             method as method_mod, plane as plane_mod,
                             sdm_dsgd, topology)
@@ -125,7 +124,8 @@ def _emit() -> None:
         stack = jax.tree.map(
             lambda v: jnp.broadcast_to(v[None], (n,) + v.shape), p0)
 
-        mesh = compat.make_mesh((n,), ("data",))
+        mesh = jax.make_mesh((n,), ("data",),
+                             axis_types=(AxisType.Auto,))
         ex = meth.make_distributed(seq, cfg, "data")
         key = jax.random.PRNGKey(0)
 
@@ -150,9 +150,9 @@ def _emit() -> None:
                 state, _ = jax.lax.scan(body, state, None, length=2)
                 return jax.tree.map(lambda v: v[None], state.x)
 
-            return compat.shard_map(inner, mesh=mesh, in_specs=(P("data"),),
-                                    out_specs=P("data"), axis_names={"data"},
-                                    check_vma=False)(stack)
+            return jax.shard_map(inner, mesh=mesh, in_specs=(P("data"),),
+                                 out_specs=P("data"), axis_names={"data"},
+                                 check_vma=False)(stack)
 
         compiled = jax.jit(one_step).lower(stack).compile()
         hlo = compiled.as_text()
@@ -220,7 +220,9 @@ def run(out_path: str = OUT_PATH) -> dict:
 
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    # the child counts HLO on faked CPU devices; it must never reach for a
+    # chip the parent process may already hold
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run(
         [sys.executable, "-m", "benchmarks.perf_wire", "--emit"],
         capture_output=True, text=True, env=env, timeout=1200)

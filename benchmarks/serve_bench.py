@@ -110,7 +110,7 @@ def _decode_launches(cfg, params, *, use_flash):
     def step(p, tok, pages, tables, offsets, emit):
         return transformer.decode_step_paged(
             p, cfg, tok, pages, {}, tables, offsets, emit,
-            use_flash=use_flash, interpret=True)
+            use_flash=use_flash)
 
     tok = jnp.zeros((MAX_BATCH,), jnp.int32)
     offsets = jnp.ones((MAX_BATCH,), jnp.int32)

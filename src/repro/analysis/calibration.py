@@ -212,7 +212,7 @@ class _TokenInterp(jaxpr_walk.JaxprInterpreter):
 
 def _iter_scans(jaxpr, consts):
     """Yield every (scan eqn, body jaxpr, body consts) anywhere in the
-    program (train loops live under pjit/shard_map)."""
+    program (train loops live under jit/shard_map)."""
     for eqn in jaxpr.eqns:
         name = eqn.primitive.name
         if name == "scan":

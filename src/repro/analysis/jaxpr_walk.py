@@ -1,7 +1,7 @@
 """Shared abstract-interpretation machinery over (Closed)Jaxprs.
 
 ``JaxprInterpreter`` walks a jaxpr and recurses through every call
-boundary jax emits on this toolchain — ``pjit``, ``closed_call``,
+boundary jax emits on this toolchain — ``jit``, ``closed_call``,
 ``scan`` (to carry fixpoint), ``while``, ``cond``/``switch`` branches,
 ``shard_map``, ``custom_jvp/vjp_call`` and ``remat`` — propagating one
 abstract value per jaxpr variable. Subclasses define the lattice
@@ -37,7 +37,7 @@ def format_site(eqn) -> str:
     """Best-effort user-frame 'file:line' for a finding."""
     try:
         from jax._src import source_info_util
-        frame = source_info_util.user_frame(eqn.source_info)
+        frame = source_info_util.user_frame(eqn.source_info.traceback)
         if frame is not None:
             return f"{frame.file_name}:{frame.start_line}"
     except Exception:
@@ -59,8 +59,8 @@ class Ctx:
 
 # call-like primitives with a single positionally-aligned subjaxpr
 _ALIGNED_CALLS = {
-    "pjit", "closed_call", "core_call", "xla_call", "remat2", "checkpoint",
-    "remat", "custom_jvp_call", "custom_vjp_call", "custom_vjp_call_jaxpr",
+    "jit", "closed_call", "remat2", "checkpoint", "remat",
+    "custom_jvp_call", "custom_vjp_call", "custom_vjp_call_jaxpr",
     "custom_jvp_call_jaxpr", "shard_map", "custom_partitioning",
 }
 _SUB_KEYS = ("jaxpr", "call_jaxpr", "fun_jaxpr")

@@ -36,9 +36,8 @@ from typing import List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
-from repro import compat
 from repro.analysis import calibration, jaxpr_taint, prng_lint, sensitivity
 from repro.core import (baselines, clipping, compressor as compressor_mod,
                         gossip, gradient_push, method as method_mod,
@@ -224,7 +223,7 @@ def _build(ac: AuditConfig):
     params_stack = {"w": jnp.broadcast_to(params0, (n, DIM))}
     base_key = jax.random.PRNGKey(42)
 
-    mesh = compat.make_mesh((n,), ("data",))
+    mesh = jax.make_mesh((n,), ("data",), axis_types=(AxisType.Auto,))
     ex = meth.make_distributed(seq, cfg, "data")
 
     def dist_train(params_stack, a_st, b_st):
@@ -248,11 +247,11 @@ def _build(ac: AuditConfig):
                 tagging.declared_release(losses[-1], label="loss"), "data")
             return jax.tree.map(lambda v: v[None], state.x), loss[None]
 
-        return compat.shard_map(inner, mesh=mesh,
-                                in_specs=(P("data"), P("data"), P("data")),
-                                out_specs=(P("data"), P("data")),
-                                axis_names={"data"},
-                                check_vma=False)(params_stack, a_st, b_st)
+        return jax.shard_map(inner, mesh=mesh,
+                             in_specs=(P("data"), P("data"), P("data")),
+                             out_specs=(P("data"), P("data")),
+                             axis_names={"data"},
+                             check_vma=False)(params_stack, a_st, b_st)
 
     args = (params_stack, a_stack, b_stack)
     jaxpr = jax.make_jaxpr(dist_train)(*args)

@@ -59,7 +59,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import sparsifier, tagging
+from repro.core import plane as plane_mod, sparsifier, tagging
 
 # Fused sender-side fixed-k packing (kernels/wire_compress gather+scale
 # pallas kernel) for the static scalar-p payload path. Bit-exact to the
@@ -749,6 +749,22 @@ def _batched_sender_indices(schedule: PermuteSchedule, me, *,
     return idx
 
 
+def fused_pack_applies(block: int, dtype, p) -> bool:
+    """Whether the sender-side fixed-k pack of ``block``-coordinate
+    blocks runs the fused gather+scale kernel.
+
+    The kernel DMAs whole lane-dense f32 plane rows, so ``block`` must
+    be a multiple of LANE. Element-granular fixed-k (``fixedk_packed``,
+    block 1) and other sub-lane blocks stay an XLA gather: one DMA per
+    kept coordinate would cost far more than XLA's gather. The het-p
+    path (tuple ``p``) keeps the jnp ops too: its scale is a traced
+    per-node mask, not a static scalar. The launcher prints the choice.
+    """
+    return (FUSED_PACK and not isinstance(p, tuple)
+            and block % plane_mod.LANE == 0
+            and jnp.dtype(dtype) == jnp.float32)
+
+
 def _packed_selection(db: jax.Array, p, me, *, base_key: jax.Array,
                       step: jax.Array) -> Tuple[int, jax.Array, jax.Array]:
     """Sender-side packed payload selection: (kb, my_idx, my_vals).
@@ -778,12 +794,10 @@ def _packed_selection(db: jax.Array, p, me, *, base_key: jax.Array,
         scale = nb_blocks / kb
     my_idx = sparsifier.fixedk_indices(
         node_round_key(base_key, me, step), nb_blocks, kb)
-    if FUSED_PACK and not isinstance(p, tuple) and db.ndim == 2 \
-            and db.dtype == jnp.float32:
+    if db.ndim == 2 and fused_pack_applies(db.shape[1], db.dtype, p):
         # fused sender-side pack: gather + contraction scale in ONE
         # pallas launch (bit-exact to the jnp pair below, so enabling
-        # it never changes a trajectory). The het-p path keeps the jnp
-        # ops: its scale is a traced per-node mask, not a static scalar.
+        # it never changes a trajectory)
         from repro.kernels import wire_compress   # lazy: core -> kernels
         my_vals = wire_compress.fixedk_gather_pack(db, my_idx, scale=scale)
     else:

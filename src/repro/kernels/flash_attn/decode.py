@@ -11,12 +11,13 @@ index is computed before the kernel body runs.
 grid = (batch, kv_heads, n_blocks); the innermost block dimension
 accumulates into VMEM scratch (m, l, acc) exactly like the prefill
 kernel in ``flash_attn.py``. GQA is handled by processing all ``group``
-query heads of one kv head per program. Like ``wire_compress``, the
-kernel runs in interpret mode on CPU hosts and a pure-jnp reference
-path (``paged_attention_ref``) serves odd shapes / ``use_kernel=False``;
-on a real TPU the (group, head_dim) tiles should be padded to (8, 128)
-sublane/lane multiples — the ops wrapper pads head_dim, group padding is
-left to the caller's head layout.
+query heads of one kv head per program. The page pool is head-major,
+``(n_pages, kv_heads, page_size, head_dim)``, so one program's K/V block
+is a whole ``(page_size, head_dim)`` tile of one head — the layout the
+TPU's (8, 128) sublane/lane tiling accepts (the ops wrapper pads
+head_dim to 128). Like every kernel here it runs compiled on a TPU and
+interpreted elsewhere; ``paged_attention_ref`` is the dense oracle
+(``use_kernel=False``).
 """
 from __future__ import annotations
 
@@ -27,6 +28,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 __all__ = ["paged_attention", "paged_attention_ref",
            "paged_flash_decode_pallas"]
@@ -47,9 +50,11 @@ def _kernel(tbl_ref, seq_ref, q_ref, k_ref, v_ref, o_ref,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     q = q_ref[0, 0]                      # (group, dh)
-    k = k_ref[0, :, 0, :]                # (page_size, dh)
-    v = v_ref[0, :, 0, :]
-    scores = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+    k = k_ref[0, 0]                      # (page_size, dh)
+    v = v_ref[0, 0]
+    scores = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale
     if softcap is not None:
         scores = softcap * jnp.tanh(scores / softcap)
 
@@ -85,8 +90,8 @@ def paged_flash_decode_pallas(q: jax.Array, k_pages: jax.Array,
                               seq_lens: jax.Array, *,
                               window: int | None = None,
                               softcap: float | None = None,
-                              interpret: bool = True) -> jax.Array:
-    """q: (b, kvh, group, dh); pages: (n_pages, page, kvh, dh);
+                              interpret: bool | None = None) -> jax.Array:
+    """q: (b, kvh, group, dh); pages: (n_pages, kvh, page, dh);
     block_table: (b, n_blocks) int32; seq_lens: (b,) int32 ->
     (b, kvh, group, dh).
 
@@ -95,7 +100,7 @@ def paged_flash_decode_pallas(q: jax.Array, k_pages: jax.Array,
     by the epsilon floor.
     """
     b, kvh, group, dh = q.shape
-    _, page, _, _ = k_pages.shape
+    page = k_pages.shape[2]
     n_blocks = block_table.shape[1]
     scale = 1.0 / math.sqrt(dh)
     grid = (b, kvh, n_blocks)
@@ -107,10 +112,10 @@ def paged_flash_decode_pallas(q: jax.Array, k_pages: jax.Array,
         in_specs=[
             pl.BlockSpec((1, 1, group, dh),
                          lambda bi, hi, ji, tbl, seq: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, page, 1, dh),
-                         lambda bi, hi, ji, tbl, seq: (tbl[bi, ji], 0, hi, 0)),
-            pl.BlockSpec((1, page, 1, dh),
-                         lambda bi, hi, ji, tbl, seq: (tbl[bi, ji], 0, hi, 0)),
+            pl.BlockSpec((1, 1, page, dh),
+                         lambda bi, hi, ji, tbl, seq: (tbl[bi, ji], hi, 0, 0)),
+            pl.BlockSpec((1, 1, page, dh),
+                         lambda bi, hi, ji, tbl, seq: (tbl[bi, ji], hi, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, group, dh),
                                lambda bi, hi, ji, tbl, seq: (bi, hi, 0, 0)),
@@ -123,7 +128,7 @@ def paged_flash_decode_pallas(q: jax.Array, k_pages: jax.Array,
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, group, dh), q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(block_table, seq_lens, q, k_pages, v_pages)
 
 
@@ -133,15 +138,15 @@ def paged_attention_ref(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                         softcap: float | None = None) -> jax.Array:
     """Dense oracle: gather pages through the table, masked softmax.
 
-    q: (b, h, dh) -> (b, h, dh). Materializes the (b, n_blocks*page)
-    contiguous view — the XLA fallback path on hosts where the Pallas
-    kernel only interprets.
+    q: (b, h, dh) -> (b, h, dh); pages (n_pages, kvh, page, dh).
+    Materializes the (b, n_blocks*page) contiguous view.
     """
     b, h, dh = q.shape
-    _, page, kvh, _ = k_pages.shape
+    _, kvh, page, _ = k_pages.shape
     group = h // kvh
-    k = k_pages[block_table]             # (b, nb, page, kvh, dh)
-    v = v_pages[block_table]
+    # (b, nb, kvh, page, dh) -> (b, nb*page, kvh, dh)
+    k = k_pages[block_table].transpose(0, 1, 3, 2, 4)
+    v = v_pages[block_table].transpose(0, 1, 3, 2, 4)
     nb = k.shape[1]
     k = k.reshape(b, nb * page, kvh, dh)
     v = v.reshape(b, nb * page, kvh, dh)
@@ -167,13 +172,13 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                     block_table: jax.Array, seq_lens: jax.Array, *,
                     window: int | None = None, softcap: float | None = None,
                     use_kernel: bool = True,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool | None = None) -> jax.Array:
     """GQA-aware public entry. q: (b, h, dh) single decode token per row;
-    k/v_pages: (n_pages, page, kv_heads, dh); block_table (b, n_blocks);
+    k/v_pages: (n_pages, kv_heads, page, dh); block_table (b, n_blocks);
     seq_lens (b,) valid tokens per row (incl. the current one).
     """
     b, h, dh = q.shape
-    kvh = k_pages.shape[2]
+    kvh = k_pages.shape[1]
     group = h // kvh
     if not use_kernel:
         return paged_attention_ref(q, k_pages, v_pages, block_table,

@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 __all__ = ["flash_attention_pallas"]
 
 NEG_INF = -1e30
@@ -79,7 +81,7 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
                            causal: bool = True, window: int | None = None,
                            softcap: float | None = None, block_q: int = 128,
                            block_k: int = 128,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: bool | None = None) -> jax.Array:
     """q: (bh, sq, dh); k/v: (bh, skv, dh) — heads pre-flattened into bh.
 
     sq % block_q == 0; skv is padded to block_k internally (masked).
@@ -112,5 +114,5 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q, 1), jnp.float32),   # running sum l
             pltpu.VMEM((block_q, dh), jnp.float32),  # output accumulator
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

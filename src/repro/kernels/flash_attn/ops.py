@@ -18,7 +18,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: int | None = None,
                     softcap: float | None = None, block_q: int = 128,
                     block_k: int = 128, use_kernel: bool = True,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool | None = None) -> jax.Array:
     """q: (b, sq, h, dh); k/v: (b, skv, kv_heads, dh) -> (b, sq, h, dh)."""
     b, sq, h, dh = q.shape
     skv, kvh = k.shape[1], k.shape[2]

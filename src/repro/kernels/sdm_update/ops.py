@@ -28,7 +28,7 @@ def sdm_update(x_tree: PyTree, s_tree: PyTree, nb_tree: PyTree,
                g_tree: PyTree, key: jax.Array, *, p: float, theta: float,
                gamma: float, sigma: float, clip_c: float | None,
                self_w: float, block_rows: int = DEFAULT_BLOCK_ROWS,
-               use_kernel: bool = True, interpret: bool = True
+               use_kernel: bool = True, interpret: bool | None = None
                ) -> Tuple[PyTree, PyTree, PyTree]:
     """Returns (x_new, s_new, sd) trees. ``key`` drives mask+noise bits."""
     spec = ParamPlane.for_tree(x_tree, lane=LANE, row_multiple=block_rows,

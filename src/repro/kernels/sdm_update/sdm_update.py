@@ -34,6 +34,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 __all__ = ["sdm_update_pallas", "LANE", "DEFAULT_BLOCK_ROWS"]
 
 LANE = 1024
@@ -80,7 +82,7 @@ def sdm_update_pallas(x: jax.Array, s: jax.Array, nb_sum: jax.Array,
                       gamma: float, sigma: float, clip_c: float | None,
                       self_w: float,
                       block_rows: int = DEFAULT_BLOCK_ROWS,
-                      interpret: bool = True
+                      interpret: bool | None = None
                       ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """All operands (rows, LANE) f32 / u32, rows % block_rows == 0.
 
@@ -99,5 +101,5 @@ def sdm_update_pallas(x: jax.Array, s: jax.Array, nb_sum: jax.Array,
         in_specs=[blk() for _ in range(7)],
         out_specs=[blk() for _ in range(3)],
         out_shape=out_shape,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, s, nb_sum, g, mask_bits, n1_bits, n2_bits))

@@ -4,9 +4,9 @@
 ("qsgdf"): callers draw the stochastic-rounding uniforms at the
 CANONICAL plane shape and pass the raw l2 norm; this wrapper derives the
 ``s / max(norm, eps)`` scalar exactly as the unfused compressor does and
-routes lane-aligned planes through the pallas kernel (pure-jnp oracle
-otherwise / when ``use_kernel=False``). Output is the flat u8 byte
-image, bit-identical across kernel, oracle and the unfused
+routes every wire plane through the pallas kernel (the pure-jnp oracle
+serves non-plane shapes and ``use_kernel=False``). Output is the flat
+u8 byte image, bit-identical across kernel, oracle and the unfused
 ``QSGDCompressor`` packer.
 """
 from __future__ import annotations
@@ -17,8 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from . import ref
-from .wire_compress import (LANE, fixedk_gather_pack_pallas, pack_factor,
-                            qsgd_pack_pallas)
+from .wire_compress import LANE, fixedk_gather_pack_pallas, qsgd_pack_pallas
 
 __all__ = ["qsgd_pack", "fixedk_gather_pack"]
 
@@ -26,23 +25,25 @@ __all__ = ["qsgd_pack", "fixedk_gather_pack"]
 @functools.partial(jax.jit,
                    static_argnames=("bits", "use_kernel", "interpret"))
 def qsgd_pack(xf: jax.Array, u: jax.Array, norm: jax.Array, *, bits: int,
-              use_kernel: bool = True, interpret: bool = True) -> jax.Array:
+              use_kernel: bool = True,
+              interpret: bool | None = None) -> jax.Array:
     """f32 tensor + uniforms + scalar norm -> flat packed u8 bytes."""
     xf = xf.astype(jnp.float32)
     s = float(2 ** (bits - 1) - 1)
     inv = s / jnp.maximum(norm, 1e-30)   # EXACT unfused scale arithmetic
-    plane_like = (xf.ndim == 2 and xf.shape[1] == LANE
-                  and xf.shape[0] % 1 == 0)
+    # any plane whose lane dim is a LANE multiple (the flat bucket and the
+    # tensor-parallel buckets alike) is a free row-major (-1, LANE) view
+    plane_like = xf.ndim == 2 and xf.shape[1] % LANE == 0
     if use_kernel and plane_like and bits in (2, 4, 8):
-        out = qsgd_pack_pallas(xf, u, inv.reshape(1, 1), bits=bits,
-                               interpret=interpret)
-        return out.reshape(-1)
+        return qsgd_pack_pallas(xf.reshape(-1, LANE), u.reshape(-1, LANE),
+                                inv.reshape(1, 1), bits=bits,
+                                interpret=interpret)
     return ref.qsgd_quantize_pack_ref(xf, u, inv, bits=bits)
 
 
 def fixedk_gather_pack(db: jax.Array, idx: jax.Array, *, scale: float,
                        use_kernel: bool = True,
-                       interpret: bool = True) -> jax.Array:
+                       interpret: bool | None = None) -> jax.Array:
     """Sender-side fixed-k pack: one-launch gather + unbiasedness scale."""
     if use_kernel:
         return fixedk_gather_pack_pallas(db, idx, scale=float(scale),

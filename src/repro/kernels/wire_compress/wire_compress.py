@@ -5,9 +5,9 @@ abs/scale/floor/stochastic-round/clamp/sign ~6 elementwise kernels, then
 an offset-encode + k strided shift/or packing steps, then a SEPARATE f32
 scale leaf on the wire. ``qsgd_pack_pallas`` fuses the whole
 quantize → offset-encode → sub-byte-pack chain into ONE kernel over the
-(rows, 128) wire plane, emitting the u8 byte image directly; the caller
-appends the 4 norm bytes so scale and values share a single wire buffer
-(one collective-permute per round instead of two).
+wire plane, emitting the u8 byte image directly; the caller appends the
+4 norm bytes so scale and values share a single wire buffer (one
+collective-permute per round instead of two).
 
 Two stages deliberately stay OUTSIDE the kernel:
 
@@ -19,17 +19,23 @@ Two stages deliberately stay OUTSIDE the kernel:
   accumulation would change the reduction ORDER vs XLA and break
   bit-equality. The kernel receives 1/norm pre-scaled (``inv``).
 
+The byte image is the unfused row-major flat order: byte ``b`` holds
+elements ``b*k .. b*k+k-1`` (``k = 8 // bits``), element ``j`` at bit
+``j*bits``. Gathering every k-th lane is a lane shuffle the TPU vector
+unit has no cheap form for. Plane rows ``R*k .. R*k+k-1`` fill exactly
+byte row R, so the kernel reads them as k sublane-strided slices and
+packs each with a matmul against a constant 0/2^(i*bits) matrix that
+routes lane t to byte lane ``j*LANE/k + t//k``. Every operand is a small
+integer or a power of two (sums <= 255), so the bf16 MXU product is
+exact and the image stays bit-identical to the unfused packer.
+
 ``fixedk_gather_pack_pallas`` fuses the fixed-k sender-side payload
 packing (gather kept blocks + contraction scale) into one launch — the
-``jnp.take * scale`` pair in ``gossip._packed_selection``. Bit-exact to
-the unfused ops, so trajectories are unchanged wherever it is enabled.
-
-Both kernels default to ``interpret=True`` (CPU CI); the byte image the
-pack kernel writes is lane-packed ``out[r, cb] = OR_j enc[r, cb*k+j] <<
-(j*bits)`` — exactly the unfused row-major flat byte order, asserted
-bit-for-bit in tests/test_plane.py. On real TPUs the sub-128-lane u8
-output tile and the strided lane slice are the known mosaic rough edges;
-a production port would pack ``k`` planes per grid step.
+``jnp.take * scale`` pair in ``gossip._packed_selection``. The plane
+stays in HBM; each grid step DMAs a chunk of kept rows, addressed by
+indices read from SMEM, straight into its output block and scales them
+there. Bit-exact to the unfused ops, so trajectories are unchanged
+wherever it is enabled.
 """
 from __future__ import annotations
 
@@ -37,12 +43,22 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.plane import LANE
+from repro.kernels import resolve_interpret
 
 __all__ = ["qsgd_pack_pallas", "fixedk_gather_pack_pallas", "LANE",
            "pack_factor"]
+
+# Output rows per qsgd grid step: 1 MiB f32 input blocks at k=4, and a
+# multiple of the (32, 128) u8 tile.
+QSGD_BLOCK_ROWS = 512
+# Kept rows gathered per fixed-k grid step (DMAs in flight per step).
+# A multiple of 1024, the HBM tile of a 1-D int32 array.
+GATHER_CHUNK = 1024
 
 
 def pack_factor(bits: int) -> int:
@@ -50,80 +66,137 @@ def pack_factor(bits: int) -> int:
     return 8 // bits if bits in (2, 4) else 1
 
 
-def _qsgd_kernel(x_ref, u_ref, inv_ref, out_ref, *, bits: int):
-    s = float(2 ** (bits - 1) - 1)
-    xf = x_ref[...]
-    # the EXACT unfused arithmetic (compressor.QSGDCompressor.compress):
-    # floor + stochastic carry + clamp + sign, fused into one pass.
-    ratio = jnp.abs(xf) * inv_ref[0, 0]
-    level = jnp.floor(ratio)
-    level = level + (u_ref[...] < (ratio - level))
-    q = (jnp.sign(xf) * jnp.minimum(level, s)).astype(jnp.int32)
-    off = q + int(s)              # offset-encode to [0, 2s] < 2^bits
+def _pack_matrices(bits: int) -> jax.Array:
+    """(k, LANE, LANE) bf16: lane t of plane row ``R*k + j`` -> output
+    lane ``j*LANE/k + t//k`` of byte row R, at bit ``(t % k) * bits``."""
     k = pack_factor(bits)
-    if k == 1:
-        out_ref[...] = off.astype(jnp.uint8)
-        return
-    # byte (r, cb) holds elements (r, cb*k + j), j in [0, k) — the
-    # unfused row-major flat pack order. The reshape is layout-free and
-    # the minor-axis picks fuse (a j::k strided slice would lower to a
-    # gather on CPU and break the single-loop fusion).
-    rows_blk = off.shape[0]
-    off3 = off.reshape(rows_blk, off.shape[1] // k, k)
-    byte = jnp.zeros(out_ref.shape, jnp.int32)
+    t = np.arange(LANE)
+    m = np.zeros((k, LANE, LANE), np.float32)
     for j in range(k):
-        byte = byte | (off3[:, :, j] << (j * bits))
-    out_ref[...] = byte.astype(jnp.uint8)
+        m[j, t, j * (LANE // k) + t // k] = 2.0 ** ((t % k) * bits)
+    return jnp.asarray(m, jnp.bfloat16)
+
+
+def _qsgd_kernel(x_ref, u_ref, inv_ref, *refs, bits: int):
+    s = float(2 ** (bits - 1) - 1)
+    k = pack_factor(bits)
+    rows = x_ref.shape[0] // k
+
+    def offsets(j):
+        # plane rows j, j+k, j+2k, ... of this block; the EXACT unfused
+        # arithmetic (compressor.QSGDCompressor.compress): floor +
+        # stochastic carry + clamp + sign, fused into one pass.
+        sl = pl.ds(j, rows, stride=k) if k > 1 else slice(None)
+        xf = x_ref[sl, :]
+        ratio = jnp.abs(xf) * inv_ref[0, 0]
+        level = jnp.floor(ratio)
+        level = level + (u_ref[sl, :] < (ratio - level))
+        q = (jnp.sign(xf) * jnp.minimum(level, s)).astype(jnp.int32)
+        return q + int(s)         # offset-encode to [0, 2s] < 2^bits
+
+    if k == 1:
+        (out_ref,) = refs
+        out_ref[...] = offsets(0).astype(jnp.uint8)
+        return
+    pack_ref, out_ref = refs
+    byte = jnp.zeros(out_ref.shape, jnp.float32)
+    for j in range(k):
+        byte = byte + jnp.dot(offsets(j).astype(jnp.bfloat16), pack_ref[j],
+                              preferred_element_type=jnp.float32)
+    out_ref[...] = byte.astype(jnp.int32).astype(jnp.uint8)
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "interpret"))
 def qsgd_pack_pallas(xf: jax.Array, u: jax.Array, inv: jax.Array, *,
-                     bits: int, interpret: bool = True) -> jax.Array:
+                     bits: int, interpret: bool | None = None) -> jax.Array:
     """(rows, LANE) f32 plane + uniforms + (1, 1) 1/norm -> packed u8.
 
-    Output is (rows, LANE // pack_factor) u8 — the exact byte image the
+    Output is the flat ``rows * LANE // pack_factor`` u8 byte image the
     unfused packer produces in row-major flat order (offset-encoded
     q + s for bits=8).
     """
     rows, lane = xf.shape
     assert lane == LANE, (xf.shape,)
     k = pack_factor(bits)
-    block_rows = 8 if rows % 8 == 0 else 1
-    grid = (rows // block_rows,)
-    blk_in = pl.BlockSpec((block_rows, LANE), lambda i: (i, 0))
-    kernel = functools.partial(_qsgd_kernel, bits=bits)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[blk_in, blk_in,
-                  pl.BlockSpec((1, 1), lambda i: (0, 0))],
-        out_specs=pl.BlockSpec((block_rows, LANE // k), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, LANE // k), jnp.uint8),
-        interpret=interpret,
-    )(xf, u, inv)
+    pad = (-rows) % k
+    if pad:
+        # zero pad rows only add whole trailing bytes, cut off below
+        xf = jnp.pad(xf, ((0, pad), (0, 0)))
+        u = jnp.pad(u, ((0, pad), (0, 0)))
+    out_rows = (rows + pad) // k
+    block = min(out_rows, QSGD_BLOCK_ROWS)
+    blk_in = pl.BlockSpec((block * k, LANE), lambda i: (i, 0))
+    in_specs = [blk_in, blk_in, pl.BlockSpec((1, 1), lambda i: (0, 0))]
+    operands = [xf, u, inv]
+    if k > 1:
+        in_specs.append(pl.BlockSpec((k, LANE, LANE), lambda i: (0, 0, 0)))
+        operands.append(_pack_matrices(bits))
+    out = pl.pallas_call(
+        functools.partial(_qsgd_kernel, bits=bits),
+        grid=(pl.cdiv(out_rows, block),),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((block, LANE), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((out_rows, LANE), jnp.uint8),
+        interpret=resolve_interpret(interpret),
+    )(*operands)
+    return out.reshape(-1)[:rows * LANE // k]
 
 
-def _gather_kernel(db_ref, idx_ref, out_ref, *, scale: float):
-    idx = idx_ref[...][:, 0]
-    out_ref[...] = jnp.take(db_ref[...], idx, axis=0) * scale
+def _gather_kernel(idx_ref, db_hbm, out_ref, sem, *, scale: float,
+                   n_kept: int, chunk: int):
+    # kept rows in this step: the last step's block may run past kb
+    n = jnp.minimum(chunk, n_kept - pl.program_id(0) * chunk)
+
+    def row_copy(j):
+        return pltpu.make_async_copy(db_hbm.at[pl.ds(idx_ref[j], 1)],
+                                     out_ref.at[pl.ds(j, 1)], sem)
+
+    def start(j, carry):
+        row_copy(j).start()
+        return carry
+
+    def wait(j, carry):
+        row_copy(j).wait()
+        return carry
+
+    # every wait names the one-row copy it waits for, so the semaphore
+    # count matches whatever the block's row count (a single whole-block
+    # wait over a block of 565 rows never returned on a v5e)
+    jax.lax.fori_loop(0, n, start, 0)
+    jax.lax.fori_loop(0, n, wait, 0)
+    out_ref[...] = out_ref[...] * scale
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def fixedk_gather_pack_pallas(db: jax.Array, idx: jax.Array, *,
                               scale: float,
-                              interpret: bool = True) -> jax.Array:
+                              interpret: bool | None = None) -> jax.Array:
     """(nb, block) plane view + (kb,) i32 indices -> (kb, block) payload.
 
     One launch for the sender-side fixed-k pack: gather the kept blocks
     and apply the (static, scalar-p) unbiasedness scale — bit-exact to
-    ``jnp.take(db, idx, axis=0) * scale``. Whole-plane VMEM block (our
-    planes are small); the PrefetchScalarGridSpec one-row-per-grid-step
-    variant is the production TPU layout.
+    ``jnp.take(db, idx, axis=0) * scale``. ``block`` must be a multiple
+    of LANE (whole lane-dense rows). Grid step i DMAs kept rows
+    ``idx[i*chunk : (i+1)*chunk]`` from HBM into its output block; the
+    index chunk rides SMEM, since the whole index vector (kb int32) can
+    outgrow it at model scale.
     """
+    nb, block = db.shape
+    assert block % LANE == 0, db.shape
     kb = idx.shape[0]
-    kernel = functools.partial(_gather_kernel, scale=scale)
+    rows = min(GATHER_CHUNK, kb)          # kept rows per grid step
+    n_steps = pl.cdiv(kb, rows)
+    idx = jnp.pad(idx.astype(jnp.int32),
+                  (0, n_steps * GATHER_CHUNK - kb))
     return pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((kb, db.shape[1]), db.dtype),
-        interpret=interpret,
-    )(db, idx.reshape(kb, 1))
+        functools.partial(_gather_kernel, scale=scale, n_kept=kb,
+                          chunk=rows),
+        grid=(n_steps,),
+        in_specs=[pl.BlockSpec((GATHER_CHUNK,), lambda i: (i,),
+                               memory_space=pltpu.SMEM),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((rows, block), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((kb, block), db.dtype),
+        scratch_shapes=[pltpu.SemaphoreType.DMA(())],
+        interpret=resolve_interpret(interpret),
+    )(idx, db)

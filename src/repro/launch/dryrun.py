@@ -128,7 +128,6 @@ def _measure(cfg, case, mesh, node_axes, method: str, gossip_mode: str,
     import jax
     import jax.numpy as jnp
 
-    from repro import compat
     from repro.core.sdm_dsgd import SDMConfig
     from repro.launch import hlo_analysis, shapes as shapes_mod
     from repro.models import transformer
@@ -212,7 +211,7 @@ def _measure(cfg, case, mesh, node_axes, method: str, gossip_mode: str,
     compiled = lowered.compile()
     record["compile_s"] = round(time.time() - t1, 2)
 
-    cost = compat.cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     hlo = compiled.as_text()
     record["flops"] = float(cost.get("flops", -1.0))
     record["bytes_accessed"] = float(cost.get("bytes accessed", -1.0))

@@ -35,7 +35,8 @@ def main() -> None:
     ap.add_argument("--page-size", type=int, default=16)
     ap.add_argument("--flash-decode", action="store_true",
                     help="route decode attention through the paged "
-                         "pallas kernel (interpret mode off-TPU)")
+                         "pallas kernel off-TPU too (interpret mode); "
+                         "on a TPU it is always on")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
@@ -44,9 +45,12 @@ def main() -> None:
     import numpy as np
 
     from repro import configs
+    from repro.launch.compile_cache import use_compile_cache
     from repro.models import transformer
     from repro.serving import Request, ServingEngine, StaticServingEngine
     from repro.serving.ingest import ingest_checkpoint
+
+    use_compile_cache()
 
     cfg = (configs.get_smoke_config(args.arch) if args.smoke
            else configs.get_config(args.arch))
@@ -65,7 +69,7 @@ def main() -> None:
     else:
         engine = ServingEngine(cfg, params, max_batch=args.max_batch,
                                max_seq=max_seq, page_size=args.page_size,
-                               use_flash=args.flash_decode)
+                               use_flash=args.flash_decode or None)
 
     rng = np.random.default_rng(args.seed)
     reqs = []

@@ -1,10 +1,11 @@
 """Production training launcher.
 
-On real TPU pods this process runs once per host (jax.distributed
-auto-init); in this container it drives the same code over N simulated
-nodes. Selects architecture / algorithm / gossip parameters from the CLI
-and runs the distributed SDM-DSGD train step built by
-``repro.train.steps.make_distributed_train``.
+One process drives every local device; by default each device is one
+SDM-DSGD node on the ``data`` axis. Selects architecture / algorithm /
+gossip parameters from the CLI and runs the distributed SDM-DSGD train
+step built by ``repro.train.steps.make_distributed_train``. ``train``
+takes the ``ModelConfig`` itself, so a caller can hand it a cut of a
+published config (``chip_smoke.py`` does).
 
 Examples:
   PYTHONPATH=src python -m repro.launch.train --arch gemma2-2b --smoke \
@@ -13,15 +14,20 @@ Examples:
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
+from typing import Any, List, Optional, Sequence
 
 
-def main() -> None:
+def parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true",
                     help="use the reduced smoke config")
-    ap.add_argument("--mesh", default="single_pod")
+    ap.add_argument("--mesh", default="local",
+                    help="local (every local device on the data axis, one "
+                         "node each) | N | DxM | PxDxM | single_pod | "
+                         "multi_pod")
     ap.add_argument("--steps", type=int, default=10)
     ap.add_argument("--global-batch", type=int, default=None)
     ap.add_argument("--seq-len", type=int, default=None)
@@ -38,7 +44,13 @@ def main() -> None:
     ap.add_argument("--clip-c", type=float, default=None)
     ap.add_argument("--gossip-mode", default="bernoulli",
                     choices=["bernoulli", "fixedk_packed", "fixedk_rows",
-                             "qsgd"])
+                             "qsgd"],
+                    help="fixedk_packed keeps single coordinates and packs "
+                         "them with XLA's gather; the fused Pallas pack "
+                         "moves whole 128-lane plane rows, so it runs only "
+                         "with --compressor block:<multiple of 128> "
+                         "(gossip.fused_pack_applies; the banner prints "
+                         "fixedk_pack=kernel|xla-gather)")
     ap.add_argument("--compressor", default=None,
                     help="wire compressor spec (repro.core.compressor): "
                          "bernoulli | fixedk[:block] | block:<B> | rows | "
@@ -70,14 +82,33 @@ def main() -> None:
                          "churn=0.02:5' (see repro.sim.fleet)")
     ap.add_argument("--sim-rounds", type=int, default=None,
                     help="global rounds to simulate (defaults to --steps)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    if args.overlap and args.topology.startswith("matchings"):
+        ap.error("--overlap needs a static topology: the double-buffered "
+                 "transport has no replica (time-varying) delivery path")
+    return args
 
+
+@dataclasses.dataclass
+class TrainRun:
+    """What one lock-step training run produced (``train``'s result)."""
+    mesh: Any
+    tc: Any                    # steps.DistributedTrainConfig
+    state: Any                 # final stacked state, one node per device
+    compiled: Any              # the compiled train step (jax.stages)
+    compile_s: float
+    losses: List[float]
+    step_s: List[float]        # per step, each ended by block_until_ready
+
+
+def train(args: argparse.Namespace, cfg) -> Optional[TrainRun]:
+    """Run ``args`` on the model ``cfg`` (a ``ModelConfig``); ``None``
+    for the ``--sim`` axis, which reports through its own printout."""
     import jax
     import jax.numpy as jnp
 
-    from repro import configs
     from repro.checkpoint import save_checkpoint
-    from repro.core import method as method_mod
+    from repro.core import gossip, method as method_mod
     from repro.core.sdm_dsgd import SDMConfig
     from repro.data import TokenStream
     from repro.launch.mesh import make_mesh_by_name, node_axis_names
@@ -86,20 +117,15 @@ def main() -> None:
     meth_name = method_mod.normalize(
         args.method or args.algorithm or "sdm-dsgd")
     method_mod.get(meth_name)   # fail fast on unknown registrations
-    cfg = (configs.get_smoke_config(args.arch) if args.smoke
-           else configs.get_config(args.arch))
     mesh = make_mesh_by_name(args.mesh)
     node_axes = node_axis_names(mesh)
     n_nodes = 1
     for a in node_axes:
         n_nodes *= mesh.shape[a]
 
-    batch = args.global_batch or max(n_nodes, 2 * n_nodes)
-    seq = args.seq_len or 64 if args.smoke else 4096
+    batch = args.global_batch or 2 * n_nodes
+    seq = args.seq_len or (64 if args.smoke else 4096)
 
-    if args.overlap and args.topology.startswith("matchings"):
-        ap.error("--overlap needs a static topology: the double-buffered "
-                 "transport has no replica (time-varying) delivery path")
     sdm_cfg = SDMConfig(p=args.p, theta=args.theta, gamma=args.gamma,
                         sigma=args.sigma, clip_c=args.clip_c,
                         mode=args.gossip_mode, compressor=args.compressor,
@@ -112,40 +138,78 @@ def main() -> None:
         method=meth_name,
         param_dtype=jnp.float32 if args.smoke else jnp.bfloat16)
     sched = steps_mod.gossip_schedule(tc, mesh)
+    mcfg = tc.resolved()[1]
+    pack = ""
+    if isinstance(mcfg, SDMConfig) and mcfg.mode == "fixedk_packed":
+        fused = gossip.fused_pack_applies(mcfg.pack_block, jnp.float32,
+                                          mcfg.p)
+        pack = " fixedk_pack=" + ("kernel" if fused else "xla-gather")
 
     print(f"arch={cfg.name} mesh={dict(zip(mesh.axis_names, mesh.devices.shape))} "
           f"nodes={n_nodes} method={meth_name} p={args.p} theta={args.theta} "
           f"compressor={args.compressor or sdm_cfg.mode} "
-          f"topology={sched.name} gossip_rounds={sched.n_rounds}"
+          f"topology={sched.name} gossip_rounds={sched.n_rounds} "
+          f"batch={batch} seq={seq}{pack}"
           + (f" time_varying_L={sched.length}" if sched.length > 1 else "")
-          + (" overlap=on" if args.overlap else ""))
+          + (" overlap=on" if args.overlap else ""), flush=True)
 
     if args.sim:
         _run_simulated(args, cfg, sdm_cfg, meth_name, n_nodes, batch, seq)
-        return
+        return None
 
     state = steps_mod.init_distributed_state(tc, mesh,
                                              jax.random.PRNGKey(args.seed))
-    step_fn = jax.jit(steps_mod.make_distributed_train(tc, mesh))
     stream = TokenStream(vocab_size=cfg.vocab_size, batch=batch, seq_len=seq,
                          seed=args.seed)
-
+    on_nodes = steps_mod.batch_sharding(mesh)
     has_ctx = cfg.family in ("audio", "vlm")
-    for t in range(args.steps):
+
+    def step_args(t):
         tokens, labels = stream.batch_at(t)
-        fn_args = [state, jnp.asarray(tokens), jnp.asarray(labels)]
+        out = [jax.device_put(tokens, on_nodes),
+               jax.device_put(labels, on_nodes)]
         if has_ctx:
             shape = (batch, cfg.encoder_seq if cfg.family == "audio"
                      else cfg.n_image_tokens, cfg.d_model)
-            fn_args.append(jnp.full(shape, 0.01, tc.param_dtype))
-        t0 = time.time()
-        state, loss = step_fn(*fn_args)
-        print(f"step {t:4d} loss {float(loss):.4f} "
-              f"({time.time() - t0:.2f}s)", flush=True)
+            out.append(jax.device_put(
+                jnp.full(shape, 0.01, tc.param_dtype), on_nodes))
+        return out
+
+    # the state is donated: the step never holds two copies of it
+    t0 = time.perf_counter()
+    compiled = jax.jit(steps_mod.make_distributed_train(tc, mesh),
+                       donate_argnums=0).lower(state, *step_args(0)).compile()
+    compile_s = time.perf_counter() - t0
+    print(f"compiled train step in {compile_s:.2f}s", flush=True)
+
+    losses, step_s = [], []
+    for t in range(args.steps):
+        fn_args = step_args(t)
+        t0 = time.perf_counter()
+        state, loss = compiled(state, *fn_args)
+        jax.block_until_ready((state, loss))
+        step_s.append(time.perf_counter() - t0)
+        losses.append(float(loss))
+        print(f"step {t:4d} loss {losses[-1]:.4f} ({step_s[-1]:.3f}s)",
+              flush=True)
 
     if args.checkpoint_dir:
         save_checkpoint(args.checkpoint_dir, args.steps, state)
         print(f"checkpoint written to {args.checkpoint_dir}")
+    return TrainRun(mesh=mesh, tc=tc, state=state, compiled=compiled,
+                    compile_s=compile_s, losses=losses, step_s=step_s)
+
+
+def main(argv: Optional[Sequence[str]] = None) -> None:
+    args = parse_args(argv)
+
+    from repro import configs
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
+    cfg = (configs.get_smoke_config(args.arch) if args.smoke
+           else configs.get_config(args.arch))
+    train(args, cfg)
 
 
 def _run_simulated(args, cfg, sdm_cfg, meth_name, n_nodes,

@@ -326,12 +326,11 @@ def attention_decode_paged(params: Dict[str, jax.Array], cfg: ModelConfig,
                            write_enabled: jax.Array,
                            layer_kind: str = "attn",
                            use_flash: bool = False,
-                           interpret: bool = True,
                            ) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
     """Single-token self-attention over a PAGED KV cache.
 
     x: (b, 1, d). ``pages`` is this layer's (k_pages, v_pages), each
-    (n_pages, page_size, kv_heads, head_dim); ``block_table`` (b,
+    (n_pages, kv_heads, page_size, head_dim); ``block_table`` (b,
     n_blocks) maps row b's logical block j to a physical page;
     ``offsets`` (b,) is each row's next write position (tokens already
     cached); ``write_enabled`` (b,) routes finished / empty slots' writes
@@ -344,20 +343,23 @@ def attention_decode_paged(params: Dict[str, jax.Array], cfg: ModelConfig,
     residual, q, k, v = _project_qkv(params, cfg, x,
                                      positions=offsets[:, None])
     k_pages, v_pages = pages
-    page = k_pages.shape[1]
+    page = k_pages.shape[2]
     rows = jnp.arange(b)
     blk = jnp.clip(offsets // page, 0, block_table.shape[1] - 1)
     page_id = jnp.where(write_enabled, block_table[rows, blk], 0)
     in_page = jnp.where(write_enabled, offsets % page, 0)
-    k_pages = k_pages.at[page_id, in_page].set(k[:, 0].astype(k_pages.dtype))
-    v_pages = v_pages.at[page_id, in_page].set(v[:, 0].astype(v_pages.dtype))
+    # indexed view (b, kv_heads, head_dim): the split advanced indices lead
+    k_pages = k_pages.at[page_id, :, in_page].set(
+        k[:, 0].astype(k_pages.dtype))
+    v_pages = v_pages.at[page_id, :, in_page].set(
+        v[:, 0].astype(v_pages.dtype))
 
     # a row that did not write must not read its (absent) current token
     seq_lens = offsets + write_enabled.astype(offsets.dtype)
     window = cfg.sliding_window if layer_kind == "attn_local" else None
     out = paged_attention(q[:, 0], k_pages, v_pages, block_table, seq_lens,
                           window=window, softcap=cfg.attn_softcap,
-                          use_kernel=use_flash, interpret=interpret)
+                          use_kernel=use_flash)
     return (_project_out(params, cfg, out[:, None], residual),
             (k_pages, v_pages))
 

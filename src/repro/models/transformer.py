@@ -426,12 +426,12 @@ def decode_step_paged(params: PyTree, cfg: ModelConfig, token: jax.Array,
                       block_tables: jax.Array, offsets: jax.Array,
                       write_enabled: jax.Array, *,
                       context: Optional[jax.Array] = None,
-                      use_flash: bool = False, interpret: bool = True
+                      use_flash: bool = False
                       ) -> Tuple[jax.Array, Dict[str, Any], Dict[str, Any]]:
     """One decode step over a PAGED KV cache (continuous-batching engine).
 
     ``pages``: {period-slot index -> (k_pages, v_pages)} for attention
-    slots, each array (n_periods, n_pages, page_size, kv_heads, head_dim)
+    slots, each array (n_periods, n_pages, kv_heads, page_size, head_dim)
     — one shared physical page pool per layer slot, scanned over the
     period axis alongside the parameters. ``rec``: {period-slot index ->
     recurrent state} for mamba/rwkv slots (dense per-row state; paging
@@ -462,7 +462,7 @@ def decode_step_paged(params: PyTree, cfg: ModelConfig, token: jax.Array,
                     sp["attn"], cfg, x, pages=period_pages[si],
                     block_table=block_tables, offsets=offsets,
                     write_enabled=write_enabled, layer_kind=spec.mixer,
-                    use_flash=use_flash, interpret=interpret)
+                    use_flash=use_flash)
             elif spec.mixer == "mamba":
                 x, new_rec[si] = mamba_mod.mamba_decode_step(
                     sp["mamba"], cfg, x, period_rec[si])

@@ -92,15 +92,16 @@ class ServingEngine:
     equivalent ``max_batch * ceil(max_seq/page_size)``; ragged traffic
     runs fine far below that — admission applies backpressure).
     ``use_flash`` routes decode attention through the paged flash
-    kernel (interpret-mode Pallas off-TPU); the default XLA gather path
-    computes identical logits (tested) and is the fast path on CPU
-    hosts. ``sync_every`` decode steps run between done-mask polls.
+    kernel; ``None`` turns it on exactly on a TPU backend. The XLA
+    gather path computes identical logits (tested) and is the fast path
+    on CPU hosts, where the kernel only interprets. ``sync_every``
+    decode steps run between done-mask polls.
     """
 
     def __init__(self, cfg: ModelConfig, params, *, max_batch: int = 8,
                  max_seq: int = 256, dtype=jnp.float32, page_size: int = 16,
-                 n_pages: Optional[int] = None, use_flash: bool = False,
-                 interpret: bool = True, sync_every: int = 1):
+                 n_pages: Optional[int] = None,
+                 use_flash: Optional[bool] = None, sync_every: int = 1):
         self.cfg = cfg
         self.params = params
         self.max_batch = max_batch
@@ -108,8 +109,8 @@ class ServingEngine:
         self.dtype = dtype
         self.page_size = page_size
         self.n_pages = n_pages
-        self.use_flash = use_flash
-        self.interpret = interpret
+        self.use_flash = (jax.default_backend() == "tpu"
+                          if use_flash is None else use_flash)
         self.sync_every = max(1, sync_every)
         self.recurrent = _is_recurrent(cfg)
         self.last_stats: Optional[ServeStats] = None
@@ -135,8 +136,7 @@ class ServingEngine:
             emit = st.active & ~st.done
             logits, pages, rec = transformer.decode_step_paged(
                 p, cfg, st.last_tok, st.pages, st.rec, tables, st.offsets,
-                emit, context=ctx, use_flash=self.use_flash,
-                interpret=self.interpret)
+                emit, context=ctx, use_flash=self.use_flash)
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             rows = jnp.arange(st.out_buf.shape[0])
             idx = jnp.clip(st.n_out, 0, st.out_buf.shape[1] - 1)

@@ -3,7 +3,7 @@
 The dense serving cache reserves ``max_batch x max_seq`` per layer no
 matter how long requests actually run. Here KV storage is a pool of
 fixed-size pages — one pool per attention period-slot, shaped
-``(n_periods, n_pages, page_size, kv_heads, head_dim)`` — and each
+``(n_periods, n_pages, kv_heads, page_size, head_dim)`` — and each
 request slot owns a BLOCK TABLE row mapping its logical block j to a
 physical page id. A slot is charged exactly
 ``ceil((prompt + budget) / page_size)`` pages at admission and returns
@@ -40,7 +40,9 @@ __all__ = ["PagedKVCache", "TRASH_PAGE"]
 TRASH_PAGE = 0
 
 # Each attention period-slot's pool is a plain ``(k_pages, v_pages)``
-# tuple, both (n_periods, n_pages+1, page_size, kv_heads, head_dim).
+# tuple, both (n_periods, n_pages+1, kv_heads, page_size, head_dim): head-
+# major, so one head's page is a (page_size, head_dim) tile for the paged
+# flash-decode kernel.
 # Plain tuples (not a NamedTuple) on purpose: the decode step returns
 # plain tuples, and a pytree-type flip between host bookkeeping and the
 # jitted step would force a retrace at every admit/retire boundary.
@@ -73,7 +75,7 @@ class PagedKVCache:
         self.n_pages = n_pages
         self.dtype = dtype
         kv, hd = cfg.n_kv_heads, cfg.resolved_head_dim
-        shape = (cfg.n_periods, n_pages + 1, page_size, kv, hd)
+        shape = (cfg.n_periods, n_pages + 1, kv, page_size, hd)
         self.pages: Dict[str, Tuple[jax.Array, jax.Array]] = {
             si: (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
             for si in _attn_slots(cfg)
@@ -173,7 +175,9 @@ class PagedKVCache:
         in_page = jnp.asarray(pos % self.page_size, jnp.int32)
         out = {}
         for si, (kp, vp) in self.pages.items():
-            out[si] = (kp[:, page_id, in_page], vp[:, page_id, in_page])
+            # advanced indices split by a slice lead: (length, n_periods, ..)
+            out[si] = (jnp.swapaxes(kp[:, page_id, :, in_page], 0, 1),
+                       jnp.swapaxes(vp[:, page_id, :, in_page], 0, 1))
         return out
 
     def dense_equivalent_pages(self) -> int:
@@ -184,5 +188,8 @@ class PagedKVCache:
 @jax.jit
 def _scatter_prompt(pages: jax.Array, dense: jax.Array, page_id: jax.Array,
                     in_page: jax.Array) -> jax.Array:
-    # pages (n_periods, n_pages+1, P, kv, hd); dense (n_periods, 1, L, kv, hd)
-    return pages.at[:, page_id, in_page].set(dense[:, 0])
+    # pages (n_periods, n_pages+1, kv, P, hd); dense (n_periods, 1, L, kv, hd).
+    # The advanced indices are split by a slice, so the indexed view is
+    # (L, n_periods, kv, hd).
+    return pages.at[:, page_id, :, in_page].set(
+        jnp.swapaxes(dense[:, 0], 0, 1))

@@ -19,8 +19,6 @@ from typing import Any, Mapping, Optional, Sequence
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
-
 __all__ = ["MeshRules", "use_rules", "current_rules", "logical",
            "logical_sharding", "tree_shardings"]
 
@@ -103,8 +101,8 @@ def logical(x: jax.Array, *axes: Optional[str]) -> jax.Array:
         return x
     assert len(axes) == x.ndim, (axes, x.shape)
     spec = rules.spec(axes, x.shape)
-    abstract = compat.get_abstract_mesh()
-    if abstract is not None and abstract.shape_tuple:
+    abstract = jax.sharding.get_abstract_mesh()
+    if abstract.shape_tuple:
         return jax.lax.with_sharding_constraint(
             x, NamedSharding(abstract, spec))
     return jax.lax.with_sharding_constraint(

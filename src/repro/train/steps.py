@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.core import gossip, method as method_mod, plane as plane_mod
 from repro.core import tagging
 from repro.models import transformer
@@ -138,17 +137,13 @@ def gossip_schedule(tc: DistributedTrainConfig, mesh: Mesh
 def plane_bucket_tree(tc: DistributedTrainConfig, mesh: Mesh):
     """The wire-plane bucket policy for this run (this file owns it).
 
-    On a tensor-parallel mesh with a working partial-auto shard_map,
-    leaves whose TRAILING logical axis maps to the model axis get their
-    own plane bucket keyed ``('model', cols)`` — the plane's lane dim
-    keeps the TP sharding (DDP-gradient-bucket style); everything else
-    rides the default flat bucket. On the full-manual fallback (old
-    jaxlibs) or meshes without a model axis, everything is replicated
-    inside the region anyway, so one flat plane is optimal: return None.
+    On a tensor-parallel mesh, leaves whose TRAILING logical axis maps
+    to the model axis get their own plane bucket keyed ``('model',
+    cols)`` — the plane's lane dim keeps the TP sharding
+    (DDP-gradient-bucket style); everything else rides the default flat
+    bucket. On meshes without a model axis one flat plane is optimal:
+    return None.
     """
-    node_axes = _node_axes(mesh)
-    if compat.partial_auto_shard_map_broken(mesh, node_axes):
-        return None
     if "model" not in mesh.shape or mesh.shape["model"] == 1:
         return None
     return plane_mod.bucket_keys_from_axes(
@@ -213,15 +208,31 @@ def init_distributed_state(tc: DistributedTrainConfig, mesh: Mesh,
 
     Method-generic: e.g. SDM's s_0[i] = (1 - W_ii(0)) x_0 with the
     node's OWN self-weight (W_ii varies per node on Metropolis–Hastings
-    graphs), gradient-push's mass w_0 = 1.
+    graphs), gradient-push's mass w_0 = 1. Built under one jit whose
+    outputs carry ``state_shardings``, so each device materializes only
+    its own node's slice — the stacked n-node state never exists on one
+    device.
     """
     n_nodes = _n_nodes(mesh)
     meth, cfg = tc.resolved()
-    params = transformer.init_params(key, tc.model, tc.param_dtype)
-    stack = jax.tree.map(
-        lambda p: jnp.broadcast_to(p[None], (n_nodes,) + p.shape), params)
-    with _bucket_ctx(tc, mesh):
-        return meth.init_stacked(stack, gossip_schedule(tc, mesh), cfg)
+    seq = gossip_schedule(tc, mesh)
+
+    def build(key):
+        params = transformer.init_params(key, tc.model, tc.param_dtype)
+        stack = jax.tree.map(
+            lambda p: jnp.broadcast_to(p[None], (n_nodes,) + p.shape),
+            params)
+        with _bucket_ctx(tc, mesh):
+            return meth.init_stacked(stack, seq, cfg)
+
+    return jax.jit(build, out_shardings=state_shardings(tc, mesh))(key)
+
+
+def batch_sharding(mesh: Mesh) -> NamedSharding:
+    """Token batches: the leading (global batch) dim split over the nodes."""
+    node_axes = _node_axes(mesh)
+    return NamedSharding(mesh, P(node_axes if len(node_axes) > 1
+                                 else node_axes[0]))
 
 
 def make_distributed_train(tc: DistributedTrainConfig, mesh: Mesh,
@@ -233,13 +244,8 @@ def make_distributed_train(tc: DistributedTrainConfig, mesh: Mesh,
     """
     cfg = tc.model
     node_axes = _node_axes(mesh)
-    # Old jaxlibs cannot partition ppermute/scan inside a partial-auto
-    # region: run the whole node step fully manual there, replicating the
-    # model axis (no TP) instead of GSPMD-sharding it.
-    full_manual = compat.partial_auto_shard_map_broken(mesh, node_axes)
-    manual_axes = set(mesh.axis_names) if full_manual else set(node_axes)
     axis = node_axes if len(node_axes) > 1 else node_axes[0]
-    inner = None if full_manual else MeshRules(mesh, INNER_RULES)
+    inner = MeshRules(mesh, INNER_RULES)
     meth, mcfg = tc.resolved()
     seq = gossip_schedule(tc, mesh)
     if getattr(mcfg, "overlap", False) and gossip.needs_replicas(seq):
@@ -300,11 +306,11 @@ def make_distributed_train(tc: DistributedTrainConfig, mesh: Mesh,
     node_ids = jnp.arange(_n_nodes(mesh), dtype=jnp.int32)
 
     def train_step(state, tokens, labels, context=None):
-        fn = compat.shard_map(
+        fn = jax.shard_map(
             node_step, mesh=mesh,
             in_specs=in_specs,
             out_specs=(state_specs, P()),
-            axis_names=manual_axes, check_vma=False)
+            axis_names=set(node_axes), check_vma=False)
         return fn(state, tokens, labels, context, node_ids)
 
     return train_step

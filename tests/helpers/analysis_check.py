@@ -18,10 +18,9 @@ sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]))
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
-from jax.sharding import PartitionSpec as P  # noqa: E402
+from jax.sharding import AxisType, PartitionSpec as P  # noqa: E402
 
 from fixtures.broken_method import broken_step  # noqa: E402
-from repro import compat  # noqa: E402
 from repro.analysis import jaxpr_taint, prng_lint  # noqa: E402
 from repro.core import gossip, topology  # noqa: E402
 
@@ -36,7 +35,7 @@ def main() -> int:
     a_st = jnp.asarray(rng.normal(size=(N, BATCH, DIM)), jnp.float32)
     b_st = jnp.asarray(rng.normal(size=(N, BATCH)), jnp.float32)
     base_key = jax.random.PRNGKey(7)
-    mesh = compat.make_mesh((N,), ("data",))
+    mesh = jax.make_mesh((N,), ("data",), axis_types=(AxisType.Auto,))
 
     def dist(x_st, a_st, b_st):
         def inner(x, a, b):
@@ -45,11 +44,11 @@ def main() -> int:
                               base_key=base_key, step=jnp.int32(0))
             return out[None]
 
-        return compat.shard_map(inner, mesh=mesh,
-                                in_specs=(P("data"), P("data"), P("data")),
-                                out_specs=P("data"),
-                                axis_names={"data"},
-                                check_vma=False)(x_st, a_st, b_st)
+        return jax.shard_map(inner, mesh=mesh,
+                             in_specs=(P("data"), P("data"), P("data")),
+                             out_specs=P("data"),
+                             axis_names={"data"},
+                             check_vma=False)(x_st, a_st, b_st)
 
     jaxpr = jax.make_jaxpr(dist)(x_st, a_st, b_st)
     taint = jaxpr_taint.analyze_taint(jaxpr, {1: "data", 2: "data"})

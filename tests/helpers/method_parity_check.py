@@ -33,9 +33,8 @@ from fractions import Fraction  # noqa: E402
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
-from jax.sharding import PartitionSpec as P  # noqa: E402
+from jax.sharding import AxisType, PartitionSpec as P  # noqa: E402
 
-from repro import compat  # noqa: E402
 from repro.core import (baselines, gossip, gradient_push, method as  # noqa: E402
                         method_mod, plane as plane_mod, sdm_dsgd,  # noqa: E402
                         sparsifier, topology)  # noqa: E402
@@ -309,7 +308,7 @@ def run_case(meth_key: str, topo_spec: str, mode: str,
     ref_x = jax.tree.map(np.asarray, debias(meth_name, state.x, state))
 
     # ---------------- distributed executor ---------------------------
-    mesh = compat.make_mesh((n,), ("data",))
+    mesh = jax.make_mesh((n,), ("data",), axis_types=(AxisType.Auto,))
     ex = meth.make_distributed(seq, cfg, "data")
 
     def dist_train(params_stack, a_st, b_st):
@@ -331,10 +330,10 @@ def run_case(meth_key: str, topo_spec: str, mode: str,
             z = debias(meth_name, state.x, state)
             return jax.tree.map(lambda v: v[None], z)
 
-        return compat.shard_map(inner, mesh=mesh,
-                                in_specs=(P("data"), P("data"), P("data")),
-                                out_specs=P("data"), axis_names={"data"},
-                                check_vma=False)(params_stack, a_st, b_st)
+        return jax.shard_map(inner, mesh=mesh,
+                             in_specs=(P("data"), P("data"), P("data")),
+                             out_specs=P("data"), axis_names={"data"},
+                             check_vma=False)(params_stack, a_st, b_st)
 
     compiled = jax.jit(dist_train).lower(params_stack, a_stack,
                                          b_stack).compile()

@@ -294,6 +294,19 @@ def test_fixedk_gather_pack_kernel_matches_ref(kb, scale):
     np.testing.assert_array_equal(np.asarray(out_k), np.asarray(out_r))
 
 
+@pytest.mark.parametrize("block,dtype,p,fused", [
+    (LANE, jnp.float32, 0.2, True),          # block:128, one plane row
+    (4 * LANE, jnp.float32, 0.2, True),
+    (1, jnp.float32, 0.2, False),            # element-granular fixedk
+    (64, jnp.float32, 0.2, False),
+    (LANE, jnp.bfloat16, 0.2, False),
+    (LANE, jnp.float32, (0.2, 0.5), False),  # het-p: traced scale mask
+])
+def test_fused_pack_applies(block, dtype, p, fused):
+    from repro.core import gossip
+    assert gossip.fused_pack_applies(block, dtype, p) is fused
+
+
 @given(seed=st.integers(0, 10_000), bits=st.sampled_from([2, 4, 8]),
        rows=st.sampled_from([8, 24, 40]))
 @settings(max_examples=15, deadline=None)

@@ -161,16 +161,16 @@ def test_paged_cache_no_cross_slot_leakage_after_recycle(seed):
 def _numpy_paged_attention(q, k_pages, v_pages, tbl, seq_lens, window,
                            softcap):
     b, h, dh = q.shape
-    _, page, kvh, _ = k_pages.shape
+    _, kvh, page, _ = k_pages.shape
     group = h // kvh
     out = np.zeros_like(q, dtype=np.float64)
     for i in range(b):
         L = int(seq_lens[i])
         if L == 0:
             continue
-        k = np.stack([k_pages[tbl[i, p // page], p % page]
+        k = np.stack([k_pages[tbl[i, p // page], :, p % page]
                       for p in range(L)])          # (L, kvh, dh)
-        v = np.stack([v_pages[tbl[i, p // page], p % page]
+        v = np.stack([v_pages[tbl[i, p // page], :, p % page]
                       for p in range(L)])
         for hh in range(h):
             kvh_i = hh // group
@@ -192,8 +192,8 @@ def test_flash_vs_naive_paged_decode_equivalence(window, softcap):
     b, kvh, group, dh, page, n_pages, n_blocks = 5, 2, 3, 32, 4, 24, 4
     h = kvh * group
     q = rng.normal(size=(b, h, dh)).astype(np.float32)
-    k_pages = rng.normal(size=(n_pages + 1, page, kvh, dh)).astype(np.float32)
-    v_pages = rng.normal(size=(n_pages + 1, page, kvh, dh)).astype(np.float32)
+    k_pages = rng.normal(size=(n_pages + 1, kvh, page, dh)).astype(np.float32)
+    v_pages = rng.normal(size=(n_pages + 1, kvh, page, dh)).astype(np.float32)
     # disjoint per-row page ownership, like the real allocator; trailing
     # blocks of short rows point at the trash page 0 (full of junk)
     perm = rng.permutation(np.arange(1, n_pages + 1))

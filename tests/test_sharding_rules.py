@@ -1,15 +1,14 @@
 """MeshRules / logical-axis sharding unit tests (single device: specs only)."""
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
-from repro import compat
 from repro.sharding import MeshRules, logical
 from repro.train.steps import INNER_RULES, outer_rules, serving_rules
 
 
 def _mesh(shape=(1, 1), names=("data", "model")):
     # AbstractMesh: spec construction without real devices
-    return compat.abstract_mesh(shape, names)
+    return AbstractMesh(tuple(shape), tuple(names))
 
 
 def test_spec_basic_mapping():

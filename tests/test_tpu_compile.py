@@ -1,0 +1,87 @@
+"""The main-path Pallas kernels compile for a TPU v5e at real shapes.
+
+Compiled with ``interpret=False`` for a described (not attached) v5e
+chip: what Mosaic refuses here — an illegal block shape, an unsupported
+layout cast — would fail the chip run. Shapes are phi3-medium-14b's: a
+slice of its wire plane, and its KV heads (10 x 128, group 4) in 16-token
+pages. All compiles stay in this one file and in fixtures, because only
+one process may load the TPU compiler at a time.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.flash_attn.decode import paged_flash_decode_pallas
+from repro.kernels.wire_compress import (fixedk_gather_pack_pallas,
+                                         qsgd_pack_pallas)
+
+PLANE_ROWS = 65_537          # odd: exercises the sub-byte row padding
+KV_HEADS, GROUP, HEAD_DIM, PAGE = 10, 4, 128, 16
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
+
+
+def _compiled_text(fn, *shapes) -> str:
+    return jax.jit(fn).lower(*shapes).compile().as_text()
+
+
+@pytest.mark.parametrize("bits", [2, 4, 8])
+def test_qsgd_pack_compiles_for_v5e(one_chip, bits):
+    plane = jax.ShapeDtypeStruct((PLANE_ROWS, 128), jnp.float32,
+                                 sharding=one_chip)
+    inv = jax.ShapeDtypeStruct((1, 1), jnp.float32, sharding=one_chip)
+    text = _compiled_text(
+        lambda x, u, i: qsgd_pack_pallas(x, u, i, bits=bits,
+                                         interpret=False),
+        plane, plane, inv)
+    assert "tpu_custom_call" in text
+
+
+# 13,108 kept rows: several chunks and a partial last one; 565: one
+# block of fewer rows than a chunk, not a multiple of 8 (the kept count
+# of the phi3 smoke plane)
+@pytest.mark.parametrize("kept", [13_108, 565])
+def test_fixedk_gather_pack_compiles_for_v5e(one_chip, kept):
+    plane = jax.ShapeDtypeStruct((PLANE_ROWS, 128), jnp.float32,
+                                 sharding=one_chip)
+    idx = jax.ShapeDtypeStruct((kept,), jnp.int32, sharding=one_chip)
+    text = _compiled_text(
+        lambda d, i: fixedk_gather_pack_pallas(d, i, scale=5.0,
+                                               interpret=False),
+        plane, idx)
+    assert "tpu_custom_call" in text
+
+
+def test_paged_flash_decode_compiles_for_v5e(one_chip):
+    batch, n_blocks = 8, 34
+    n_pages = batch * n_blocks + 1
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    pages = s((n_pages, KV_HEADS, PAGE, HEAD_DIM), jnp.bfloat16)
+    text = _compiled_text(
+        lambda q, k, v, t, n: paged_flash_decode_pallas(q, k, v, t, n,
+                                                        interpret=False),
+        s((batch, KV_HEADS, GROUP, HEAD_DIM), jnp.bfloat16), pages, pages,
+        s((batch, n_blocks), jnp.int32), s((batch,), jnp.int32))
+    assert "tpu_custom_call" in text
