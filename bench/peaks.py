@@ -1,0 +1,20 @@
+"""Published peaks of one chip, keyed by JAX's ``device_kind``.
+
+Source: Google Cloud documentation, "TPU v5e" (per chip): 197 TFLOP/s
+bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s inter-chip interconnect.
+A kind that is not here is an error, never a default.
+"""
+from __future__ import annotations
+
+PEAKS = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9,
+                    "hbm_bytes": 16e9, "ici_bits_per_s": 1600e9},
+}
+
+
+def peak(device_kind: str) -> dict:
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; known: {sorted(PEAKS)}") from None
