@@ -535,6 +535,7 @@ def union_exchange_payload(useq: UnionSchedule, payload, decompress,
     return jnp.stack(outs)
 
 
+@jax.named_scope("sdm_pack")
 def _union_packed_exchange(useq: UnionSchedule, db: jax.Array, unpack, *,
                            axis_name, base_key: jax.Array, step: jax.Array,
                            p, node_index) -> Tuple[jax.Array, jax.Array]:
@@ -641,6 +642,7 @@ def _me(axis_name, node_index):
     return jax.lax.axis_index(axis_name)
 
 
+@jax.named_scope("sdm_permute")
 def _wire_ppermute(x: jax.Array, axis_name, perm) -> jax.Array:
     """The ONE ppermute call site of the transport layer.
 
@@ -728,6 +730,7 @@ def exchange_payload(schedule, payload, decompress, axis_name, *,
                           payload)
 
 
+@jax.named_scope("sdm_draw")
 def _batched_sender_indices(schedule: PermuteSchedule, me, *,
                             base_key: jax.Array, step: jax.Array,
                             nb: int, kb: int) -> jax.Array:
@@ -765,6 +768,7 @@ def fused_pack_applies(block: int, dtype, p) -> bool:
             and jnp.dtype(dtype) == jnp.float32)
 
 
+@jax.named_scope("sdm_pack")
 def _packed_selection(db: jax.Array, p, me, *, base_key: jax.Array,
                       step: jax.Array) -> Tuple[int, jax.Array, jax.Array]:
     """Sender-side packed payload selection: (kb, my_idx, my_vals).
@@ -805,6 +809,7 @@ def _packed_selection(db: jax.Array, p, me, *, base_key: jax.Array,
     return kb, my_idx, my_vals
 
 
+@jax.named_scope("sdm_pack")
 def _packed_exchange(seq: ScheduleSequence, db: jax.Array, unpack, *,
                      axis_name, base_key: jax.Array, step: jax.Array,
                      p, node_index) -> Tuple[jax.Array, jax.Array]:

@@ -300,6 +300,7 @@ def compressor_of(cfg) -> compressor_mod.Compressor:
     raise ValueError(f"unknown mode {cfg.mode}")
 
 
+@jax.named_scope("sdm_mask")
 def masked_grad(grads: PyTree, key: jax.Array, *, sigma: float,
                 clip_c: float | None) -> PyTree:
     """clip (optional, §5 procedure) then Gaussian-mask: g_hat = clip(g) + eta.
@@ -686,6 +687,7 @@ def init_distributed_state(params: PyTree, self_weight,
                     step=jnp.zeros((), jnp.int32), xhat=xhat, nb=nb0)
 
 
+@jax.named_scope("sdm_pack")
 def _plane_payload_exchange(planes: Tuple[jax.Array, ...],
                             comp: compressor_mod.Compressor, *,
                             axis_name, base_key: jax.Array, step, me,
@@ -721,6 +723,7 @@ def _plane_payload_exchange(planes: Tuple[jax.Array, ...],
     return tuple(own), tuple(recv)
 
 
+@jax.named_scope("sdm_pack")
 def _plane_exchange(d_planes: Tuple[jax.Array, ...], *, schedule, axis_name,
                     base_key: jax.Array, step: jax.Array, cfg: SDMConfig,
                     me, node_index=None) -> Tuple[Tuple[jax.Array, ...],
@@ -762,6 +765,7 @@ def _plane_exchange(d_planes: Tuple[jax.Array, ...], *, schedule, axis_name,
     return tuple(own), tuple(nb)
 
 
+@jax.named_scope("sdm_pack")
 def _replica_plane_exchange(d_planes: Tuple[jax.Array, ...], *,
                             useq, axis_name, base_key: jax.Array,
                             step: jax.Array, cfg: SDMConfig, me,
@@ -863,7 +867,10 @@ def distributed_advance(state: SDMState, *, base_key: jax.Array, axis_name,
     own, nb = _plane_exchange(
         state.d, schedule=seq, axis_name=axis_name, base_key=base_key,
         step=state.step, cfg=cfg, me=me, node_index=node_index)
-    x = jax.tree.map(jnp.add, state.x, spec.unpack(own))
+    with jax.named_scope("sdm_mix"):
+        x = jax.tree.map(jnp.add, state.x, spec.unpack(own))
+        s = tuple(s_ + nb_ for s_, nb_ in
+                  zip(state.s, state.nb if cfg.overlap else nb))
     if cfg.overlap:
         # Overlapped transport: this step's mixing consumes the PENDING
         # buffer (last step's exchange result) and the fresh exchange
@@ -873,9 +880,7 @@ def distributed_advance(state: SDMState, *, base_key: jax.Array, axis_name,
         # free to issue collective-permute-start here and sink the
         # matching -done past the entire gradient computation of the
         # next iteration.
-        s = tuple(s_ + p_ for s_, p_ in zip(state.s, state.nb))
         return state._replace(x=x, s=s, nb=tagging.pending_buffer(nb))
-    s = tuple(s_ + nb_ for s_, nb_ in zip(state.s, nb))
     return state._replace(x=x, s=s)
 
 
@@ -982,14 +987,16 @@ def distributed_commit(state: SDMState, grads: PyTree, *, base_key: jax.Array,
     noise_key = jax.random.fold_in(
         gossip.node_round_key(base_key, me, state.step), 0x5eed)
     g = _masked_grad(grads, noise_key, cfg)
-    spec = plane_mod.ParamPlane.for_tree(state.x)
-    xp = spec.pack(state.x)
-    gp = spec.pack(g)
-    # W~ x for node i = W_ii x_i + s_i  (s maintained incrementally on
-    # static schedules, recomputed from the exact replicas on
-    # time-varying ones — either way it carries this round's weights).
-    y = tuple((1.0 - cfg.theta) * x_
-              + cfg.theta * (sw * x_ + s_ - cfg.gamma * g_)
-              for x_, s_, g_ in zip(xp, state.s, gp))
-    d = tuple(y_ - x_ for y_, x_ in zip(y, xp))
+    with jax.named_scope("sdm_mix"):
+        spec = plane_mod.ParamPlane.for_tree(state.x)
+        xp = spec.pack(state.x)
+        gp = spec.pack(g)
+        # W~ x for node i = W_ii x_i + s_i  (s maintained incrementally
+        # on static schedules, recomputed from the exact replicas on
+        # time-varying ones — either way it carries this round's
+        # weights).
+        y = tuple((1.0 - cfg.theta) * x_
+                  + cfg.theta * (sw * x_ + s_ - cfg.gamma * g_)
+                  for x_, s_, g_ in zip(xp, state.s, gp))
+        d = tuple(y_ - x_ for y_, x_ in zip(y, xp))
     return state._replace(d=d, step=state.step + 1)

@@ -80,6 +80,7 @@ def sparsifier_variance(x: jax.Array, p: float) -> jax.Array:
 # Fixed-count ("packed") sparsification: the communication-real variant.
 # --------------------------------------------------------------------------
 
+@jax.named_scope("sdm_draw")
 def fixedk_indices(key: jax.Array, d: int, k: int) -> jax.Array:
     """k distinct uniform indices into [0, d), regenerable from ``key``.
 

@@ -111,6 +111,7 @@ def train(args: argparse.Namespace, cfg) -> Optional[TrainRun]:
     from repro.core import gossip, method as method_mod
     from repro.core.sdm_dsgd import SDMConfig
     from repro.data import TokenStream
+    from repro.launch.compile_cache import compile_counts
     from repro.launch.mesh import make_mesh_by_name, node_axis_names
     from repro.train import steps as steps_mod
 
@@ -176,11 +177,14 @@ def train(args: argparse.Namespace, cfg) -> Optional[TrainRun]:
         return out
 
     # the state is donated: the step never holds two copies of it
+    before = dict(compile_counts())
     t0 = time.perf_counter()
     compiled = jax.jit(steps_mod.make_distributed_train(tc, mesh),
                        donate_argnums=0).lower(state, *step_args(0)).compile()
     compile_s = time.perf_counter() - t0
-    print(f"compiled train step in {compile_s:.2f}s", flush=True)
+    cache = {k: round(v - before[k], 3) for k, v in compile_counts().items()}
+    print(f"compiled train step in {compile_s:.2f}s (compile {cache})",
+          flush=True)
 
     losses, step_s = [], []
     for t in range(args.steps):

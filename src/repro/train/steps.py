@@ -261,8 +261,12 @@ def make_distributed_train(tc: DistributedTrainConfig, mesh: Mesh,
 
     def local_grads(params, tokens, labels, context):
         def loss_fn(p):
-            logits, aux = transformer.forward(p, cfg, tokens, context=context)
-            return transformer.lm_loss(logits, labels, cfg.vocab_size, aux)
+            # the backward pass keeps the scope: transpose(jvp(model_fwd))
+            with jax.named_scope("model_fwd"):
+                logits, aux = transformer.forward(p, cfg, tokens,
+                                                  context=context)
+                return transformer.lm_loss(logits, labels, cfg.vocab_size,
+                                           aux)
 
         loss, grads = jax.value_and_grad(loss_fn)(params)
         return grads, loss
