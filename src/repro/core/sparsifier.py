@@ -39,6 +39,10 @@ __all__ = [
     "bernoulli_mask",
     "bernoulli_sparsify",
     "fixedk_indices",
+    "fixedk_mask",
+    "score_keys",
+    "topk_mask",
+    "topk_of_keys",
     "fixedk_pack",
     "fixedk_unpack",
     "fixedk_sparsify",
@@ -90,6 +94,90 @@ def fixedk_indices(key: jax.Array, d: int, k: int) -> jax.Array:
     scores = jax.random.uniform(key, (d,))
     _, idx = jax.lax.top_k(scores, k)
     return idx
+
+
+# ``jax.random.uniform`` makes a float32 score from 32 random bits as
+# (bits >> 9) / 2**23, exactly: the scores' order is that of the 23-bit
+# integer keys bits >> 9.
+SCORE_BITS = 23
+# keys per block of the tie count in ``topk_mask``
+TIE_BLOCK = 1024
+
+
+@jax.named_scope("sdm_draw")
+def score_keys(key: jax.Array, d: int) -> jax.Array:
+    """(d,) int32 keys in [0, 2**SCORE_BITS) ranked as the scores of
+    ``fixedk_indices(key, d, k)``: one draw serves both ``topk_mask`` and
+    ``topk_of_keys``.
+    """
+    bits = jax.random.bits(key, (d,), jnp.uint32)
+    # one copy of the keys in memory: without the barrier XLA recomputes
+    # their producer (the random bits) inside every pass that reads them
+    return jax.lax.optimization_barrier(
+        (bits >> (32 - SCORE_BITS)).astype(jnp.int32))
+
+
+@jax.named_scope("sdm_draw")
+def fixedk_mask(key: jax.Array, d: int, k) -> jax.Array:
+    """(d,) bool mask of exactly the set ``fixedk_indices(key, d, k)`` holds.
+
+    The same scores ranked by counting instead of sorting (see
+    ``topk_mask``); ``k`` may be a traced int32 scalar.
+    """
+    return topk_mask(score_keys(key, d), k, SCORE_BITS)
+
+
+@jax.named_scope("sdm_draw")
+def topk_of_keys(m: jax.Array, k: int) -> jax.Array:
+    """``fixedk_indices``' index list from its ``score_keys``: ``lax.top_k``
+    of the same float32 scores, m / 2**SCORE_BITS, in the same order."""
+    _, idx = jax.lax.top_k(m.astype(jnp.float32) * 2.0 ** -SCORE_BITS, k)
+    return idx
+
+
+@jax.named_scope("sdm_draw")
+def topk_mask(m: jax.Array, k, bits: int) -> jax.Array:
+    """Mask of the set ``lax.top_k(m, k)`` selects, without a sort.
+
+    ``m`` holds (d,) int32 keys in [0, 2**bits). ``top_k`` keeps every key
+    above the k-th largest key t, and of the keys equal to t the r
+    lowest-index ones, r = k - count(m > t) (ties go to the lower index).
+    t is found by a 16-way search, each step one pass over ``m`` that
+    counts the keys at or above 15 thresholds (ceil(bits / 4) passes);
+    the index cut by one pass that counts the ties in each block of
+    ``TIE_BLOCK`` keys, then a running count within the one block that
+    holds the r-th tie. No sort, no full-length cumsum.
+    """
+    d = m.shape[0]
+    k = jnp.asarray(k, jnp.int32)
+
+    def count(pred):
+        return jnp.sum(pred, dtype=jnp.int32)
+
+    # t: count(m >= lo) >= k > count(m >= lo + width) = n_hi
+    lo, n_hi, width_bits = jnp.int32(0), jnp.int32(0), bits
+    while width_bits:
+        ways = 1 << min(4, width_bits)
+        width_bits -= min(4, width_bits)
+        step = 1 << width_bits
+        at_or_above = jnp.stack([count(m >= lo + j * step)
+                                 for j in range(1, ways)])
+        j = count(at_or_above >= k)       # thresholds that keep >= k keys
+        n_hi = jnp.where(j < ways - 1,
+                         at_or_above[jnp.minimum(j, ways - 2)], n_hi)
+        lo = lo + j * step
+    t, r = lo, k - n_hi          # r >= 1: the ties at t that top_k keeps
+    # c: the index just past the r-th tie
+    blocks = -(-d // TIE_BLOCK)
+    tie = jnp.pad(m == t, (0, blocks * TIE_BLOCK - d)).reshape(blocks, -1)
+    ties_through = jnp.cumsum(jnp.sum(tie, axis=1, dtype=jnp.int32))
+    b = count(ties_through < r)          # the block that holds the r-th tie
+    before = jnp.where(b > 0, ties_through[jnp.maximum(b - 1, 0)], 0)
+    row = jax.lax.dynamic_index_in_dim(tie, b, keepdims=False)
+    c = b * TIE_BLOCK + count(
+        before + jnp.cumsum(row, dtype=jnp.int32) < r) + 1
+    iota = jax.lax.iota(jnp.int32, d)
+    return (m > t) | ((m == t) & (iota < c))
 
 
 def fixedk_pack(x_flat: jax.Array, idx: jax.Array, d: int) -> jax.Array:

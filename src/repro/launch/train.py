@@ -141,20 +141,26 @@ def train(args: argparse.Namespace, cfg) -> Optional[TrainRun]:
     sched = steps_mod.gossip_schedule(tc, mesh)
     mcfg = tc.resolved()[1]
     pack = ""
-    if isinstance(mcfg, SDMConfig) and mcfg.mode == "fixedk_packed":
+    fixedk = isinstance(mcfg, SDMConfig) and mcfg.mode in (
+        "fixedk_packed", "fixedk_rows")
+    if fixedk and mcfg.mode == "fixedk_packed":
         fused = gossip.fused_pack_applies(mcfg.pack_block, jnp.float32,
                                           mcfg.p)
         pack = " fixedk_pack=" + ("kernel" if fused else "xla-gather")
+    if fixedk:
+        # a node's own S(d) is a dense select over a mask-drawn keep set
+        pack += " own_sdm=mask-select"
 
-    print(f"arch={cfg.name} mesh={dict(zip(mesh.axis_names, mesh.devices.shape))} "
-          f"nodes={n_nodes} method={meth_name} p={args.p} theta={args.theta} "
-          f"compressor={args.compressor or sdm_cfg.mode} "
-          f"topology={sched.name} gossip_rounds={sched.n_rounds} "
-          f"batch={batch} seq={seq}{pack}"
-          + (f" time_varying_L={sched.length}" if sched.length > 1 else "")
-          + (" overlap=on" if args.overlap else ""), flush=True)
+    banner = (f"arch={cfg.name} mesh={dict(zip(mesh.axis_names, mesh.devices.shape))} "
+              f"nodes={n_nodes} method={meth_name} p={args.p} theta={args.theta} "
+              f"compressor={args.compressor or sdm_cfg.mode} "
+              f"topology={sched.name} gossip_rounds={sched.n_rounds} "
+              f"batch={batch} seq={seq}{pack}")
+    tail = ((f" time_varying_L={sched.length}" if sched.length > 1 else "")
+            + (" overlap=on" if args.overlap else ""))
 
     if args.sim:
+        print(banner + tail, flush=True)
         _run_simulated(args, cfg, sdm_cfg, meth_name, n_nodes, batch, seq)
         return None
 
@@ -176,11 +182,19 @@ def train(args: argparse.Namespace, cfg) -> Optional[TrainRun]:
                 jnp.full(shape, 0.01, tc.param_dtype), on_nodes))
         return out
 
-    # the state is donated: the step never holds two copies of it
+    # the banner follows the step's tracing, so that it can say how many
+    # sorted (top_k) keep-set draws the built step holds
     before = dict(compile_counts())
+    drawn = dict(gossip.draw_counts())
     t0 = time.perf_counter()
-    compiled = jax.jit(steps_mod.make_distributed_train(tc, mesh),
-                       donate_argnums=0).lower(state, *step_args(0)).compile()
+    # the state is donated: the step never holds two copies of it
+    lowered = jax.jit(steps_mod.make_distributed_train(tc, mesh),
+                      donate_argnums=0).lower(state, *step_args(0))
+    if fixedk:
+        banner += (" topk_draws="
+                   f"{gossip.draw_counts()['top_k'] - drawn['top_k']}")
+    print(banner + tail, flush=True)
+    compiled = lowered.compile()
     compile_s = time.perf_counter() - t0
     cache = {k: round(v - before[k], 3) for k, v in compile_counts().items()}
     print(f"compiled train step in {compile_s:.2f}s (compile {cache})",

@@ -129,3 +129,70 @@ def test_sparsify_support_subset_property(p, seed):
     xs = np.asarray(x)
     nz = out != 0
     np.testing.assert_allclose(out[nz], xs[nz] / p, rtol=1e-5)
+
+
+def _topk_set(idx, d):
+    mask = np.zeros(d, bool)
+    mask[np.asarray(idx)] = True
+    return mask
+
+
+@pytest.mark.parametrize("d,k", [(1, 1), (2, 1), (2, 2), (7, 3), (127, 1),
+                                 (127, 127), (333, 83), (1000, 1000),
+                                 (4097, 819), (65539, 13108)])
+def test_fixedk_mask_is_the_topk_set(d, k):
+    """fixedk_mask holds exactly fixedk_indices' set: the same scores,
+    ranked by counting (k = 1, k = d, d not a multiple of 128)."""
+    for seed in range(3):
+        key = jax.random.PRNGKey(seed)
+        mask = np.asarray(sparsifier.fixedk_mask(key, d, k))
+        assert mask.dtype == bool and mask.shape == (d,)
+        np.testing.assert_array_equal(
+            mask, _topk_set(sparsifier.fixedk_indices(key, d, k), d))
+
+
+def test_score_keys_order_the_uniform_scores():
+    """jax.random.uniform's float32 scores are (bits >> 9) / 2**23 bit for
+    bit, so ranking the integer keys ranks the scores."""
+    key = jax.random.PRNGKey(11)
+    u = np.asarray(jax.random.uniform(key, (1 << 16,)))
+    bits = np.asarray(jax.random.bits(key, (1 << 16,), jnp.uint32))
+    keys = bits >> (32 - sparsifier.SCORE_BITS)
+    np.testing.assert_array_equal(
+        u, keys.astype(np.float32) / 2 ** sparsifier.SCORE_BITS)
+
+
+def test_fixedk_mask_traced_k():
+    """k as a traced int32 scalar: one program serves every k."""
+    d = 1031
+    key = jax.random.PRNGKey(12)
+    draw = jax.jit(lambda k: sparsifier.fixedk_mask(key, d, k))
+    for k in (1, 2, 200, 515, 1030, 1031):
+        np.testing.assert_array_equal(
+            np.asarray(draw(jnp.int32(k))),
+            _topk_set(sparsifier.fixedk_indices(key, d, k), d))
+    assert draw._cache_size() == 1
+
+
+@pytest.mark.parametrize("d", [1, 5, 100, 1031, 5003])
+def test_topk_mask_tie_heavy_keys(d):
+    """Keys in [0, 8): most elements tie at the threshold, and lax.top_k
+    keeps the lowest-index ones; the counting search must too."""
+    rng = np.random.default_rng(d)
+    m = jnp.asarray(rng.integers(0, 8, d), jnp.int32)
+    for k in sorted({1, max(1, d // 3), max(1, d // 2), d - 1 or 1, d}):
+        np.testing.assert_array_equal(
+            np.asarray(sparsifier.topk_mask(m, k, 3)),
+            _topk_set(jax.lax.top_k(m, k)[1], d), err_msg=f"k={k}")
+
+
+@pytest.mark.parametrize("d,k", [(1, 1), (127, 1), (333, 83), (4097, 819)])
+def test_topk_of_keys_is_fixedk_indices(d, k):
+    """The wire's index list from the one draw of score keys is
+    fixedk_indices' list, order included."""
+    for seed in range(3):
+        key = jax.random.PRNGKey(seed)
+        np.testing.assert_array_equal(
+            np.asarray(sparsifier.topk_of_keys(
+                sparsifier.score_keys(key, d), k)),
+            np.asarray(sparsifier.fixedk_indices(key, d, k)))

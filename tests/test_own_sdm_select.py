@@ -1,0 +1,127 @@
+"""A node's own S(d) in the packed transports: a keep set drawn as a mask
+(``sparsifier.fixedk_mask``) and applied as a dense select.
+
+* On 4 CPU devices (subprocess, ``helpers/own_sdm_check.py``): the own
+  S(d) of every packed transport equals the node's payload scattered back
+  into a zero plane, bit for bit — fixed-k at block 1 and 128, rows,
+  per-node p, and the replica (union) transport — and the 4-node step
+  still draws its wire index lists with ``top_k``.
+* On one device: the step holds no sort, and no gather or scatter of
+  the plane, and the launcher's banner says so.
+"""
+import contextlib
+import io
+import os
+import pathlib
+import re
+import subprocess
+import sys
+
+import jax.numpy as jnp
+import pytest
+
+from repro import configs
+from repro.core import gossip, sparsifier
+from repro.launch import hlo_analysis
+
+HELPER = pathlib.Path(__file__).parent / "helpers" / "own_sdm_check.py"
+SRC = str(pathlib.Path(__file__).parent.parent / "src")
+
+OWN_CASES = ["packed_b1", "packed_b1_bf16", "packed_b128", "rows", "hetp_b1",
+             "hetp_b128", "union_b1", "union_hetp_b128", "union_rows"]
+
+
+@pytest.fixture(scope="module")
+def four_nodes() -> dict:
+    out = subprocess.run(
+        [sys.executable, str(HELPER)], capture_output=True, text=True,
+        env=dict(os.environ, PYTHONPATH=SRC, JAX_PLATFORMS="cpu"),
+        timeout=900)
+    assert out.returncode == 0, out.stderr[-3000:]
+    lines = {}
+    for line in out.stdout.splitlines():
+        toks = line.split()
+        if toks and toks[0] == "RANDOM":
+            lines.setdefault("RANDOM", {})[toks[1]] = int(toks[2])
+        elif toks and toks[0] in ("CASE", "DRAWS"):
+            key = toks[1] if toks[0] == "CASE" else "DRAWS"
+            rest = toks[2:] if toks[0] == "CASE" else toks[1:]
+            lines[key] = {k: int(v) for k, v in zip(rest[::2], rest[1::2])}
+    return lines
+
+
+@pytest.mark.parametrize("case", OWN_CASES)
+def test_own_sdm_equals_scattered_payload(four_nodes, case):
+    got = four_nodes[case]
+    assert got["EQUAL"] == 1, got
+    assert got["NONZERO"] > 0, got
+
+
+@pytest.mark.parametrize("case", ["packed_b128", "union_b1"])
+def test_sender_draws_its_round_key_once(four_nodes, case):
+    """The own S(d)'s mask and the payload's top_k read one draw of the
+    node's round key: two draws in all, with the senders' batched one."""
+    assert four_nodes["RANDOM"][case] == 2, four_nodes["RANDOM"]
+
+
+def test_four_node_step_keeps_wire_topk_draws(four_nodes):
+    draws = four_nodes["DRAWS"]
+    assert draws["own_mask"] >= 1, draws
+    assert draws["top_k"] >= 1, draws
+    assert draws["SORT"] >= 1, draws
+
+
+def test_one_node_own_sdm_needs_no_wire_draw():
+    """With no gossip round the transport draws no index list at all."""
+    one = gossip.sequence_by_name("ring", 1)
+    d = jnp.linspace(-1.0, 1.0, 1000)
+    before = dict(gossip.draw_counts())
+    own, nb_sum = gossip.exchange_packed(
+        one, d, axis_name="data", base_key=jnp.zeros(2, jnp.uint32),
+        step=jnp.int32(0), p=0.3, node_index=jnp.int32(0))
+    after = gossip.draw_counts()
+    assert after["top_k"] == before["top_k"]
+    assert after["own_mask"] == before["own_mask"] + 1
+    assert int((own != 0).sum()) == sparsifier.num_kept(1000, 0.3)
+    assert not nb_sum.any()
+
+
+@pytest.fixture(scope="module")
+def one_node_run():
+    """The launcher's fixed-k run on a one-device mesh, and its stdout."""
+    from repro.launch import train as train_mod
+
+    args = train_mod.parse_args([
+        "--arch", "chatglm3-6b", "--smoke", "--method", "sdm-dsgd",
+        "--gossip-mode", "fixedk_packed", "--p", "0.2", "--sigma", "0.5",
+        "--clip-c", "1.0", "--topology", "ring", "--mesh", "1",
+        "--global-batch", "1", "--seq-len", "16", "--steps", "1"])
+    buf = io.StringIO()
+    with contextlib.redirect_stdout(buf):
+        run = train_mod.train(args, configs.get_smoke_config("chatglm3-6b"))
+    return run, buf.getvalue()
+
+
+def test_one_node_step_has_no_sort_gather_or_scatter_of_plane(one_node_run):
+    run, _ = one_node_run
+    hlo = run.compiled.as_text()
+    # XLA's CPU backend lowers top_k to a TopK custom call, the TPU's to a
+    # sort
+    assert hlo_analysis.instruction_counts(hlo).get("sort", 0) == 0
+    assert 'custom_call_target="TopK"' not in hlo
+    [plane] = run.state.d
+    d = plane[0].size
+    kb = sparsifier.num_kept(d, 0.2)
+    for line in hlo.splitlines():
+        if re.search(r"\s(gather|scatter)\(", line):
+            dims = {int(v) for v in re.findall(r"\d+", line.split("=")[1]
+                                                .split("(")[0])}
+            assert not dims & {d, kb}, line
+
+
+def test_banner_reports_own_side_and_topk_draws(one_node_run):
+    _, out = one_node_run
+    [banner] = [ln for ln in out.splitlines() if ln.startswith("arch=")]
+    assert "fixedk_pack=xla-gather own_sdm=mask-select topk_draws=0" \
+        in banner, banner
+    assert "gossip_rounds=0" in banner
