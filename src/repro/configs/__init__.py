@@ -17,6 +17,7 @@ ARCH_IDS = [
     "phi3_medium_14b",
     "rwkv6_3b",
     "chatglm3_6b",
+    "moonlight_16b_a3b",
 ]
 
 # public ids (with dashes/dots) -> module name
@@ -31,6 +32,7 @@ ALIASES = {
     "phi3-medium-14b": "phi3_medium_14b",
     "rwkv6-3b": "rwkv6_3b",
     "chatglm3-6b": "chatglm3_6b",
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
 }
 
 

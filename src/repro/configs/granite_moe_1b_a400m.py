@@ -23,5 +23,5 @@ def smoke_config() -> ModelConfig:
         n_layers=2, d_model=128, n_heads=4, n_kv_heads=2, head_dim=32,
         d_ff=64, vocab_size=512,
         period=_PERIOD,
-        n_experts=4, top_k=2, d_ff_expert=64, vocab_pad_multiple=16, capacity_factor=16.0,
+        n_experts=4, top_k=2, d_ff_expert=64, vocab_pad_multiple=16,
     )

@@ -37,5 +37,5 @@ def smoke_config() -> ModelConfig:
         d_ff=256, vocab_size=512,
         period=_period(moe=True),
         n_experts=4, top_k=2, d_ff_expert=128,
-        pos_embedding="none", vocab_pad_multiple=16, capacity_factor=16.0,
+        pos_embedding="none", vocab_pad_multiple=16,
     )

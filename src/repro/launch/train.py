@@ -113,6 +113,7 @@ def train(args: argparse.Namespace, cfg) -> Optional[TrainRun]:
     from repro.data import TokenStream
     from repro.launch.compile_cache import compile_counts
     from repro.launch.mesh import make_mesh_by_name, node_axis_names
+    from repro.models import moe
     from repro.train import steps as steps_mod
 
     meth_name = method_mod.normalize(
@@ -186,6 +187,7 @@ def train(args: argparse.Namespace, cfg) -> Optional[TrainRun]:
     # sorted (top_k) keep-set draws the built step holds
     before = dict(compile_counts())
     drawn = dict(gossip.draw_counts())
+    moe_before = dict(moe.layer_counts())
     t0 = time.perf_counter()
     # the state is donated: the step never holds two copies of it
     lowered = jax.jit(steps_mod.make_distributed_train(tc, mesh),
@@ -193,6 +195,11 @@ def train(args: argparse.Namespace, cfg) -> Optional[TrainRun]:
     if fixedk:
         banner += (" topk_draws="
                    f"{gossip.draw_counts()['top_k'] - drawn['top_k']}")
+    if cfg.has_moe:
+        # traced MoE layer bodies (a scanned period's slot once), their
+        # held and routed experts, and their grouped matmuls
+        banner += " moe=" + ",".join(
+            f"{k}:{v - moe_before[k]}" for k, v in moe.layer_counts().items())
     print(banner + tail, flush=True)
     compiled = lowered.compile()
     compile_s = time.perf_counter() - t0
@@ -204,12 +211,13 @@ def train(args: argparse.Namespace, cfg) -> Optional[TrainRun]:
     for t in range(args.steps):
         fn_args = step_args(t)
         t0 = time.perf_counter()
-        state, loss = compiled(state, *fn_args)
+        state, loss, *rows = compiled(state, *fn_args)
         jax.block_until_ready((state, loss))
         step_s.append(time.perf_counter() - t0)
         losses.append(float(loss))
-        print(f"step {t:4d} loss {losses[-1]:.4f} ({step_s[-1]:.3f}s)",
-              flush=True)
+        routed = f" moe_rows {int(rows[0])}" if rows else ""
+        print(f"step {t:4d} loss {losses[-1]:.4f}{routed} "
+              f"({step_s[-1]:.3f}s)", flush=True)
 
     if args.checkpoint_dir:
         save_checkpoint(args.checkpoint_dir, args.steps, state)

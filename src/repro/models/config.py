@@ -9,9 +9,9 @@ __all__ = ["ModelConfig", "LayerSpec"]
 
 @dataclasses.dataclass(frozen=True)
 class LayerSpec:
-    """One layer inside the repeating period.
+    """One layer: a slot of the repeating period, or a leading layer.
 
-    mixer: 'attn' | 'attn_local' | 'mamba' | 'rwkv'
+    mixer: 'attn' | 'attn_local' | 'mla' (latent attention) | 'mamba' | 'rwkv'
     ffn:   'mlp' | 'moe' | None (rwkv has its own channel-mix; use 'rwkv_ffn')
     cross_attn: insert a cross-attention sub-block (enc-dec / VLM layers).
     """
@@ -33,8 +33,10 @@ class ModelConfig:
     vocab_size: int
     head_dim: Optional[int] = None   # defaults to d_model // n_heads
 
-    # repeating layer structure; n_layers % len(period) == 0
+    # layer structure: the leading layers run once each, in order, then
+    # the period repeats; (n_layers - len(prefix)) % len(period) == 0
     period: Tuple[LayerSpec, ...] = (LayerSpec(),)
+    prefix: Tuple[LayerSpec, ...] = ()
 
     # attention details
     rope_theta: float = 10_000.0
@@ -46,16 +48,28 @@ class ModelConfig:
     post_block_norm: bool = False    # gemma2 post-norms
     attn_chunk_q: Optional[int] = None     # q-chunked attention block size
 
+    # latent attention (mla; DeepSeek-V3): q from d_model directly, k/v
+    # up-projected from a normed kv_lora_rank latent; q/k heads are
+    # qk_nope_head_dim + qk_rope_head_dim wide, v heads v_head_dim
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+
     # MLP
     act: str = "silu"                # silu (SwiGLU) | gelu (GeGLU / plain)
     glu: bool = True
 
-    # MoE
+    # MoE: the router scores all n_experts; this chip holds experts
+    # [expert_offset, expert_offset + experts_held) (0 held: all of them)
     n_experts: int = 0
     top_k: int = 0
     d_ff_expert: int = 0
-    capacity_factor: float = 1.25
-    router_group_size: int = 128     # tokens per dispatch group
+    experts_held: int = 0
+    expert_offset: int = 0
+    n_shared_experts: int = 0        # one shared SwiGLU n_shared x d_ff_expert wide
+    router_scoring: str = "softmax"  # softmax | sigmoid (bias-corrected top-k)
+    routed_scaling_factor: float = 1.0
 
     # Mamba (jamba defaults)
     mamba_d_state: int = 16
@@ -86,10 +100,23 @@ class ModelConfig:
     unroll_layers: bool = False      # python-loop the periods (cost probes)
 
     def __post_init__(self) -> None:
-        if self.n_layers % len(self.period) != 0:
+        # a configuration file gives each layer as {"mixer": .., "ffn": ..}
+        for name in ("period", "prefix"):
+            object.__setattr__(self, name, tuple(
+                LayerSpec(**s) if isinstance(s, dict) else s
+                for s in getattr(self, name)))
+        if (self.n_layers - len(self.prefix)) % len(self.period) != 0:
             raise ValueError(
-                f"{self.name}: n_layers={self.n_layers} not divisible by "
+                f"{self.name}: n_layers={self.n_layers} less "
+                f"{len(self.prefix)} leading layers not divisible by "
                 f"period length {len(self.period)}")
+        if self.n_experts and not (
+                0 <= self.expert_offset
+                and self.expert_offset + self.n_held_experts <= self.n_experts):
+            raise ValueError(
+                f"{self.name}: experts [{self.expert_offset}, "
+                f"{self.expert_offset + self.n_held_experts}) not within "
+                f"{self.n_experts}")
 
     # ---- derived ---------------------------------------------------------
     @property
@@ -98,7 +125,24 @@ class ModelConfig:
 
     @property
     def n_periods(self) -> int:
-        return self.n_layers // len(self.period)
+        return (self.n_layers - len(self.prefix)) // len(self.period)
+
+    @property
+    def layers(self) -> Tuple[LayerSpec, ...]:
+        """Every layer in order: the leading ones, then the periods."""
+        return self.prefix + self.period * self.n_periods
+
+    @property
+    def n_held_experts(self) -> int:
+        return self.experts_held or self.n_experts
+
+    @property
+    def has_moe(self) -> bool:
+        return any(s.ffn == "moe" for s in self.layers)
+
+    @property
+    def mla_qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
 
     @property
     def padded_vocab(self) -> int:
@@ -125,7 +169,7 @@ class ModelConfig:
     def supports_long_context(self) -> bool:
         """True when decode memory/compute is sub-quadratic-safe at 500k:
         SSM/hybrid state-space layers, or sliding-window local attention."""
-        kinds = {s.mixer for s in self.period}
+        kinds = {s.mixer for s in self.layers}
         if kinds <= {"mamba", "rwkv"}:
             return True
         if "mamba" in kinds or "rwkv" in kinds:
@@ -142,10 +186,17 @@ class ModelConfig:
         total = self.padded_vocab * d  # embed
         if not self.tie_embeddings:
             total += self.padded_vocab * d
-        for spec in self.period * self.n_periods:
+        glu = 3 if self.glu else 2
+        for spec in self.layers:
             if spec.mixer in ("attn", "attn_local"):
                 total += d * (self.n_heads + 2 * self.n_kv_heads) * hd
                 total += self.n_heads * hd * d
+            elif spec.mixer == "mla":
+                r, h = self.kv_lora_rank, self.n_heads
+                total += d * h * self.mla_qk_head_dim
+                total += d * (r + self.qk_rope_head_dim) + r
+                total += r * h * (self.qk_nope_head_dim + self.v_head_dim)
+                total += h * self.v_head_dim * d
             elif spec.mixer == "mamba":
                 di = self.mamba_d_inner
                 total += d * 2 * di + di * self.mamba_d_conv
@@ -157,9 +208,10 @@ class ModelConfig:
                 total += d * (self.n_heads + 2 * self.n_kv_heads) * hd
                 total += self.n_heads * hd * d
             if spec.ffn == "mlp":
-                total += d * self.d_ff * (3 if self.glu else 2)
+                total += d * self.d_ff * glu
             elif spec.ffn == "moe":
-                total += self.n_experts * d * self.d_ff_expert * (3 if self.glu else 2)
+                total += self.n_held_experts * d * self.d_ff_expert * glu
+                total += self.n_shared_experts * d * self.d_ff_expert * glu
                 total += d * self.n_experts
             elif spec.ffn == "rwkv_ffn":
                 total += int(d * d * 3.5 * 2)
@@ -169,11 +221,13 @@ class ModelConfig:
         return total
 
     def active_param_count(self) -> int:
-        """Params touched per token (MoE counts top_k of n_experts)."""
+        """Params touched per token (MoE counts the held experts' share
+        of top_k of n_experts)."""
         if self.n_experts == 0:
             return self.param_count()
         total = self.param_count()
-        moe_layers = sum(1 for s in self.period if s.ffn == "moe") * self.n_periods
-        full = self.n_experts * self.d_model * self.d_ff_expert * (3 if self.glu else 2)
-        active = self.top_k * self.d_model * self.d_ff_expert * (3 if self.glu else 2)
-        return total - moe_layers * (full - active)
+        moe_layers = sum(1 for s in self.layers if s.ffn == "moe")
+        per_expert = self.d_model * self.d_ff_expert * (3 if self.glu else 2)
+        held = self.n_held_experts
+        active = self.top_k * held / self.n_experts
+        return int(total - moe_layers * (held - active) * per_expert)

@@ -16,8 +16,9 @@ from repro.models.config import ModelConfig
 from repro.sharding import logical
 
 __all__ = ["ParamSpec", "init_tree", "axes_of", "shapes_of",
-           "rms_norm", "rope", "attention_specs", "attention_apply",
-           "attention_decode_paged", "mlp_specs", "mlp_apply", "KVCache",
+           "rms_norm", "rope", "attention_specs", "mla_specs",
+           "attention_apply",
+           "attention_decode_paged", "mlp_specs", "mlp_apply", "ffn", "KVCache",
            "softcap"]
 
 PyTree = Any
@@ -145,8 +146,10 @@ def _sdpa(q: jax.Array, k: jax.Array, v: jax.Array, *,
           kv_valid_len: Optional[jax.Array] = None) -> jax.Array:
     """Grouped-query scaled dot-product attention.
 
-    q: (b, sq, h, hd); k/v: (b, skv, kv, hd). positions give absolute token
-    indices for masking (decode: q_position = current pos).
+    q: (b, sq, h, hd); k: (b, skv, kv, hd); v: (b, skv, kv, hd_v), where
+    latent attention's value heads are narrower than its q/k heads.
+    positions give absolute token indices for masking (decode:
+    q_position = current pos).
     """
     b, sq, h, hd = q.shape
     kvh = k.shape[2]
@@ -165,7 +168,7 @@ def _sdpa(q: jax.Array, k: jax.Array, v: jax.Array, *,
     scores = jnp.where(mask[:, None, None, :, :], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
     out = jnp.einsum("bkgqs,bskd->bqkgd", probs, v)
-    return out.reshape(b, sq, h, hd)
+    return out.reshape(b, sq, h, v.shape[-1])
 
 
 def _sdpa_chunked(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -173,10 +176,12 @@ def _sdpa_chunked(q: jax.Array, k: jax.Array, v: jax.Array, *,
                   causal: bool, window: Optional[int],
                   softcap_val: Optional[float],
                   kv_valid_len: Optional[jax.Array],
-                  chunk: int) -> jax.Array:
+                  chunk: int, remat: bool = False) -> jax.Array:
     """Query-chunked attention: scans q in blocks so the (sq, skv) score
     matrix never materializes whole. XLA analogue of the Pallas flash
-    kernel (used where Pallas cannot lower, e.g. CPU dry-runs)."""
+    kernel (used where Pallas cannot lower, e.g. CPU dry-runs). With
+    ``remat`` the backward pass recomputes each block's scores instead of
+    keeping every block's probabilities (h * sq * skv of them)."""
     b, sq, h, hd = q.shape
     n_chunks = sq // chunk
     assert sq % chunk == 0, (sq, chunk)
@@ -190,14 +195,17 @@ def _sdpa_chunked(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     kv_valid_len=kv_valid_len)
         return None, out
 
+    if remat:
+        body = jax.checkpoint(body)
     _, outs = jax.lax.scan(body, None, (qc, pc))
-    return outs.transpose(1, 0, 2, 3, 4).reshape(b, sq, h, hd)
+    return outs.transpose(1, 0, 2, 3, 4).reshape(b, sq, h, v.shape[-1])
 
 
-def _attend(q, k, v, *, chunk_q: Optional[int] = None, **kw) -> jax.Array:
+def _attend(q, k, v, *, chunk_q: Optional[int] = None, remat: bool = False,
+            **kw) -> jax.Array:
     sq = q.shape[1]
     if chunk_q is not None and sq > chunk_q and sq % chunk_q == 0:
-        return _sdpa_chunked(q, k, v, chunk=chunk_q, **kw)
+        return _sdpa_chunked(q, k, v, chunk=chunk_q, remat=remat, **kw)
     return _sdpa(q, k, v, **kw)
 
 
@@ -237,6 +245,55 @@ def _project_qkv(params: Dict[str, jax.Array], cfg: ModelConfig,
     return residual, q, k, v
 
 
+def mla_specs(cfg: ModelConfig) -> Dict[str, ParamSpec]:
+    """Latent attention (DeepSeek-V3, no q compression): ``wq`` d_model ->
+    heads x (nope | rope); ``wkv_a`` d_model -> the kv latent | one rope
+    key shared by every head; ``wkv_b`` the normed latent -> heads x
+    (k nope | v)."""
+    d, h, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    return {
+        "wq": ParamSpec((d, h * cfg.mla_qk_head_dim), ("embed", "heads_flat")),
+        "wkv_a": ParamSpec((d, r + cfg.qk_rope_head_dim), ("embed", None)),
+        "kv_norm": ParamSpec((r,), (None,), "ones"),
+        "wkv_b": ParamSpec((r, h * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
+                           (None, "heads_flat")),
+        "wo": ParamSpec((h * cfg.v_head_dim, d), ("heads_flat", "embed")),
+        "norm": ParamSpec((d,), ("embed",), "ones"),
+    }
+
+
+# the kv latent's RMSNorm keeps the release's default epsilon, not the
+# model's rms_norm_eps (DeepseekV3RMSNorm(kv_lora_rank))
+MLA_LATENT_EPS = 1e-6
+
+
+def _project_mla(params: Dict[str, jax.Array], cfg: ModelConfig,
+                 x: jax.Array, *, positions: jax.Array):
+    """Latent attention's pre-attention stage. Returns (residual, q, k, v)
+    with q, k: (b, s, h, nope + rope) and v: (b, s, h, v_head_dim); the
+    rope key is computed once and broadcast to every head."""
+    residual = x
+    hn = rms_norm(x, params["norm"], cfg.norm_eps)
+    hn = logical(hn, "batch", "seq", "embed")
+    b, s, _ = x.shape
+    h, nope, rd = cfg.n_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    q = jnp.einsum("bsd,de->bse", hn, params["wq"])
+    q = logical(q, "batch", "seq", "heads_flat").reshape(b, s, h, nope + rd)
+    ckv = jnp.einsum("bsd,de->bse", hn, params["wkv_a"])
+    latent, k_rot = ckv[..., :cfg.kv_lora_rank], ckv[..., cfg.kv_lora_rank:]
+    latent = rms_norm(latent, params["kv_norm"], MLA_LATENT_EPS)
+    kv = jnp.einsum("bsr,re->bse", latent, params["wkv_b"])
+    kv = logical(kv, "batch", "seq", "heads_flat").reshape(
+        b, s, h, nope + cfg.v_head_dim)
+    k_nope, v = kv[..., :nope], kv[..., nope:]
+    q_rot = rope(q[..., nope:], positions, cfg.rope_theta)
+    k_rot = rope(k_rot[:, :, None, :], positions, cfg.rope_theta)
+    q = jnp.concatenate([q[..., :nope], q_rot], axis=-1)
+    k = jnp.concatenate(
+        [k_nope, jnp.broadcast_to(k_rot, (b, s, h, rd))], axis=-1)
+    return residual, q, k, v
+
+
 def _project_out(params: Dict[str, jax.Array], cfg: ModelConfig,
                  out: jax.Array, residual: jax.Array) -> jax.Array:
     """Shared post-attention stage: head merge, output projection,
@@ -270,9 +327,16 @@ def attention_apply(params: Dict[str, jax.Array], cfg: ModelConfig,
     its token at its OWN next position and attends only its own valid
     prefix, so right-padded unequal-length prompts stay exact.
     Cross-attention: pass ``kv_source`` (encoder / image states).
+    Latent attention (``layer_kind="mla"``, self-attention only) caches
+    the per-head k and v it expands from its latent; the caller names it
+    with the ``mla_attn`` scope.
     """
-    residual, q, k, v = _project_qkv(params, cfg, x, positions=positions,
-                                     kv_source=kv_source, use_rope=use_rope)
+    if layer_kind == "mla":
+        residual, q, k, v = _project_mla(params, cfg, x, positions=positions)
+    else:
+        residual, q, k, v = _project_qkv(params, cfg, x, positions=positions,
+                                         kv_source=kv_source,
+                                         use_rope=use_rope)
 
     window = cfg.sliding_window if layer_kind == "attn_local" else None
     new_cache = None
@@ -286,7 +350,7 @@ def attention_apply(params: Dict[str, jax.Array], cfg: ModelConfig,
                       softcap_val=cfg.attn_softcap, kv_valid_len=None)
     elif cache is None:
         kv_pos = positions
-        out = _attend(q, k, v, chunk_q=cfg.attn_chunk_q,
+        out = _attend(q, k, v, chunk_q=cfg.attn_chunk_q, remat=cfg.remat,
                       q_positions=positions, kv_positions=kv_pos,
                       causal=causal, window=window,
                       softcap_val=cfg.attn_softcap, kv_valid_len=None)
@@ -391,11 +455,9 @@ def _activation(x: jax.Array, act: str) -> jax.Array:
     raise ValueError(act)
 
 
-def mlp_apply(params: Dict[str, jax.Array], cfg: ModelConfig,
-              x: jax.Array) -> jax.Array:
-    residual = x
-    h = rms_norm(x, params["norm"], cfg.norm_eps, plus_one=cfg.post_block_norm)
-    h = logical(h, "batch", "seq", "embed")
+def ffn(params: Dict[str, jax.Array], cfg: ModelConfig,
+        h: jax.Array) -> jax.Array:
+    """The feed-forward projections of normed ``h`` (b, s, d)."""
     up = jnp.einsum("bsd,df->bsf", h, params["w_up"])
     if cfg.glu:
         gate = _activation(jnp.einsum("bsd,df->bsf", h, params["w_gate"]),
@@ -405,7 +467,15 @@ def mlp_apply(params: Dict[str, jax.Array], cfg: ModelConfig,
         up = _activation(up, cfg.act)
     up = logical(up, "batch", "seq", "mlp")
     out = jnp.einsum("bsf,fd->bsd", up, params["w_down"])
-    out = logical(out, "batch", "seq", "embed")
+    return logical(out, "batch", "seq", "embed")
+
+
+def mlp_apply(params: Dict[str, jax.Array], cfg: ModelConfig,
+              x: jax.Array) -> jax.Array:
+    residual = x
+    h = rms_norm(x, params["norm"], cfg.norm_eps, plus_one=cfg.post_block_norm)
+    h = logical(h, "batch", "seq", "embed")
+    out = ffn(params, cfg, h)
     if cfg.post_block_norm:
         out = rms_norm(out, params["post_norm"], cfg.norm_eps, plus_one=True)
     return residual + out

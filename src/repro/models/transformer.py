@@ -1,10 +1,12 @@
 """Model composition: embeddings + scanned layer periods + heads.
 
 The layer stack is expressed as a repeating *period* of LayerSpecs
-(config.py). Parameters for each slot in the period are stacked over a
-leading ``layers`` axis (n_periods entries) and the whole stack runs
-under one ``jax.lax.scan`` — a single compiled layer body regardless of
-depth, which keeps HLO small at 64 layers / 512 devices.
+(config.py), after any leading layers (``prefix``; DeepSeek-V3's dense
+first layer), which run once each. Parameters for each slot in the
+period are stacked over a leading ``layers`` axis (n_periods entries)
+and the whole stack runs under one ``jax.lax.scan`` — a single compiled
+layer body regardless of depth, which keeps HLO small at 64 layers /
+512 devices.
 
 Supports: train forward, prefill (builds caches), single-token decode.
 Encoder-decoder (whisper) and VLM cross-attention take pre-computed
@@ -12,6 +14,7 @@ Encoder-decoder (whisper) and VLM cross-attention take pre-computed
 """
 from __future__ import annotations
 
+import contextlib
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
@@ -23,13 +26,13 @@ from repro.models import rwkv as rwkv_mod
 from repro.models.config import LayerSpec, ModelConfig
 from repro.models.layers import (KVCache, ParamSpec, attention_apply,
                                  attention_decode_paged, attention_specs,
-                                 axes_of, init_tree, mlp_apply, mlp_specs,
-                                 rms_norm, shapes_of, softcap)
+                                 axes_of, init_tree, mla_specs, mlp_apply,
+                                 mlp_specs, rms_norm, shapes_of, softcap)
 from repro.sharding import logical
 
 __all__ = ["model_specs", "init_params", "param_axes", "param_shapes",
-           "forward", "lm_loss", "init_cache", "prefill", "decode_step",
-           "decode_step_paged", "Cache"]
+           "forward", "forward_and_rows", "lm_loss", "init_cache", "prefill",
+           "decode_step", "decode_step_paged", "Cache"]
 
 PyTree = Any
 
@@ -42,6 +45,8 @@ def _slot_specs(cfg: ModelConfig, spec: LayerSpec) -> Dict[str, Any]:
     out: Dict[str, Any] = {}
     if spec.mixer in ("attn", "attn_local"):
         out["attn"] = attention_specs(cfg)
+    elif spec.mixer == "mla":
+        out["attn"] = mla_specs(cfg)
     elif spec.mixer == "mamba":
         out["mamba"] = mamba_mod.mamba_specs(cfg)
     elif spec.mixer == "rwkv":
@@ -78,6 +83,9 @@ def model_specs(cfg: ModelConfig) -> Dict[str, Any]:
             for i, s in enumerate(cfg.period)
         },
     }
+    if cfg.prefix:
+        specs["prefix"] = {str(i): _slot_specs(cfg, s)
+                           for i, s in enumerate(cfg.prefix)}
     if not cfg.tie_embeddings:
         specs["lm_head"] = ParamSpec((d, cfg.padded_vocab), ("embed", "vocab"))
     if cfg.pos_embedding == "learned":
@@ -112,54 +120,65 @@ def param_shapes(cfg: ModelConfig) -> PyTree:
 # --------------------------------------------------------------------------
 
 class Cache(NamedTuple):
-    """Per-slot caches, each stacked over the period axis (n_periods, ...)."""
+    """Per-slot caches, each stacked over the period axis (n_periods, ...),
+    and the leading layers' caches (``prefix``, unstacked)."""
     slots: Dict[str, Any]
     offset: jax.Array  # () int32 — number of tokens already in the cache
+    prefix: Dict[str, Any] = {}
 
 
 def _slot_cache(cfg: ModelConfig, spec: LayerSpec, batch: int, max_len: int,
-                dtype) -> Any:
-    n = cfg.n_periods
+                dtype, lead: Tuple[int, ...]) -> Any:
     if spec.mixer in ("attn", "attn_local"):
         kv, hd = cfg.n_kv_heads, cfg.resolved_head_dim
-        shape = (n, batch, max_len, kv, hd)
+        shape = lead + (batch, max_len, kv, hd)
         return KVCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
+    if spec.mixer == "mla":
+        # per-head k and v, expanded from the latent
+        shape = lead + (batch, max_len, cfg.n_heads)
+        return KVCache(k=jnp.zeros(shape + (cfg.mla_qk_head_dim,), dtype),
+                       v=jnp.zeros(shape + (cfg.v_head_dim,), dtype))
     if spec.mixer == "mamba":
         st = mamba_mod.init_mamba_state(cfg, batch, dtype)
-        return jax.tree.map(lambda a: jnp.broadcast_to(a, (n,) + a.shape), st)
+        return jax.tree.map(lambda a: jnp.broadcast_to(a, lead + a.shape), st)
     if spec.mixer == "rwkv":
         st = rwkv_mod.init_rwkv_state(cfg, batch, dtype)
-        return jax.tree.map(lambda a: jnp.broadcast_to(a, (n,) + a.shape), st)
+        return jax.tree.map(lambda a: jnp.broadcast_to(a, lead + a.shape), st)
     raise ValueError(spec.mixer)
 
 
 def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype) -> Cache:
     return Cache(
-        slots={str(i): _slot_cache(cfg, s, batch, max_len, dtype)
+        slots={str(i): _slot_cache(cfg, s, batch, max_len, dtype,
+                                   (cfg.n_periods,))
                for i, s in enumerate(cfg.period)},
-        offset=jnp.zeros((), jnp.int32))
+        offset=jnp.zeros((), jnp.int32),
+        prefix={str(i): _slot_cache(cfg, s, batch, max_len, dtype, ())
+                for i, s in enumerate(cfg.prefix)})
 
 
 def cache_logical_axes(cfg: ModelConfig) -> Cache:
     """Logical axes tree matching init_cache's structure."""
-    def slot_axes(spec: LayerSpec):
-        if spec.mixer in ("attn", "attn_local"):
-            a = ("layers", "batch", "cache_seq", "kv_heads", None)
+    def slot_axes(spec: LayerSpec, lead: Tuple[str, ...]):
+        if spec.mixer in ("attn", "attn_local", "mla"):
+            a = lead + ("batch", "cache_seq", "kv_heads", None)
             return KVCache(k=a, v=a)
         if spec.mixer == "mamba":
             return mamba_mod.MambaState(
-                conv=("layers", "batch", None, "mlp"),
-                ssm=("layers", "batch", "mlp", None))
+                conv=lead + ("batch", None, "mlp"),
+                ssm=lead + ("batch", "mlp", None))
         if spec.mixer == "rwkv":
             return rwkv_mod.RWKVState(
-                att_shift=("layers", "batch", "embed"),
-                ffn_shift=("layers", "batch", "embed"),
-                wkv=("layers", "batch", "heads", None, None))
+                att_shift=lead + ("batch", "embed"),
+                ffn_shift=lead + ("batch", "embed"),
+                wkv=lead + ("batch", "heads", None, None))
         raise ValueError(spec.mixer)
 
-    return Cache(slots={str(i): slot_axes(s)
+    return Cache(slots={str(i): slot_axes(s, ("layers",))
                         for i, s in enumerate(cfg.period)},
-                 offset=())
+                 offset=(),
+                 prefix={str(i): slot_axes(s, ())
+                         for i, s in enumerate(cfg.prefix)})
 
 
 # --------------------------------------------------------------------------
@@ -212,23 +231,35 @@ def _encode_context(params, cfg: ModelConfig,
     return rms_norm(x, enc["final_norm"], cfg.norm_eps)
 
 
+def _mixer_scope(spec: LayerSpec):
+    """Latent attention runs under the ``mla_attn`` span."""
+    if spec.mixer == "mla":
+        return jax.named_scope("mla_attn")
+    return contextlib.nullcontext()
+
+
 def _apply_slot_full(cfg: ModelConfig, spec: LayerSpec, slot_params,
                      x: jax.Array, positions: jax.Array,
                      context: Optional[jax.Array],
                      init_state, want_state: bool):
-    """One layer slot over a full sequence. Returns (x, aux, new_state)."""
+    """One layer slot over a full sequence. Returns (x, aux, rows,
+    new_state); ``rows`` counts an MoE layer's rows routed to held
+    experts."""
     aux = jnp.zeros((), jnp.float32)
+    rows = jnp.zeros((), jnp.int32)
     state = None
-    if spec.mixer in ("attn", "attn_local"):
-        if want_state:
-            # prefill: write this call's k/v into the provided cache
-            x, state = attention_apply(
-                slot_params["attn"], cfg, x, positions=positions,
-                layer_kind=spec.mixer, cache=init_state,
-                cache_offset=jnp.zeros((), jnp.int32))
-        else:
-            x, _ = attention_apply(slot_params["attn"], cfg, x,
-                                   positions=positions, layer_kind=spec.mixer)
+    if spec.mixer in ("attn", "attn_local", "mla"):
+        with _mixer_scope(spec):
+            if want_state:
+                # prefill: write this call's k/v into the provided cache
+                x, state = attention_apply(
+                    slot_params["attn"], cfg, x, positions=positions,
+                    layer_kind=spec.mixer, cache=init_state,
+                    cache_offset=jnp.zeros((), jnp.int32))
+            else:
+                x, _ = attention_apply(slot_params["attn"], cfg, x,
+                                       positions=positions,
+                                       layer_kind=spec.mixer)
     elif spec.mixer == "mamba":
         if want_state:
             x, state = mamba_mod.mamba_apply(slot_params["mamba"], cfg, x,
@@ -249,11 +280,11 @@ def _apply_slot_full(cfg: ModelConfig, spec: LayerSpec, slot_params,
     if spec.ffn == "mlp":
         x = mlp_apply(slot_params["mlp"], cfg, x)
     elif spec.ffn == "moe":
-        x, aux = moe_mod.moe_apply(slot_params["moe"], cfg, x)
+        x, aux, rows = moe_mod.moe_apply(slot_params["moe"], cfg, x)
     elif spec.ffn == "rwkv_ffn":
         x, state = rwkv_mod.rwkv_channel_mix(slot_params["channel_mix"], cfg,
                                              x, state)
-    return x, aux, state
+    return x, aux, rows, state
 
 
 
@@ -274,10 +305,25 @@ def _scan_periods(cfg: ModelConfig, body, init_carry, xs):
     return carry, stacked
 
 
+def _remat(cfg: ModelConfig, fn):
+    if not cfg.remat:
+        return fn
+    return jax.checkpoint(fn, policy=jax.checkpoint_policies.nothing_saveable)
+
+
 def forward(params: PyTree, cfg: ModelConfig, tokens: jax.Array, *,
             context: Optional[jax.Array] = None
             ) -> Tuple[jax.Array, jax.Array]:
     """Training forward. tokens: (b, s) -> (logits (b, s, V), aux_loss)."""
+    logits, aux, _ = forward_and_rows(params, cfg, tokens, context=context)
+    return logits, aux
+
+
+def forward_and_rows(params: PyTree, cfg: ModelConfig, tokens: jax.Array, *,
+                     context: Optional[jax.Array] = None
+                     ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """``forward``, and the rows routed to held experts summed over the MoE
+    layers (int32; 0 for a model without them)."""
     b, s = tokens.shape
     x = _embed_tokens(params, cfg, tokens)
     positions = jnp.broadcast_to(jnp.arange(s), (b, s))
@@ -285,19 +331,29 @@ def forward(params: PyTree, cfg: ModelConfig, tokens: jax.Array, *,
         x = x + params["pos_embed"][:s][None]
     ctx = _encode_context(params, cfg, context)
 
+    aux = jnp.zeros((), jnp.float32)
+    rows = jnp.zeros((), jnp.int32)
+    for i, spec in enumerate(cfg.prefix):
+        def leading(x, p, spec=spec):
+            x, a, r, _ = _apply_slot_full(cfg, spec, p, x, positions, ctx,
+                                          None, False)
+            return x, (a, r)
+        x, (a, r) = _remat(cfg, leading)(x, params["prefix"][str(i)])
+        aux, rows = aux + a, rows + r
+
     def period_body(x, period_params):
         aux = jnp.zeros((), jnp.float32)
+        rows = jnp.zeros((), jnp.int32)
         for i, spec in enumerate(cfg.period):
-            x, a, _ = _apply_slot_full(cfg, spec, period_params[str(i)], x,
-                                       positions, ctx, None, False)
-            aux = aux + a
-        return x, aux
+            x, a, r, _ = _apply_slot_full(cfg, spec, period_params[str(i)],
+                                          x, positions, ctx, None, False)
+            aux, rows = aux + a, rows + r
+        return x, (aux, rows)
 
-    if cfg.remat:
-        period_body = jax.checkpoint(
-            period_body, policy=jax.checkpoint_policies.nothing_saveable)
-    x, auxs = _scan_periods(cfg, period_body, x, params["blocks"])
-    return _logits(params, cfg, x), jnp.sum(auxs)
+    x, (auxs, period_rows) = _scan_periods(cfg, _remat(cfg, period_body), x,
+                                           params["blocks"])
+    return (_logits(params, cfg, x), aux + jnp.sum(auxs),
+            rows + jnp.sum(period_rows))
 
 
 def lm_loss(logits: jax.Array, labels: jax.Array, vocab_size: int,
@@ -335,14 +391,22 @@ def prefill(params: PyTree, cfg: ModelConfig, tokens: jax.Array,
         x = x + params["pos_embed"][:s][None]
     ctx = _encode_context(params, cfg, context)
 
+    def layer(x, spec, slot_params, slot_cache):
+        x, _, _, st = _apply_slot_full(cfg, spec, slot_params, x, positions,
+                                       ctx, slot_cache, True)
+        return x, st if st is not None else slot_cache
+
+    new_prefix = {}
+    for i, spec in enumerate(cfg.prefix):
+        x, new_prefix[str(i)] = layer(x, spec, params["prefix"][str(i)],
+                                      cache.prefix[str(i)])
+
     def period_body(x, scanned):
         period_params, period_cache = scanned
         new_cache = {}
         for i, spec in enumerate(cfg.period):
-            x, _, st = _apply_slot_full(cfg, spec, period_params[str(i)], x,
-                                        positions, ctx,
-                                        period_cache[str(i)], True)
-            new_cache[str(i)] = st if st is not None else period_cache[str(i)]
+            x, new_cache[str(i)] = layer(x, spec, period_params[str(i)],
+                                         period_cache[str(i)])
         return x, new_cache
 
     x, new_slots = _scan_periods(cfg, period_body, x,
@@ -353,7 +417,8 @@ def prefill(params: PyTree, cfg: ModelConfig, tokens: jax.Array,
         x_last = jnp.take_along_axis(x, last_index[:, None, None], axis=1)
     logits = _logits(params, cfg, x_last)
     return logits[:, 0, :], Cache(slots=new_slots,
-                                  offset=jnp.asarray(s, jnp.int32))
+                                  offset=jnp.asarray(s, jnp.int32),
+                                  prefix=new_prefix)
 
 
 def decode_step(params: PyTree, cfg: ModelConfig, token: jax.Array,
@@ -383,42 +448,48 @@ def decode_step(params: PyTree, cfg: ModelConfig, token: jax.Array,
                 params["pos_embed"], cache.offset, 1, axis=0)[None]
     ctx = context
 
+    def layer(x, spec, sp, pc):
+        if spec.mixer in ("attn", "attn_local", "mla"):
+            with _mixer_scope(spec):
+                x, pc = attention_apply(sp["attn"], cfg, x,
+                                        positions=positions,
+                                        layer_kind=spec.mixer, cache=pc,
+                                        cache_offset=cache.offset,
+                                        cache_offsets=offsets)
+        elif spec.mixer == "mamba":
+            x, pc = mamba_mod.mamba_decode_step(sp["mamba"], cfg, x, pc)
+        elif spec.mixer == "rwkv":
+            x, pc = rwkv_mod.rwkv_time_mix_step(sp["time_mix"], cfg, x, pc)
+        if spec.cross_attn and ctx is not None:
+            x, _ = attention_apply(sp["cross"], cfg, x,
+                                   positions=positions, kv_source=ctx)
+        if spec.ffn == "mlp":
+            x = mlp_apply(sp["mlp"], cfg, x)
+        elif spec.ffn == "moe":
+            x, _, _ = moe_mod.moe_apply(sp["moe"], cfg, x)
+        elif spec.ffn == "rwkv_ffn":
+            x, pc = rwkv_mod.rwkv_channel_mix_step(sp["channel_mix"], cfg, x,
+                                                   pc)
+        return x, pc
+
+    new_prefix = {}
+    for i, spec in enumerate(cfg.prefix):
+        x, new_prefix[str(i)] = layer(x, spec, params["prefix"][str(i)],
+                                      cache.prefix[str(i)])
+
     def period_body(x, scanned):
         period_params, period_cache = scanned
         new_cache = {}
         for i, spec in enumerate(cfg.period):
-            sp = period_params[str(i)]
-            pc = period_cache[str(i)]
-            if spec.mixer in ("attn", "attn_local"):
-                x, kvc = attention_apply(sp["attn"], cfg, x,
-                                         positions=positions,
-                                         layer_kind=spec.mixer, cache=pc,
-                                         cache_offset=cache.offset,
-                                         cache_offsets=offsets)
-                new_cache[str(i)] = kvc
-            elif spec.mixer == "mamba":
-                x, mst = mamba_mod.mamba_decode_step(sp["mamba"], cfg, x, pc)
-                new_cache[str(i)] = mst
-            elif spec.mixer == "rwkv":
-                x, rst = rwkv_mod.rwkv_time_mix_step(sp["time_mix"], cfg, x, pc)
-                new_cache[str(i)] = rst
-            if spec.cross_attn and ctx is not None:
-                x, _ = attention_apply(sp["cross"], cfg, x,
-                                       positions=positions, kv_source=ctx)
-            if spec.ffn == "mlp":
-                x = mlp_apply(sp["mlp"], cfg, x)
-            elif spec.ffn == "moe":
-                x, _ = moe_mod.moe_apply(sp["moe"], cfg, x)
-            elif spec.ffn == "rwkv_ffn":
-                x, rst2 = rwkv_mod.rwkv_channel_mix_step(
-                    sp["channel_mix"], cfg, x, new_cache[str(i)])
-                new_cache[str(i)] = rst2
+            x, new_cache[str(i)] = layer(x, spec, period_params[str(i)],
+                                         period_cache[str(i)])
         return x, new_cache
 
     x, new_slots = _scan_periods(cfg, period_body, x,
                                  (params["blocks"], cache.slots))
     logits = _logits(params, cfg, x)
-    return logits[:, 0, :], Cache(slots=new_slots, offset=cache.offset + 1)
+    return logits[:, 0, :], Cache(slots=new_slots, offset=cache.offset + 1,
+                                  prefix=new_prefix)
 
 
 def decode_step_paged(params: PyTree, cfg: ModelConfig, token: jax.Array,
@@ -443,6 +514,11 @@ def decode_step_paged(params: PyTree, cfg: ModelConfig, token: jax.Array,
     jitted function with no host round-trips — the serving engine's
     done-mask bookkeeping composes around it on device.
     """
+    if cfg.prefix or any(s.mixer == "mla" for s in cfg.period):
+        raise NotImplementedError(
+            f"{cfg.name}: the paged cache holds the period's attn and "
+            f"attn_local layers only; leading layers and latent attention "
+            f"(mla) decode through the dense cache (decode_step)")
     b = token.shape[0]
     x = _embed_tokens(params, cfg, token[:, None])
     positions = offsets[:, None]
@@ -475,7 +551,7 @@ def decode_step_paged(params: PyTree, cfg: ModelConfig, token: jax.Array,
             if spec.ffn == "mlp":
                 x = mlp_apply(sp["mlp"], cfg, x)
             elif spec.ffn == "moe":
-                x, _ = moe_mod.moe_apply(sp["moe"], cfg, x)
+                x, _, _ = moe_mod.moe_apply(sp["moe"], cfg, x)
             elif spec.ffn == "rwkv_ffn":
                 x, new_rec[si] = rwkv_mod.rwkv_channel_mix_step(
                     sp["channel_mix"], cfg, x, new_rec[si])

@@ -102,6 +102,11 @@ class ServingEngine:
                  max_seq: int = 256, dtype=jnp.float32, page_size: int = 16,
                  n_pages: Optional[int] = None,
                  use_flash: Optional[bool] = None, sync_every: int = 1):
+        if cfg.prefix or any(s.mixer == "mla" for s in cfg.period):
+            raise NotImplementedError(
+                f"{cfg.name}: the paged cache has no latent attention (mla) "
+                f"or leading layers; serve it through "
+                f"transformer.prefill/decode_step")
         self.cfg = cfg
         self.params = params
         self.max_batch = max_batch
@@ -314,6 +319,11 @@ class StaticServingEngine:
 
     def __init__(self, cfg: ModelConfig, params, *, max_batch: int = 8,
                  max_seq: int = 256, dtype=jnp.float32):
+        if cfg.prefix or any(s.mixer == "mla" for s in cfg.period):
+            raise NotImplementedError(
+                f"{cfg.name}: the paged cache has no latent attention (mla) "
+                f"or leading layers; serve it through "
+                f"transformer.prefill/decode_step")
         self.cfg = cfg
         self.params = params
         self.max_batch = max_batch

@@ -238,7 +238,9 @@ def batch_sharding(mesh: Mesh) -> NamedSharding:
 def make_distributed_train(tc: DistributedTrainConfig, mesh: Mesh,
                            base_key: Optional[jax.Array] = None
                            ) -> Callable:
-    """Returns train_step(state, tokens, labels[, context]) -> (state, metrics).
+    """Returns train_step(state, tokens, labels[, context]) -> (state, loss),
+    or (state, loss, moe_rows) for a model with MoE layers: the rows
+    routed to the experts each node holds, summed over layers and nodes.
 
     tokens/labels: (global_batch, seq) sharded over the node axes.
     """
@@ -263,13 +265,14 @@ def make_distributed_train(tc: DistributedTrainConfig, mesh: Mesh,
         def loss_fn(p):
             # the backward pass keeps the scope: transpose(jvp(model_fwd))
             with jax.named_scope("model_fwd"):
-                logits, aux = transformer.forward(p, cfg, tokens,
-                                                  context=context)
+                logits, aux, rows = transformer.forward_and_rows(
+                    p, cfg, tokens, context=context)
                 return transformer.lm_loss(logits, labels, cfg.vocab_size,
-                                           aux)
+                                           aux), rows
 
-        loss, grads = jax.value_and_grad(loss_fn)(params)
-        return grads, loss
+        (loss, rows), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+            params)
+        return grads, ((loss, rows) if cfg.has_moe else loss)
 
     def node_step(state, tokens, labels, context, node_ids):
         """Per-node body; runs under shard_map with `axis` manual.
@@ -288,18 +291,24 @@ def make_distributed_train(tc: DistributedTrainConfig, mesh: Mesh,
         # layout cannot diverge from the state it receives.
         with use_rules(inner), _bucket_ctx(tc, mesh):
             state = squeeze(state)
-            state, loss = executor.step(
+            state, out = executor.step(
                 state,
                 lambda p: local_grads(p, tokens, labels, context),
                 base_key=base_key, node_index=me)
 
         # the training loss IS data-derived; averaging it over nodes is a
         # deliberate release (the metric), declared so the taint auditor
-        # reports it instead of flagging the psum.
+        # reports it instead of flagging the psum. So is the count of
+        # rows the router sent to this node's experts.
+        loss, rows = out if cfg.has_moe else (out, None)
         loss = jax.lax.pmean(tagging.declared_release(loss, label="loss"),
                              axis)
         unsqueeze = lambda t: jax.tree.map(lambda v: v[None], t)
-        return unsqueeze(state), loss
+        if not cfg.has_moe:
+            return unsqueeze(state), loss
+        rows = jax.lax.psum(tagging.declared_release(rows, label="moe_rows"),
+                            axis)
+        return unsqueeze(state), loss, rows
 
     state_specs = jax.tree.map(lambda _: P(axis), state_shape_dtype(tc, mesh))
     data_spec = P(axis)
@@ -313,7 +322,7 @@ def make_distributed_train(tc: DistributedTrainConfig, mesh: Mesh,
         fn = jax.shard_map(
             node_step, mesh=mesh,
             in_specs=in_specs,
-            out_specs=(state_specs, P()),
+            out_specs=(state_specs, P()) + ((P(),) if cfg.has_moe else ()),
             axis_names=set(node_axes), check_vma=False)
         return fn(state, tokens, labels, context, node_ids)
 

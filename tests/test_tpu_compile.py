@@ -85,3 +85,27 @@ def test_paged_flash_decode_compiles_for_v5e(one_chip):
         s((batch, KV_HEADS, GROUP, HEAD_DIM), jnp.bfloat16), pages, pages,
         s((batch, n_blocks), jnp.int32), s((batch,), jnp.int32))
     assert "tpu_custom_call" in text
+
+
+def test_moe_grouped_matmul_compiles_for_v5e(one_chip):
+    """Moonlight-16B-A3B's expert layer at its widths (8 held of 64
+    experts, 1408 wide, top 6) over 8,192 tokens, forward and backward:
+    the held experts' rows run through XLA's ragged-dot kernels."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.models import moe
+
+    cfg = dataclasses.replace(get_config("moonlight-16b-a3b"), experts_held=8)
+    s = lambda shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                                           sharding=one_chip)
+    params = jax.tree.map(lambda spec: s(spec.shape), moe.moe_specs(cfg),
+                          is_leaf=lambda v: hasattr(v, "axes"))
+
+    def loss(p, x):
+        out, _, rows = moe.moe_apply(p, cfg, x)
+        return jnp.sum(out.astype(jnp.float32)), rows
+
+    text = _compiled_text(jax.grad(loss, has_aux=True), params,
+                          s((1, 8192, cfg.d_model)))
+    assert "ragged-dot" in text
