@@ -72,10 +72,12 @@ FUSED_PACK = os.environ.get("REPRO_FUSED_PACK", "1") != "0"
 
 # Trace-time record of the fixed-k keep-set draws built into programs:
 # ``own_mask`` counts own S(d) keep sets drawn as masks
-# (``_own_and_payload``), ``top_k`` the sorted index-list draws a step
-# keeps (the wire payload's, and the receivers' batched regeneration of
-# their senders'). Read with ``draw_counts``.
-_DRAWS = {"own_mask": 0, "top_k": 0}
+# (``_own_and_payload``); ``compact`` the wire's index lists a step keeps,
+# each a counted mask compacted to ascending order (the sender's payload
+# list, and one per sender the receivers regenerate); ``top_k`` the
+# sorted index-list draws, which no packed transport makes. Read with
+# ``draw_counts``.
+_DRAWS = {"own_mask": 0, "compact": 0, "top_k": 0}
 
 __all__ = [
     "mix_dense",
@@ -555,14 +557,14 @@ def _union_packed_exchange(useq: UnionSchedule, db: jax.Array, to_leaf, *,
     the static ``_packed_exchange`` transport (same keys,
     same pad-to-max-k heterogeneous-p payloads), but each union round's
     received values are unpacked into their OWN increment row instead of
-    a weighted sum — one batched sender top_k per (leaf, step) regardless
-    of sequence length.
+    a weighted sum — one batched draw of the senders' index lists per
+    (leaf, step) regardless of sequence length.
     """
     nb_blocks = db.shape[0]
     me = _me(axis_name, node_index)
     own_sparse, kb, my_vals = _own_and_payload(
         db, to_leaf, p, me, base_key=base_key, step=step)
-    _DRAWS["top_k"] += 1
+    _DRAWS["compact"] += 1
     sender_idx = _batched_sender_indices(
         useq, me, base_key=base_key, step=step, nb=nb_blocks, kb=kb)
     incr = jnp.stack([
@@ -736,31 +738,38 @@ def exchange_payload(schedule, payload, decompress, axis_name, *,
 def _batched_sender_indices(schedule: PermuteSchedule, me, *,
                             base_key: jax.Array, step: jax.Array,
                             nb: int, kb: int) -> jax.Array:
-    """All this-step senders' index sets from ONE shared uniform draw.
+    """All this-step senders' index lists, drawn as the senders drew them.
 
-    Every shift round of a step exchanges the same leaf, so the per-step
-    draw is shared: one (R, nb) batched uniform + one batched top_k
-    replaces R separate draw+sort dispatches (one per round). Bit-equal
-    to the per-round regeneration — vmapped PRNG draws and row-batched
-    top_k match the scalar calls exactly — so trajectories are unchanged.
-    Returns (n_rounds, kb) indices, row i for the shift of round i.
+    Every shift round of a step exchanges the same leaf, so the draw is
+    batched over the R senders: their (R, nb) score keys, each row's top
+    kb set ranked by counting (``sparsifier.topk_mask``, vmapped: the
+    search is traced once) and compacted to ascending order
+    (``sparsifier.mask_indices``). Vmapped PRNG draws equal the scalar
+    ones, so row i is bit for bit the list the shift-s sender of round i
+    packed its payload by (``_packed_selection``). Returns (n_rounds, kb)
+    indices.
     """
     n = schedule.n_nodes
     shifts = jnp.asarray([rnd.shift for rnd in schedule.rounds], jnp.int32)
     senders = jnp.mod(me - shifts, n)
     keys = jax.vmap(lambda j: node_round_key(base_key, j, step))(senders)
-    scores = jax.vmap(lambda k: jax.random.uniform(k, (nb,)))(keys)
-    _, idx = jax.lax.top_k(scores, kb)
-    _DRAWS["top_k"] += 1
-    return idx
+
+    def sender_list(key):
+        keep = sparsifier.topk_mask(sparsifier.score_keys(key, nb), kb,
+                                    sparsifier.SCORE_BITS)
+        return sparsifier.mask_indices(keep, kb)
+
+    _DRAWS["compact"] += len(schedule.rounds)
+    return jax.vmap(sender_list)(keys)
 
 
 def draw_counts() -> dict:
     """The fixed-k keep-set draws traced so far in this process:
-    ``{"own_mask": ..., "top_k": ...}`` (see ``_DRAWS``). Counted when a
-    step is traced, per draw site, so a reading taken before and after
-    lowering a step says what that step holds: 0 ``top_k`` on a schedule
-    with no gossip rounds. The dict is live; copy it to keep a reading.
+    ``{"own_mask": ..., "compact": ..., "top_k": ...}`` (see ``_DRAWS``).
+    Counted when a step is traced, per draw site, so a reading taken
+    before and after lowering a step says what that step holds: 0
+    ``compact`` on a schedule with no gossip rounds. The dict is live;
+    copy it to keep a reading.
     """
     return _DRAWS
 
@@ -800,7 +809,7 @@ def _own_and_payload(db: jax.Array, to_leaf, p, me, *,
                      ) -> Tuple[jax.Array, int, jax.Array]:
     """(own S(d) in the leaf's shape, kb, packed payload) from the node's
     ONE draw of the round: the score keys of ``node_round_key(base, me,
-    step)``, read twice.
+    step)``, ranked once for the own keep set.
 
     * The own S(d) is the top kb_me keys — the payload's rows it does not
       zero — found as a mask (``sparsifier.topk_mask``: no sort) and
@@ -809,7 +818,7 @@ def _own_and_payload(db: jax.Array, to_leaf, p, me, *,
       times the same scale nb_blocks / kb_me.
     * The payload (``_packed_selection``) is the wire's alone: on a
       schedule with no round it is dead code, which XLA drops with its
-      top_k.
+      index list.
     """
     nb_blocks = db.shape[0]
     kb, kb_me = _kept(nb_blocks, p, me)
@@ -827,15 +836,19 @@ def _own_and_payload(db: jax.Array, to_leaf, p, me, *,
     own = jax.lax.optimization_barrier(
         jnp.where(keep[:, None], (db * scale).astype(db.dtype),
                   jnp.zeros((), db.dtype)))
-    return to_leaf(own), kb, _packed_selection(db, keys, kb, kb_me, p)
+    return to_leaf(own), kb, _packed_selection(db, keys, keep, kb, kb_me, p)
 
 
 @jax.named_scope("sdm_pack")
-def _packed_selection(db: jax.Array, keys: jax.Array, kb: int, kb_me,
-                      p) -> jax.Array:
-    """Sender-side packed payload: the (kb, block) rows of ``db`` at the
-    top kb ``keys`` (``sparsifier.topk_of_keys``, the index list
-    ``fixedk_indices`` draws), scaled.
+def _packed_selection(db: jax.Array, keys: jax.Array, keep: jax.Array,
+                      kb: int, kb_me, p) -> jax.Array:
+    """Sender-side packed payload: the (kb, block) rows of ``db`` in the
+    top kb set of ``keys``, in ascending row order, scaled.
+
+    The list is a counted mask compacted (``sparsifier.mask_indices``),
+    the one receivers regenerate (``_batched_sender_indices``): the set
+    of ``fixedk_indices``, with no sort. With a scalar ``p`` the set is
+    the own keep set ``keep``, compacted without ranking the keys again.
 
     The ONE implementation shared by the static (``_packed_exchange``)
     and the replica/union (``_union_packed_exchange``) transports, so
@@ -843,20 +856,22 @@ def _packed_selection(db: jax.Array, keys: jax.Array, kb: int, kb_me,
     cannot desynchronize.
 
     ``p`` may be a per-node tuple: the payload then pads to
-    k_max = max_i ceil(p_i * n_blocks) — every node draws k_max top-k
-    indices from its seed, zeroes value rows beyond its OWN k_i and
-    scales kept rows by n_blocks/k_i. Top-k indices are distinct, so the
+    k_max = max_i ceil(p_i * n_blocks) — every node sends the rows of
+    its top k_max keys, zeroes those outside its OWN top k_i (``keep``)
+    and scales the rest by n_blocks/k_i. The rows are distinct, so the
     zero pad rows scatter onto coordinates the sender did not select
     (already zero in S(d)) and receivers need no masking: the wire keeps
     ONE static shape while each node transmits its own budget.
     """
     nb_blocks = db.shape[0]
     if isinstance(p, tuple):
+        my_idx = sparsifier.mask_indices(
+            sparsifier.topk_mask(keys, kb, sparsifier.SCORE_BITS), kb)
         scale = (nb_blocks / kb_me.astype(jnp.float32)) \
-            * (jnp.arange(kb)[:, None] < kb_me)
+            * jnp.take(keep, my_idx)[:, None]
     else:
+        my_idx = sparsifier.mask_indices(keep, kb)
         scale = nb_blocks / kb
-    my_idx = sparsifier.topk_of_keys(keys, kb)
     if db.ndim == 2 and fused_pack_applies(db.shape[1], db.dtype, p):
         # fused sender-side pack: gather + contraction scale in ONE
         # pallas launch (bit-exact to the jnp pair below, so enabling
@@ -867,8 +882,14 @@ def _packed_selection(db: jax.Array, keys: jax.Array, kb: int, kb_me,
 
 
 def _unpack(db: jax.Array, vals: jax.Array, idx: jax.Array) -> jax.Array:
-    """Scatter a received payload's rows back into a zero block view."""
-    return jnp.zeros_like(db).at[idx].set(vals)
+    """Scatter a received payload's rows back into a zero block view.
+
+    ``idx`` is a sender's list, ascending and distinct
+    (``sparsifier.mask_indices``); told so, the TPU's scatter does not
+    sort it first.
+    """
+    return jnp.zeros_like(db).at[idx].set(vals, indices_are_sorted=True,
+                                          unique_indices=True)
 
 
 def _row_view(d: jax.Array) -> jax.Array:
@@ -894,9 +915,9 @@ def _packed_exchange(seq: ScheduleSequence, db: jax.Array, to_leaf, *,
     me = _me(axis_name, node_index)
     own_sparse, kb, my_vals = _own_and_payload(
         db, to_leaf, p, me, base_key=base_key, step=step)
-    # the payload's top_k is left in the built step only where a round
-    # sends it
-    _DRAWS["top_k"] += any(sched.rounds for sched in seq.schedules)
+    # the payload's index list is left in the built step only where a
+    # round sends it
+    _DRAWS["compact"] += any(sched.rounds for sched in seq.schedules)
 
     def nb_for(sched: PermuteSchedule, vals_out: jax.Array) -> jax.Array:
         nb_sum = jnp.zeros_like(own_sparse)

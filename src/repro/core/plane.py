@@ -7,15 +7,15 @@ and ppermuting each parameter leaf separately serializes
 leaf) per gossip step — hundreds of small collectives on a real
 transformer. The standard fix (cf. cpSGD's fixed-budget wire encoding and
 DDP gradient bucketing) is to flatten the whole tree into one contiguous
-**wire plane** and run the compressor / top-k / exchange ONCE per plane:
+**wire plane** and run the compressor / draw / exchange ONCE per plane:
 
     ParamPlane.for_tree(tree)   ->  static layout spec (hashable)
     spec.pack(tree)             ->  tuple of (rows, LANE) f32 planes
     spec.unpack(planes)         ->  tree (original shapes/dtypes)
 
 so a compiled distributed step issues exactly R collective-permutes per
-exchange **independent of the model's leaf count**, and one top-k over
-the whole plane replaces per-leaf ``num_kept`` ceils.
+exchange **independent of the model's leaf count**, and one fixed-k draw
+over the whole plane replaces per-leaf ``num_kept`` ceils.
 
 Buckets
 -------

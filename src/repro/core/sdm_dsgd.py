@@ -36,7 +36,7 @@ Two implementations, bit-for-bit testable against each other:
 
 Wire-plane transport (PR 5): the whole differential is bucketized into
 contiguous ``repro.core.plane`` wire planes and the compressor draw /
-top-k / ppermute rounds run ONCE PER PLANE instead of once per pytree
+index lists / ppermute rounds run ONCE PER PLANE instead of once per pytree
 leaf — a compiled distributed step issues exactly R collective-permutes
 per exchange regardless of the model's leaf count, and the distributed
 state carries ``s`` / ``d`` (and the replica stack ``xhat``) as
@@ -731,7 +731,7 @@ def _plane_exchange(d_planes: Tuple[jax.Array, ...], *, schedule, axis_name,
     """Plane-granular exchange: (own S(d) planes, weighted nb-sum planes).
 
     The ONE static-schedule transport behind every SDM mode: each
-    bucket's plane is compressed/drawn/top-k'd ONCE (key
+    bucket's plane is compressed/drawn ONCE (key
     ``fold_in(base, bucket)`` — the schedule the reference's
     ``sparsify_planes_stacked`` mirrors) and crosses the wire in exactly
     R collective-permutes per bucket, independent of the model's leaf

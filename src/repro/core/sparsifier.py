@@ -42,7 +42,7 @@ __all__ = [
     "fixedk_mask",
     "score_keys",
     "topk_mask",
-    "topk_of_keys",
+    "mask_indices",
     "fixedk_pack",
     "fixedk_unpack",
     "fixedk_sparsify",
@@ -102,13 +102,15 @@ def fixedk_indices(key: jax.Array, d: int, k: int) -> jax.Array:
 SCORE_BITS = 23
 # keys per block of the tie count in ``topk_mask``
 TIE_BLOCK = 1024
+# entries per row of the in-row ranks in ``mask_indices``
+RANK_BLOCK = 128
 
 
 @jax.named_scope("sdm_draw")
 def score_keys(key: jax.Array, d: int) -> jax.Array:
     """(d,) int32 keys in [0, 2**SCORE_BITS) ranked as the scores of
-    ``fixedk_indices(key, d, k)``: one draw serves both ``topk_mask`` and
-    ``topk_of_keys``.
+    ``fixedk_indices(key, d, k)``: ``topk_mask`` ranks them without a
+    sort.
     """
     bits = jax.random.bits(key, (d,), jnp.uint32)
     # one copy of the keys in memory: without the barrier XLA recomputes
@@ -125,14 +127,6 @@ def fixedk_mask(key: jax.Array, d: int, k) -> jax.Array:
     ``topk_mask``); ``k`` may be a traced int32 scalar.
     """
     return topk_mask(score_keys(key, d), k, SCORE_BITS)
-
-
-@jax.named_scope("sdm_draw")
-def topk_of_keys(m: jax.Array, k: int) -> jax.Array:
-    """``fixedk_indices``' index list from its ``score_keys``: ``lax.top_k``
-    of the same float32 scores, m / 2**SCORE_BITS, in the same order."""
-    _, idx = jax.lax.top_k(m.astype(jnp.float32) * 2.0 ** -SCORE_BITS, k)
-    return idx
 
 
 @jax.named_scope("sdm_draw")
@@ -178,6 +172,43 @@ def topk_mask(m: jax.Array, k, bits: int) -> jax.Array:
         before + jnp.cumsum(row, dtype=jnp.int32) < r) + 1
     iota = jax.lax.iota(jnp.int32, d)
     return (m > t) | ((m == t) & (iota < c))
+
+
+@jax.named_scope("sdm_draw")
+def mask_indices(mask: jax.Array, k: int) -> jax.Array:
+    """(k,) int32: the ascending indices of the first k <= d true entries
+    of the (d,) bool ``mask``, as ``jnp.nonzero(mask, size=k)`` lists them;
+    where the mask holds fewer, the rest read d.
+
+    No sort, no scatter and no full-length cumsum. Each kept entry moves
+    left by the s entries not kept before it: s from its count within a
+    row of ``RANK_BLOCK`` entries (one 0/1 matmul with a triangular
+    matrix, exact: counts of at most RANK_BLOCK, accumulated in f32) and
+    the running count of the rows before. The move is made one bit of s
+    at a time, lowest first, each bit one dense pass that shifts the
+    entries with that bit set by its weight: the entries' order is kept,
+    so no two ever land on one place. The entries carry s itself, and the
+    one that ends at place q is index q + s. Exact for any d < 2**31;
+    ``mask`` may come from ``topk_mask`` with a traced k.
+    """
+    d = mask.shape[0]
+    rows = -(-d // RANK_BLOCK)
+    m = jnp.pad(mask, (0, rows * RANK_BLOCK - d)).reshape(rows, RANK_BLOCK)
+    j = jax.lax.iota(jnp.int32, RANK_BLOCK)
+    upper = (j[:, None] <= j[None, :]).astype(jnp.bfloat16)
+    through = jnp.dot(m.astype(jnp.bfloat16), upper,
+                      preferred_element_type=jnp.float32).astype(jnp.int32)
+    count = through[:, -1]
+    rank = (jnp.cumsum(count) - count)[:, None] + through - 1   # kept before
+    index = jax.lax.iota(jnp.int32, rows)[:, None] * RANK_BLOCK + j
+    s = jnp.where(m, index - rank, -1).reshape(-1)[:d]   # -1: no entry
+    for b in range(max(d - 1, 1).bit_length()):
+        moves = (s >= 0) & ((s >> b) & 1 == 1)
+        arriving = jnp.pad(jnp.where(moves, s, -1)[1 << b:], (0, 1 << b),
+                           constant_values=-1)
+        s = jnp.where(moves | (s < 0), arriving, s)
+    s = s[:k]
+    return jnp.where(s >= 0, jax.lax.iota(jnp.int32, k) + s, d)
 
 
 def fixedk_pack(x_flat: jax.Array, idx: jax.Array, d: int) -> jax.Array:

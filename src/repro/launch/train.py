@@ -184,7 +184,7 @@ def train(args: argparse.Namespace, cfg) -> Optional[TrainRun]:
         return out
 
     # the banner follows the step's tracing, so that it can say how many
-    # sorted (top_k) keep-set draws the built step holds
+    # sorted (top_k) and compacted wire index lists the built step holds
     before = dict(compile_counts())
     drawn = dict(gossip.draw_counts())
     moe_before = dict(moe.layer_counts())
@@ -193,8 +193,9 @@ def train(args: argparse.Namespace, cfg) -> Optional[TrainRun]:
     lowered = jax.jit(steps_mod.make_distributed_train(tc, mesh),
                       donate_argnums=0).lower(state, *step_args(0))
     if fixedk:
-        banner += (" topk_draws="
-                   f"{gossip.draw_counts()['top_k'] - drawn['top_k']}")
+        now = gossip.draw_counts()
+        banner += (f" topk_draws={now['top_k'] - drawn['top_k']}"
+                   f" compact_draws={now['compact'] - drawn['compact']}")
     if cfg.has_moe:
         # traced MoE layer bodies (a scanned period's slot once), their
         # held and routed experts, and their grouped matmuls

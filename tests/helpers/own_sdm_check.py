@@ -1,13 +1,16 @@
-"""Subprocess helper: a node's own S(d) from the packed transports, on a
-4-node CPU mesh, against the payload scattered back — the form the own
-side had before it became a dense select — and the draw counter of a
-4-node train step.
+"""Subprocess helper: a node's own S(d) and what it receives from the
+packed transports, on a 4-node CPU mesh, against dense expectations
+built from ``fixedk_indices`` and a scatter — the own S(d) is the
+node's payload scattered back, the form the own side had before it
+became a dense select; the neighbour sum (or the replica transport's
+per-slot increments) is the senders' payloads scattered back and
+weighed — and the draw counter of a 4-node train step.
 
 Run with 4 fake host devices; prints one line per case
 
-    CASE <id> EQUAL <0|1> NONZERO <i>
+    CASE <id> EQUAL <0|1> NONZERO <i> NB_EQUAL <0|1> NB_NONZERO <i>
     RANDOM <id> <i>
-    DRAWS own_mask <i> top_k <i> SORT <i>
+    DRAWS own_mask <i> compact <i> top_k <i> SORT <i>
 
 that tests/test_own_sdm_select.py asserts on. Must set XLA_FLAGS before
 the jax import.
@@ -36,6 +39,7 @@ CASES = {
     "packed_b1_bf16": ("static", (1000,), jnp.bfloat16, 0.3, 1),
     "packed_b128": ("static", (128 * 9 + 17,), jnp.float32, 0.25, 128),
     "rows": ("static_rows", (37, 24), jnp.float32, 0.3, None),
+    "hetp_rows": ("static_rows", (37, 24), jnp.float32, HET_P, None),
     "hetp_b1": ("static", (1000,), jnp.float32, HET_P, 1),
     "hetp_b128": ("static", (128 * 9 + 17,), jnp.float32, HET_P, 128),
     "union_b1": ("union", (1000,), jnp.float32, 0.3, 1),
@@ -67,43 +71,74 @@ def scattered_own(d, p, me, block):
     return to_leaf(jnp.zeros_like(db).at[idx].set(vals))
 
 
+RING = gossip.sequence_by_name("ring", N)
+UNION = gossip.union_schedule(gossip.sequence_by_name("matchings:3", N))
+
+
 def own_of(mesh, name):
     """(the case's leaves on N nodes, the jitted transport returning each
-    node's own S(d))."""
+    node's own S(d) and its neighbour sum or per-slot increments)."""
     transport, shape, dtype, p, block = CASES[name]
     d = jax.random.normal(jax.random.PRNGKey(1), (N,) + shape, jnp.float32
                           ).astype(dtype)
-    ring = gossip.sequence_by_name("ring", N)
-    useq = gossip.union_schedule(gossip.sequence_by_name("matchings:3", N))
     kw = dict(axis_name="data", base_key=BASE_KEY, step=jnp.int32(STEP),
               p=p)
 
     def body(dl):
         dl = dl[0]
         if transport == "static":
-            own, _ = gossip.exchange_packed(ring, dl, block=block, **kw)
+            own, nb = gossip.exchange_packed(RING, dl, block=block, **kw)
         elif transport == "static_rows":
-            own, _ = gossip.exchange_packed_rows(ring, dl, **kw)
+            own, nb = gossip.exchange_packed_rows(RING, dl, **kw)
         elif transport == "union":
-            own, _ = gossip.union_exchange_packed(useq, dl, block=block,
-                                                  **kw)
+            own, nb = gossip.union_exchange_packed(UNION, dl, block=block,
+                                                   **kw)
         else:
-            own, _ = gossip.union_exchange_packed_rows(useq, dl, **kw)
-        return own[None]
+            own, nb = gossip.union_exchange_packed_rows(UNION, dl, **kw)
+        return own[None], nb[None]
 
     return d, jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P("data"),
                                     out_specs=P("data"), check_vma=False))
 
 
+def received(d, name, me):
+    """What node ``me`` receives, from the senders' payloads scattered
+    back: the weighted sum over the ring's rounds, in round order, or
+    one row per union round (zeros where the round has no edge into
+    ``me``)."""
+    transport, _, dtype, p, block = CASES[name]
+    sched = RING.schedules[0] if transport.startswith("static") else UNION
+    rows = []
+    for rnd in sched.rounds:
+        sender = (me - rnd.shift) % N
+        if (sender, me) in rnd.perm:
+            rows.append(scattered_own(d[sender], p, sender, block))
+        else:
+            rows.append(jnp.zeros_like(d[me]))
+    if transport.startswith("union"):
+        return jnp.stack(rows)
+    total = jnp.zeros_like(d[me])
+    for rnd, row in zip(sched.rounds, rows):
+        w = jnp.asarray(rnd.recv_weights, jnp.float32)[me].astype(dtype)
+        total = total + w * row
+    return total
+
+
 def run_case(mesh, name):
     _, _, _, p, block = CASES[name]
     d, exchange = own_of(mesh, name)
-    own = np.asarray(exchange(d).astype(jnp.float32))
+    own, nb = (np.asarray(v.astype(jnp.float32)) for v in exchange(d))
     want = np.stack([np.asarray(scattered_own(d[i], p, i, block)
                                 .astype(jnp.float32)) for i in range(N)])
-    equal = int(np.array_equal(own, want))
-    print(f"CASE {name} EQUAL {equal} NONZERO {int((own != 0).sum())}",
-          flush=True)
+    # jitted as the transport is: XLA's CPU backend fuses the weighted
+    # sum's multiply-adds, which rounds differently from eager ops
+    expect = jax.jit(received, static_argnums=(1, 2))
+    want_nb = np.stack([np.asarray(expect(d, name, i).astype(jnp.float32))
+                        for i in range(N)])
+    print(f"CASE {name} EQUAL {int(np.array_equal(own, want))} "
+          f"NONZERO {int((own != 0).sum())} "
+          f"NB_EQUAL {int(np.array_equal(nb, want_nb))} "
+          f"NB_NONZERO {int((nb != 0).sum())}", flush=True)
 
 
 def random_draws(mesh, name):
@@ -119,7 +154,8 @@ def random_draws(mesh, name):
 
 def step_draws():
     """Draws the 4-node fixed-k train step holds, and its sort and top_k
-    ops."""
+    ops: one own mask, one compacted payload list and one compacted list
+    per sender a plane."""
     from repro import configs
     from repro.core.sdm_dsgd import SDMConfig
     from repro.train import steps
@@ -139,9 +175,8 @@ def step_draws():
     # XLA's CPU backend lowers top_k to a TopK custom call
     sorts = (hlo_analysis.instruction_counts(hlo).get("sort", 0)
              + hlo.count('custom_call_target="TopK"'))
-    print(f"DRAWS own_mask {after['own_mask'] - before['own_mask']} "
-          f"top_k {after['top_k'] - before['top_k']} SORT {sorts}",
-          flush=True)
+    print("DRAWS " + " ".join(f"{k} {after[k] - before[k]}" for k in after)
+          + f" SORT {sorts}", flush=True)
 
 
 def main():

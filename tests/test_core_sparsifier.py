@@ -187,12 +187,41 @@ def test_topk_mask_tie_heavy_keys(d):
 
 
 @pytest.mark.parametrize("d,k", [(1, 1), (127, 1), (333, 83), (4097, 819)])
-def test_topk_of_keys_is_fixedk_indices(d, k):
-    """The wire's index list from the one draw of score keys is
-    fixedk_indices' list, order included."""
+def test_mask_indices_is_sorted_fixedk_indices(d, k):
+    """The wire's index list, a counted mask compacted, is fixedk_indices'
+    set in ascending order."""
     for seed in range(3):
         key = jax.random.PRNGKey(seed)
         np.testing.assert_array_equal(
-            np.asarray(sparsifier.topk_of_keys(
-                sparsifier.score_keys(key, d), k)),
-            np.asarray(sparsifier.fixedk_indices(key, d, k)))
+            np.asarray(sparsifier.mask_indices(
+                sparsifier.fixedk_mask(key, d, k), k)),
+            np.sort(np.asarray(sparsifier.fixedk_indices(key, d, k))))
+
+
+@pytest.mark.parametrize("d", [1, 5, 100, 1031, 5003])
+def test_mask_indices_tie_heavy_keys(d):
+    """Keys in [0, 8): the compacted list is lax.top_k's set, ties at the
+    threshold to the lowest indices, in ascending order."""
+    rng = np.random.default_rng(d)
+    m = jnp.asarray(rng.integers(0, 8, d), jnp.int32)
+    for k in sorted({1, max(1, d // 3), max(1, d // 2), d - 1 or 1, d}):
+        np.testing.assert_array_equal(
+            np.asarray(sparsifier.mask_indices(
+                sparsifier.topk_mask(m, k, 3), k)),
+            np.sort(np.asarray(jax.lax.top_k(m, k)[1])), err_msg=f"k={k}")
+
+
+def test_mask_indices_traced_k():
+    """A mask drawn for a traced k, compacted to a fixed length: one
+    program lists every k's set in ascending order and fills the rest of
+    the list with d."""
+    d, size = 1031, 600
+    key = jax.random.PRNGKey(13)
+    draw = jax.jit(lambda k: sparsifier.mask_indices(
+        sparsifier.fixedk_mask(key, d, k), size))
+    for k in (1, 2, 200, 515, 599, 600):
+        got = np.asarray(draw(jnp.int32(k)))
+        np.testing.assert_array_equal(
+            got[:k], np.sort(np.asarray(sparsifier.fixedk_indices(key, d, k))))
+        assert (got[k:] == d).all(), k
+    assert draw._cache_size() == 1

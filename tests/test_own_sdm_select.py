@@ -1,11 +1,15 @@
 """A node's own S(d) in the packed transports: a keep set drawn as a mask
-(``sparsifier.fixedk_mask``) and applied as a dense select.
+(``sparsifier.fixedk_mask``) and applied as a dense select; the wire's
+index lists: counted masks compacted to ascending order
+(``sparsifier.mask_indices``) on the sender and on the receivers.
 
 * On 4 CPU devices (subprocess, ``helpers/own_sdm_check.py``): the own
   S(d) of every packed transport equals the node's payload scattered back
-  into a zero plane, bit for bit — fixed-k at block 1 and 128, rows,
-  per-node p, and the replica (union) transport — and the 4-node step
-  still draws its wire index lists with ``top_k``.
+  into a zero plane, and what a node receives equals its senders'
+  payloads scattered back and weighed, bit for bit, payloads built from
+  ``fixedk_indices`` — fixed-k at block 1 and 128, rows, per-node p, and
+  the replica (union) transport — and the 4-node step draws its wire
+  index lists by compaction, with no ``top_k`` and no sort.
 * On one device: the step holds no sort, and no gather or scatter of
   the plane, and the launcher's banner says so.
 """
@@ -27,8 +31,9 @@ from repro.launch import hlo_analysis
 HELPER = pathlib.Path(__file__).parent / "helpers" / "own_sdm_check.py"
 SRC = str(pathlib.Path(__file__).parent.parent / "src")
 
-OWN_CASES = ["packed_b1", "packed_b1_bf16", "packed_b128", "rows", "hetp_b1",
-             "hetp_b128", "union_b1", "union_hetp_b128", "union_rows"]
+OWN_CASES = ["packed_b1", "packed_b1_bf16", "packed_b128", "rows",
+             "hetp_rows", "hetp_b1", "hetp_b128", "union_b1",
+             "union_hetp_b128", "union_rows"]
 
 
 @pytest.fixture(scope="module")
@@ -57,18 +62,33 @@ def test_own_sdm_equals_scattered_payload(four_nodes, case):
     assert got["NONZERO"] > 0, got
 
 
+@pytest.mark.parametrize("case", OWN_CASES)
+def test_received_equals_scattered_sender_payloads(four_nodes, case):
+    """The neighbour sum (static ring) or per-slot increments (union
+    transport) equal the senders' fixedk_indices payloads scattered back,
+    bit for bit: sender and receivers agree on each list."""
+    got = four_nodes[case]
+    assert got["NB_EQUAL"] == 1, got
+    assert got["NB_NONZERO"] > 0, got
+
+
 @pytest.mark.parametrize("case", ["packed_b128", "union_b1"])
 def test_sender_draws_its_round_key_once(four_nodes, case):
-    """The own S(d)'s mask and the payload's top_k read one draw of the
-    node's round key: two draws in all, with the senders' batched one."""
+    """The own S(d)'s mask and the payload's index list read one draw of
+    the node's round key: two draws in all, with the senders' batched
+    one."""
     assert four_nodes["RANDOM"][case] == 2, four_nodes["RANDOM"]
 
 
 def test_four_node_step_keeps_wire_topk_draws(four_nodes):
+    """The 4-node ring step's wire lists are compacted masks: per plane
+    one own mask, the payload's list and one list per sender (2 on the
+    ring); no top_k draw and no sort is left."""
     draws = four_nodes["DRAWS"]
     assert draws["own_mask"] >= 1, draws
-    assert draws["top_k"] >= 1, draws
-    assert draws["SORT"] >= 1, draws
+    assert draws["compact"] == 3 * draws["own_mask"], draws
+    assert draws["top_k"] == 0, draws
+    assert draws["SORT"] == 0, draws
 
 
 def test_one_node_own_sdm_needs_no_wire_draw():
@@ -81,6 +101,7 @@ def test_one_node_own_sdm_needs_no_wire_draw():
         step=jnp.int32(0), p=0.3, node_index=jnp.int32(0))
     after = gossip.draw_counts()
     assert after["top_k"] == before["top_k"]
+    assert after["compact"] == before["compact"]
     assert after["own_mask"] == before["own_mask"] + 1
     assert int((own != 0).sum()) == sparsifier.num_kept(1000, 0.3)
     assert not nb_sum.any()
@@ -122,6 +143,6 @@ def test_one_node_step_has_no_sort_gather_or_scatter_of_plane(one_node_run):
 def test_banner_reports_own_side_and_topk_draws(one_node_run):
     _, out = one_node_run
     [banner] = [ln for ln in out.splitlines() if ln.startswith("arch=")]
-    assert "fixedk_pack=xla-gather own_sdm=mask-select topk_draws=0" \
-        in banner, banner
+    assert ("fixedk_pack=xla-gather own_sdm=mask-select topk_draws=0"
+            " compact_draws=0") in banner, banner
     assert "gossip_rounds=0" in banner
