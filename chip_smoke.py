@@ -11,11 +11,11 @@ whole period of its one-layer pattern) and an eighth of the vocabulary
 parameters are bf16 from a seed.
 
 * ``train`` runs ``repro.launch.train`` itself: SDM-DSGD with fixed-k
-  packed payloads over 128-coordinate blocks (one lane-dense plane row,
-  so the fused fixed-k pack kernel gathers whole rows), p=0.2, Gaussian
-  masking with a clip, seq 2048, one sequence per node; one warm-up step
-  and three timed steps. The compiled step's own byte count (arguments,
-  outputs, temporaries, code, less donated aliases) must fit in HBM.
+  packed payloads over 128-coordinate blocks (one lane-dense plane
+  row), p=0.2, Gaussian masking with a clip, seq 2048, one sequence per
+  node; one warm-up step and three timed steps. The compiled step's own
+  byte count (arguments, outputs, temporaries, code, less donated
+  aliases) must fit in HBM.
 * ``kernels`` runs each main-path Pallas kernel at the run's real plane
   and KV-cache shapes against its jnp reference on the chip.
 * ``serve`` serves 8 ragged requests through ``ServingEngine`` with the
@@ -43,9 +43,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
 
 ARCH = "phi3-medium-14b"
 # fixed-k packed payloads over one plane row (LANE coordinates) per
-# block: the granularity the fused pack kernel moves. Element-granular
-# fixed-k (plain --gossip-mode fixedk_packed) packs with an XLA gather
-# (gossip.fused_pack_applies; the launcher's banner says which).
+# block, the benchmark's ring4 wire format (bench/workloads)
 TRAIN_FLAGS = ("--arch", ARCH, "--method", "sdm-dsgd",
                "--gossip-mode", "fixedk_packed", "--compressor", "block:128",
                "--p", "0.2", "--sigma", "0.5", "--clip-c", "1.0",
@@ -118,15 +116,11 @@ def phase_train(cfg, *, nodes: int, seq_len: int, steps: int = 4,
     print(f"[train] compile_s {run.compile_s:.3f} warmup_step_s "
           f"{run.step_s[0]:.4f} timed_step_s {timed} "
           f"losses {run.losses}", flush=True)
-    print(f"[train] tpu_custom_call {len(kernels)} "
-          f"fixedk_gather_pack "
-          f"{sum('fixedk_gather_pack' in k for k in kernels)}", flush=True)
+    print(f"[train] tpu_custom_call {len(kernels)}", flush=True)
     foot = step_footprint(run.compiled)
     print(f"[train] compiled step bytes per device {foot}", flush=True)
     check(all(np.isfinite(run.losses)), f"non-finite loss {run.losses}")
     if on_chip:
-        check(any("fixedk_gather_pack" in k for k in kernels),
-              "compiled step holds no fixed-k pack kernel")
         # the HBM check rests on the compiled footprint: the allocator's
         # peak is printed beside it for comparison
         for i, (peak, limit) in enumerate(peak_bytes(run.mesh.devices.flat)):
@@ -241,15 +235,14 @@ def wire_plane_rows(cfg, dtype) -> int:
 
 
 def phase_kernels(cfg, *, batch: int, max_seq: int, page_size: int,
-                  dtype, gather_rows=(), p: float = 0.2) -> None:
+                  dtype) -> None:
     """Each main-path kernel at the run's plane and cache shapes against
-    its jnp reference: bit-exact for the wire kernels. The fixed-k pack
-    also runs over the first ``gather_rows`` rows of the plane."""
+    its jnp reference: bit-exact for the wire kernel."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from repro.core import plane as plane_mod, sparsifier
+    from repro.core import plane as plane_mod
     from repro.kernels import wire_compress
     from repro.kernels.flash_attn.decode import (paged_attention,
                                                  paged_attention_ref)
@@ -261,7 +254,7 @@ def phase_kernels(cfg, *, batch: int, max_seq: int, page_size: int,
         return out, time.perf_counter() - t0
 
     rows, lane = wire_plane_rows(cfg, dtype), plane_mod.LANE
-    kx, ku, ki, kq = jax.random.split(jax.random.PRNGKey(1), 4)
+    kx, ku, kq = jax.random.split(jax.random.PRNGKey(1), 3)
     xf = jax.random.normal(kx, (rows, lane), jnp.float32)
     u = jax.random.uniform(ku, (rows, lane))
     norm = jnp.sqrt(jnp.sum(jnp.square(xf)))
@@ -289,22 +282,6 @@ def phase_kernels(cfg, *, batch: int, max_seq: int, page_size: int,
               flush=True)
         check(bad == 0 and out_k.shape[0] == i * per + out_r.shape[0],
               f"qsgd_pack bits={bits}: {bad} bytes differ from the ref")
-
-    for nb in (rows,) + tuple(gather_rows):
-        kb = sparsifier.num_kept(nb, p)
-        idx = sparsifier.fixedk_indices(ki, nb, kb)
-        gather = jax.jit(lambda d, i, use, s=nb / kb:
-                         wire_compress.fixedk_gather_pack(
-                             d, i, scale=s, use_kernel=use),
-                         static_argnums=2)
-        out_k, t_k = timed(gather, xf[:nb], idx, True)
-        out_r, t_r = timed(gather, xf[:nb], idx, False)
-        bad = int(jnp.sum(out_k != out_r))
-        print(f"[kernels] fixedk_gather_pack kept {kb} of {nb} rows "
-              f"mismatches {bad} kernel_s {t_k:.5f} ref_s {t_r:.5f}",
-              flush=True)
-        check(bad == 0, f"fixedk_gather_pack at {nb} rows: {bad} values "
-                        f"differ from the ref")
 
     kvh, dh = cfg.n_kv_heads, cfg.resolved_head_dim
     h = cfg.n_heads
@@ -420,16 +397,10 @@ def main(argv=None) -> int:
           f"heads {cfg.n_heads}/{cfg.n_kv_heads}x{cfg.resolved_head_dim} "
           f"d_ff {cfg.d_ff}; params bf16", flush=True)
 
-    from repro import configs
-
-    small = configs.get_smoke_config(ARCH)      # the parity phase's model
     if args.chips == 1:
         phase_train(cfg, nodes=1, seq_len=2048)
-        # the fixed-k pack also at the parity phase's plane: one block
-        # of fewer kept rows than a chunk, not a multiple of 8
         phase_kernels(cfg, batch=8, max_seq=512 + 32, page_size=16,
-                      dtype=jnp.bfloat16,
-                      gather_rows=(wire_plane_rows(small, jnp.float32),))
+                      dtype=jnp.bfloat16)
         phase_serve(cfg, n_requests=8, prompt_lens=(128, 512),
                     new_tokens=32, page_size=16, dtype=jnp.bfloat16)
     else:
@@ -437,7 +408,9 @@ def main(argv=None) -> int:
         check_placement(run)
         check_permutes(run, "block:128")
         del run
-        phase_parity(small, nodes=4, seq_len=64)
+        from repro import configs
+
+        phase_parity(configs.get_smoke_config(ARCH), nodes=4, seq_len=64)
 
     dev = devices[0]
     print(json.dumps({"ok": True, "device": {

@@ -48,7 +48,7 @@ from __future__ import annotations
 import dataclasses
 import math
 from fractions import Fraction
-from typing import Any, Callable, Dict, Tuple
+from typing import Any, Callable, ClassVar, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -133,6 +133,18 @@ class Compressor:
     """
 
     p: "float | Tuple[float, ...]" = 0.2
+
+    # How SDM-DSGD moves this family's wire planes
+    # (``sdm_dsgd._plane_exchange`` dispatches on it, once): "payload" is
+    # the generic ``gossip.exchange_payload`` transport of compress()'s
+    # pytree, which any new family rides unchanged; "dense" ppermutes the
+    # decompressed plane; "blocks" and "rows" are the packed fixed-k
+    # transports, whose receivers regenerate the index lists from the
+    # seed so only the kept values cross the wire.
+    transport: ClassVar[str] = "payload"
+    # The family's ``SDMConfig.mode`` spelling (legacy; printed and
+    # matched on by callers, derived from the resolved compressor).
+    sdm_mode: ClassVar[str] = "payload"
 
     def __post_init__(self) -> None:
         object.__setattr__(self, "p", _as_p_tuple_or_float(self.p))
@@ -244,6 +256,8 @@ class BernoulliCompressor(Compressor):
     """
 
     name: str = dataclasses.field(default="bernoulli", init=False, repr=False)
+    transport: ClassVar[str] = "dense"
+    sdm_mode: ClassVar[str] = "bernoulli"
 
     def compress(self, key, x, *, node=None) -> Payload:
         vals = sparsifier.bernoulli_sparsify(key, x, self.p_of(node)
@@ -298,6 +312,8 @@ class FixedKCompressor(Compressor):
 
     block: int = 1
     name: str = dataclasses.field(default="fixedk", init=False, repr=False)
+    transport: ClassVar[str] = "blocks"
+    sdm_mode: ClassVar[str] = "fixedk_packed"
 
     def __post_init__(self) -> None:
         super().__post_init__()
@@ -381,6 +397,8 @@ class RowsCompressor(Compressor):
     """
 
     name: str = dataclasses.field(default="rows", init=False, repr=False)
+    transport: ClassVar[str] = "rows"
+    sdm_mode: ClassVar[str] = "fixedk_rows"
 
     def _rows_cols(self, shape: Tuple[int, ...]) -> Tuple[int, int]:
         d = int(math.prod(shape))
@@ -451,6 +469,7 @@ class QSGDCompressor(Compressor):
 
     bits: int = 8
     name: str = dataclasses.field(default="qsgd", init=False, repr=False)
+    sdm_mode: ClassVar[str] = "qsgd"
 
     def __post_init__(self) -> None:
         super().__post_init__()
@@ -553,6 +572,7 @@ class FusedQSGDCompressor(QSGDCompressor):
     """
 
     name: str = dataclasses.field(default="qsgdf", init=False, repr=False)
+    sdm_mode: ClassVar[str] = "payload"   # its own wire, not qsgd's
 
     def __post_init__(self) -> None:
         super().__post_init__()

@@ -54,7 +54,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import os
 from fractions import Fraction
 from typing import Sequence, Tuple
 
@@ -62,22 +61,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import plane as plane_mod, sparsifier, tagging
-
-# Fused sender-side fixed-k packing (kernels/wire_compress gather+scale
-# pallas kernel) for the static scalar-p payload path. Bit-exact to the
-# unfused jnp gather, so this is a launch-count knob, never a trajectory
-# knob; REPRO_FUSED_PACK=0 is the escape hatch.
-FUSED_PACK = os.environ.get("REPRO_FUSED_PACK", "1") != "0"
+from repro.core import sparsifier, tagging
 
 # Trace-time record of the fixed-k keep-set draws built into programs:
 # ``own_mask`` counts own S(d) keep sets drawn as masks
 # (``_own_and_payload``); ``compact`` the wire's index lists a step keeps,
 # each a counted mask compacted to ascending order (the sender's payload
-# list, and one per sender the receivers regenerate); ``top_k`` the
-# sorted index-list draws, which no packed transport makes. Read with
+# list, and one per sender the receivers regenerate). Read with
 # ``draw_counts``.
-_DRAWS = {"own_mask": 0, "compact": 0, "top_k": 0}
+_DRAWS = {"own_mask": 0, "compact": 0}
 
 __all__ = [
     "mix_dense",
@@ -765,29 +757,13 @@ def _batched_sender_indices(schedule: PermuteSchedule, me, *,
 
 def draw_counts() -> dict:
     """The fixed-k keep-set draws traced so far in this process:
-    ``{"own_mask": ..., "compact": ..., "top_k": ...}`` (see ``_DRAWS``).
+    ``{"own_mask": ..., "compact": ...}`` (see ``_DRAWS``).
     Counted when a step is traced, per draw site, so a reading taken
     before and after lowering a step says what that step holds: 0
     ``compact`` on a schedule with no gossip rounds. The dict is live;
     copy it to keep a reading.
     """
     return _DRAWS
-
-
-def fused_pack_applies(block: int, dtype, p) -> bool:
-    """Whether the sender-side fixed-k pack of ``block``-coordinate
-    blocks runs the fused gather+scale kernel.
-
-    The kernel DMAs whole lane-dense f32 plane rows, so ``block`` must
-    be a multiple of LANE. Element-granular fixed-k (``fixedk_packed``,
-    block 1) and other sub-lane blocks stay an XLA gather: one DMA per
-    kept coordinate would cost far more than XLA's gather. The het-p
-    path (tuple ``p``) keeps the jnp ops too: its scale is a traced
-    per-node mask, not a static scalar. The launcher prints the choice.
-    """
-    return (FUSED_PACK and not isinstance(p, tuple)
-            and block % plane_mod.LANE == 0
-            and jnp.dtype(dtype) == jnp.float32)
 
 
 def _kept(nb_blocks: int, p, me) -> Tuple[int, "int | jax.Array"]:
@@ -872,12 +848,6 @@ def _packed_selection(db: jax.Array, keys: jax.Array, keep: jax.Array,
     else:
         my_idx = sparsifier.mask_indices(keep, kb)
         scale = nb_blocks / kb
-    if db.ndim == 2 and fused_pack_applies(db.shape[1], db.dtype, p):
-        # fused sender-side pack: gather + contraction scale in ONE
-        # pallas launch (bit-exact to the jnp pair below, so enabling
-        # it never changes a trajectory)
-        from repro.kernels import wire_compress   # lazy: core -> kernels
-        return wire_compress.fixedk_gather_pack(db, my_idx, scale=scale)
     return (jnp.take(db, my_idx, axis=0) * scale).astype(db.dtype)
 
 

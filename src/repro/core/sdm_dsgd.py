@@ -77,11 +77,13 @@ class SDMConfig:
     """Hyper-parameters of Algorithm 1.
 
     ``compressor``, when set, is a ``repro.core.compressor`` spec that
-    SELECTS the wire format by name (the preferred axis; ``mode`` is
-    derived from it): 'bernoulli' | 'fixedk[:block]' | 'block:<B>' |
-    'rows' | 'qsgd[:bits]'. ``compressor_of(cfg)`` resolves either
-    spelling to the Compressor object that owns sensitivity and
-    wire-cost accounting.
+    SELECTS the wire format by name (the preferred axis):
+    'bernoulli' | 'fixedk[:block]' | 'block:<B>' | 'rows' |
+    'qsgd[:bits]' | 'qsgdf[:bits]' | any registered family. Either
+    spelling is resolved once, at construction, to the Compressor object
+    (``wire``, what ``compressor_of`` returns) that owns sensitivity,
+    wire-cost accounting and the transport; ``mode`` is then read off
+    it ('payload' for every family without a legacy spelling).
 
     mode (legacy spelling, still accepted):
       'bernoulli'     — paper-faithful i.i.d. Bernoulli(p) masking, dense payloads.
@@ -134,49 +136,51 @@ class SDMConfig:
     # schedules only.
     overlap: bool = False
 
+    # The wire format, resolved ONCE from ``compressor`` or ``mode`` (what
+    # ``compressor_of`` returns). Derived from the fields above, so it
+    # takes no part in == or hash.
+    wire: compressor_mod.Compressor = dataclasses.field(
+        default=None, init=False, repr=False, compare=False)
+
     def __post_init__(self) -> None:
-        if self.compressor is not None:
-            # single source of truth: parse through the registry factories
-            # and read (mode, pack_block, qsgd_bits) off the object, so
-            # per-family defaults cannot drift from compressor.make.
-            comp = compressor_mod.make(self.compressor, p=self.p)
-            if isinstance(comp, compressor_mod.FusedQSGDCompressor):
-                # MUST precede the QSGDCompressor check (it is a
-                # subclass): the fused single-buffer format rides the
-                # generic payload transport, and mapping it to
-                # mode="qsgd" would make compressor_of rebuild a plain
-                # QSGDCompressor — silently dropping the fused wire.
-                object.__setattr__(self, "mode", "payload")
-                object.__setattr__(self, "qsgd_bits", comp.bits)
-            elif isinstance(comp, compressor_mod.QSGDCompressor):
-                object.__setattr__(self, "mode", "qsgd")
-                object.__setattr__(self, "qsgd_bits", comp.bits)
-            elif isinstance(comp, compressor_mod.RowsCompressor):
-                object.__setattr__(self, "mode", "fixedk_rows")
-            elif isinstance(comp, compressor_mod.FixedKCompressor):
-                object.__setattr__(self, "mode", "fixedk_packed")
-                object.__setattr__(self, "pack_block", comp.block)
-            elif isinstance(comp, compressor_mod.BernoulliCompressor):
-                object.__setattr__(self, "mode", "bernoulli")
-            else:
-                # any other registered family rides the generic
-                # exchange_payload transport — "adding a compressor"
-                # needs no SDM-side mapping.
-                object.__setattr__(self, "mode", "payload")
-        if self.error_feedback and self.mode in ("qsgd", "payload"):
-            # EF undoes the sparsifiers' 1/p amplification by scaling the
-            # transmitted update by p; quantizers/generic payloads have
-            # no such factor, so the scale would silently discard (1-p)
-            # of every update.
-            raise ValueError("error_feedback is a sparsifier-path "
-                             f"extension; unsupported with mode={self.mode!r}")
         if isinstance(self.p, (list, tuple)):
             object.__setattr__(self, "p", tuple(float(v) for v in self.p))
             if not self.p:
                 raise ValueError("per-node p must be non-empty")
             if any(not (0.0 < v <= 1.0) for v in self.p):
                 raise ValueError("every per-node p must be in (0,1]")
-            if self.mode not in ("bernoulli", "fixedk_packed"):
+        elif not (0.0 < self.p <= 1.0):
+            raise ValueError("p in (0,1]")
+        if not (0.0 < self.theta <= 1.0):
+            raise ValueError("theta in (0,1]")
+        spec = self.compressor or {
+            "bernoulli": "bernoulli",
+            "fixedk_packed": f"fixedk:{self.pack_block}",
+            "fixedk_rows": "rows",
+            "qsgd": f"qsgd:{self.qsgd_bits}"}.get(self.mode)
+        if spec is None:
+            raise ValueError("mode='payload' needs a compressor spec"
+                             if self.mode == "payload"
+                             else f"unknown mode {self.mode}")
+        # through the registry factories, and (mode, pack_block,
+        # qsgd_bits) read back off the object, so per-family defaults
+        # cannot drift from compressor.make
+        wire = compressor_mod.make(spec, p=self.p)
+        object.__setattr__(self, "wire", wire)
+        object.__setattr__(self, "mode", wire.sdm_mode)
+        object.__setattr__(self, "pack_block",
+                           getattr(wire, "block", self.pack_block))
+        object.__setattr__(self, "qsgd_bits",
+                           getattr(wire, "bits", self.qsgd_bits))
+        if self.error_feedback and wire.transport == "payload":
+            # EF undoes the sparsifiers' 1/p amplification by scaling the
+            # transmitted update by p; quantizers/generic payloads have
+            # no such factor, so the scale would silently discard (1-p)
+            # of every update.
+            raise ValueError("error_feedback is a sparsifier-path "
+                             f"extension; unsupported with mode={self.mode!r}")
+        if isinstance(self.p, tuple):
+            if wire.transport not in ("dense", "blocks"):
                 raise ValueError(
                     "heterogeneous per-node p needs mode='bernoulli' or "
                     "'fixedk_packed' (pad-to-max-k payloads); "
@@ -184,15 +188,6 @@ class SDMConfig:
             if self.error_feedback:
                 raise ValueError(
                     "error_feedback with per-node p is unsupported")
-        elif not (0.0 < self.p <= 1.0):
-            raise ValueError("p in (0,1]")
-        if not (0.0 < self.theta <= 1.0):
-            raise ValueError("theta in (0,1]")
-        if self.mode not in ("bernoulli", "fixedk_packed", "fixedk_rows",
-                             "qsgd", "payload"):
-            raise ValueError(f"unknown mode {self.mode}")
-        if self.mode == "payload" and not self.compressor:
-            raise ValueError("mode='payload' needs a compressor spec")
 
     @property
     def p_min(self) -> float:
@@ -279,25 +274,15 @@ def check_per_node_p(cfg, n_nodes: int) -> None:
 
 
 def compressor_of(cfg) -> compressor_mod.Compressor:
-    """The Compressor object a config's wire format resolves to.
+    """The Compressor a config's wire format resolved to.
 
-    Whether the config was built with ``compressor='...'`` or the legacy
-    ``mode=`` spelling, this is the single point where sdm_dsgd selects
-    a compressor BY NAME from the registry — sensitivity
-    (``release_probability``) and wire-cost (``wire_elements`` /
-    ``wire_bits``) accounting live on the returned object.
+    Built once, when the ``SDMConfig`` was (``cfg.wire``), from either
+    the ``compressor='...'`` spec or the legacy ``mode=`` spelling:
+    sensitivity (``release_probability``), wire-cost accounting
+    (``wire_elements`` / ``wire_bits``) and the transport
+    (``transport``) live on the returned object.
     """
-    if cfg.mode == "bernoulli":
-        return compressor_mod.BernoulliCompressor(p=cfg.p)
-    if cfg.mode == "fixedk_packed":
-        return compressor_mod.FixedKCompressor(p=cfg.p, block=cfg.pack_block)
-    if cfg.mode == "fixedk_rows":
-        return compressor_mod.RowsCompressor(p=cfg.p)
-    if cfg.mode == "qsgd":
-        return compressor_mod.QSGDCompressor(bits=cfg.qsgd_bits)
-    if cfg.mode == "payload":   # any registered family, generic transport
-        return compressor_mod.make(cfg.compressor, p=cfg.p)
-    raise ValueError(f"unknown mode {cfg.mode}")
+    return cfg.wire
 
 
 @jax.named_scope("sdm_mask")
@@ -410,7 +395,7 @@ def transmitted_elements_per_step(params: PyTree, cfg: SDMConfig,
     """
     comp = compressor_of(cfg)
     wire = wire_shape_tree(params)
-    if isinstance(cfg.p, tuple) and cfg.mode != "qsgd" and node is None:
+    if isinstance(cfg.p, tuple) and node is None:
         exact = compressor_mod.node_mean_exact(
             cfg.p, lambda i: compressor_mod.tree_wire_elements_exact(
                 comp, wire, node=i))
@@ -442,7 +427,7 @@ def transmitted_bits_per_step(params: PyTree, cfg: SDMConfig,
     comp = compressor_of(cfg)
     wire = wire_shape_tree(params)
     kw = dict(value_bits=value_bits, index_sync=index_sync)
-    if isinstance(cfg.p, tuple) and cfg.mode != "qsgd" and node is None:
+    if isinstance(cfg.p, tuple) and node is None:
         exact = compressor_mod.node_mean_exact(
             cfg.p, lambda i: compressor_mod.tree_wire_bits_exact(
                 comp, wire, node=i, **kw))
@@ -701,8 +686,9 @@ def _plane_payload_exchange(planes: Tuple[jax.Array, ...],
     R rounds (``useq=None``, weighted sum) or every union round
     (``useq`` set, per-slot increment stacks). ``transform`` rewrites
     each payload pre-wire (compressed push-sum's contraction). Shared by
-    the SDM qsgd/payload modes AND compressed gradient-push, so the key
-    schedule and contraction point cannot desynchronize between them.
+    SDM's "payload"-transport families AND compressed gradient-push, so
+    the key schedule and contraction point cannot desynchronize between
+    them.
     Returns (own decompressed planes, received planes).
     """
     own, recv = [], []
@@ -723,88 +709,65 @@ def _plane_payload_exchange(planes: Tuple[jax.Array, ...],
     return tuple(own), tuple(recv)
 
 
+# The gossip calls behind the packed transports (``Compressor.transport``),
+# as (static schedule, union schedule).
+_PACKED_TRANSPORTS = {
+    "blocks": (gossip.exchange_packed, gossip.union_exchange_packed),
+    "rows": (gossip.exchange_packed_rows, gossip.union_exchange_packed_rows),
+}
+
+
 @jax.named_scope("sdm_pack")
-def _plane_exchange(d_planes: Tuple[jax.Array, ...], *, schedule, axis_name,
+def _plane_exchange(d_planes: Tuple[jax.Array, ...], *, axis_name,
                     base_key: jax.Array, step: jax.Array, cfg: SDMConfig,
-                    me, node_index=None) -> Tuple[Tuple[jax.Array, ...],
-                                                  Tuple[jax.Array, ...]]:
-    """Plane-granular exchange: (own S(d) planes, weighted nb-sum planes).
+                    me, schedule=None, useq=None, node_index=None
+                    ) -> Tuple[Tuple[jax.Array, ...], Tuple[jax.Array, ...]]:
+    """Plane-granular exchange: (own S(d) planes, received planes).
 
-    The ONE static-schedule transport behind every SDM mode: each
-    bucket's plane is compressed/drawn ONCE (key
-    ``fold_in(base, bucket)`` — the schedule the reference's
-    ``sparsify_planes_stacked`` mirrors) and crosses the wire in exactly
-    R collective-permutes per bucket, independent of the model's leaf
-    count.
+    The ONE transport behind every SDM wire format. Each bucket's plane
+    is compressed/drawn ONCE (key ``fold_in(base, bucket)`` — the
+    schedule the reference's ``sparsify_planes_stacked`` mirrors). On a
+    static ``schedule`` it crosses the wire in exactly R
+    collective-permutes per bucket, independent of the model's leaf
+    count, and what is received is the weighted neighbour sum. On the
+    union schedule ``useq`` of a time-varying sequence every union
+    round's delivery lands in its OWN (n_replicas, rows, lane) row
+    instead — one batched sender draw per bucket regardless of sequence
+    length. The way the plane travels is the compressor family's
+    ``transport``, chosen here once.
     """
     comp = compressor_of(cfg)
-    if cfg.mode in ("qsgd", "payload"):
+    if comp.transport == "payload":
         return _plane_payload_exchange(
             d_planes, comp, axis_name=axis_name, base_key=base_key,
-            step=step, me=me, schedule=schedule, node_index=node_index)
-    own, nb = [], []
-    for b, dp in enumerate(d_planes):
-        bkey = jax.random.fold_in(base_key, b)
-        if cfg.mode == "fixedk_rows":
-            o, s = gossip.exchange_packed_rows(
-                schedule, dp, axis_name=axis_name, base_key=bkey,
-                step=step, p=cfg.p, node_index=node_index)
-        elif cfg.mode == "fixedk_packed":
-            o, s = gossip.exchange_packed(
-                schedule, dp.reshape(-1), axis_name=axis_name,
-                base_key=bkey, step=step, p=cfg.p, block=cfg.pack_block,
-                node_index=node_index)
-            o, s = o.reshape(dp.shape), s.reshape(dp.shape)
-        else:   # bernoulli: dense masked plane payload
+            step=step, me=me, schedule=schedule, useq=useq,
+            node_index=node_index)
+    union = useq is not None
+    if comp.transport == "dense":   # the masked plane itself crosses
+        def one(dp, bkey):
             key = gossip.node_round_key(bkey, me, step)
             o = comp.decompress(comp.compress(key, dp, node=me))
-            s = gossip.exchange(schedule, o, axis_name,
-                                node_index=node_index, step=step)
-        own.append(o)
-        nb.append(s)
-    return tuple(own), tuple(nb)
+            if union:
+                return o, gossip.union_exchange(useq, o, axis_name)
+            return o, gossip.exchange(schedule, o, axis_name,
+                                      node_index=node_index, step=step)
+    else:
+        exchange = _PACKED_TRANSPORTS[comp.transport][union]
+        kw = dict(axis_name=axis_name, step=step, p=cfg.p,
+                  node_index=node_index)
+        flat = comp.transport == "blocks"   # blocks of the flat plane
+        if flat:
+            kw["block"] = comp.block
 
-
-@jax.named_scope("sdm_pack")
-def _replica_plane_exchange(d_planes: Tuple[jax.Array, ...], *,
-                            useq, axis_name, base_key: jax.Array,
-                            step: jax.Array, cfg: SDMConfig, me,
-                            node_index=None, transform=None
-                            ) -> Tuple[Tuple[jax.Array, ...],
-                                       Tuple[jax.Array, ...]]:
-    """Replica (union) plane transport: (own planes, per-slot increments).
-
-    Same selection/keys as ``_plane_exchange``; each union round's
-    delivery lands in its OWN (n_replicas, rows, lane) row instead of a
-    weighted sum — one batched sender draw per bucket regardless of
-    sequence length.
-    """
-    comp = compressor_of(cfg)
-    if cfg.mode in ("qsgd", "payload"):
-        return _plane_payload_exchange(
-            d_planes, comp, axis_name=axis_name, base_key=base_key,
-            step=step, me=me, useq=useq, transform=transform)
-    own, incr = [], []
-    for b, dp in enumerate(d_planes):
-        bkey = jax.random.fold_in(base_key, b)
-        if cfg.mode == "fixedk_rows":
-            o, inc = gossip.union_exchange_packed_rows(
-                useq, dp, axis_name=axis_name, base_key=bkey,
-                step=step, p=cfg.p, node_index=node_index)
-        elif cfg.mode == "fixedk_packed":
-            o, inc = gossip.union_exchange_packed(
-                useq, dp.reshape(-1), axis_name=axis_name, base_key=bkey,
-                step=step, p=cfg.p, block=cfg.pack_block,
-                node_index=node_index)
-            o = o.reshape(dp.shape)
-            inc = inc.reshape((inc.shape[0],) + dp.shape)
-        else:
-            key = gossip.node_round_key(bkey, me, step)
-            o = comp.decompress(comp.compress(key, dp, node=me))
-            inc = gossip.union_exchange(useq, o, axis_name)
-        own.append(o)
-        incr.append(inc)
-    return tuple(own), tuple(incr)
+        def one(dp, bkey):
+            view = dp.reshape(-1) if flat else dp
+            o, r = exchange(useq if union else schedule, view,
+                            base_key=bkey, **kw)
+            return o.reshape(dp.shape), r.reshape(r.shape[:-view.ndim]
+                                                  + dp.shape)
+    own, recv = zip(*(one(dp, jax.random.fold_in(base_key, b))
+                      for b, dp in enumerate(d_planes)))
+    return own, recv
 
 
 def _replica_advance_exchange(d_planes: Tuple[jax.Array, ...],
@@ -820,7 +783,7 @@ def _replica_advance_exchange(d_planes: Tuple[jax.Array, ...],
     W(t)-mixing on B-connected sequences.
     """
     useq = gossip.union_schedule(seq)
-    own, incr = _replica_plane_exchange(
+    own, incr = _plane_exchange(
         d_planes, useq=useq, axis_name=axis_name, base_key=base_key,
         step=step, cfg=cfg, me=me, node_index=node_index)
     new_xhat = tuple(xh + inc for xh, inc in zip(xhat, incr))

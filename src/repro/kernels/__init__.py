@@ -1,4 +1,7 @@
-"""Pallas TPU kernels for the paper's per-iteration hot loop and attention.
+"""Pallas TPU kernels: the fused QSGD wire packer (``wire_compress``,
+behind the ``qsgdf`` compressor), paged flash decode and flash prefill
+(``flash_attn``), and a fused SDM update (``sdm_update``, reached only
+from tests). Fixed-k payloads are packed by XLA's gather, not a kernel.
 
 Each kernel ships as <name>/<name>.py (pl.pallas_call + BlockSpec),
 <name>/ops.py (jit'd public wrapper), <name>/ref.py (pure-jnp oracle).

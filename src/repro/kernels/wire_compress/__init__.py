@@ -1,8 +1,7 @@
-"""Fused wire-compressor pipeline (pallas): quantize+pack, gather+pack."""
-from .ops import fixedk_gather_pack, qsgd_pack
-from .ref import fixedk_gather_pack_ref, qsgd_decode_ref, qsgd_quantize_pack_ref
-from .wire_compress import (LANE, fixedk_gather_pack_pallas, pack_factor,
-                            qsgd_pack_pallas)
+"""Fused wire-compressor pipeline (pallas): QSGD quantize+pack."""
+from .ops import qsgd_pack
+from .ref import qsgd_decode_ref, qsgd_quantize_pack_ref
+from .wire_compress import LANE, pack_factor, qsgd_pack_pallas
 
 __all__ = [
     "LANE",
@@ -11,7 +10,4 @@ __all__ = [
     "qsgd_pack_pallas",
     "qsgd_decode_ref",
     "qsgd_quantize_pack_ref",
-    "fixedk_gather_pack",
-    "fixedk_gather_pack_pallas",
-    "fixedk_gather_pack_ref",
 ]

@@ -17,9 +17,9 @@ import jax
 import jax.numpy as jnp
 
 from . import ref
-from .wire_compress import LANE, fixedk_gather_pack_pallas, qsgd_pack_pallas
+from .wire_compress import LANE, qsgd_pack_pallas
 
-__all__ = ["qsgd_pack", "fixedk_gather_pack"]
+__all__ = ["qsgd_pack"]
 
 
 @functools.partial(jax.jit,
@@ -40,12 +40,3 @@ def qsgd_pack(xf: jax.Array, u: jax.Array, norm: jax.Array, *, bits: int,
                                 interpret=interpret)
     return ref.qsgd_quantize_pack_ref(xf, u, inv, bits=bits)
 
-
-def fixedk_gather_pack(db: jax.Array, idx: jax.Array, *, scale: float,
-                       use_kernel: bool = True,
-                       interpret: bool | None = None) -> jax.Array:
-    """Sender-side fixed-k pack: one-launch gather + unbiasedness scale."""
-    if use_kernel:
-        return fixedk_gather_pack_pallas(db, idx, scale=float(scale),
-                                         interpret=interpret)
-    return ref.fixedk_gather_pack_ref(db, idx, scale=scale)

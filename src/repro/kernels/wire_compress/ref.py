@@ -1,6 +1,6 @@
-"""Pure-jnp oracle for the fused wire-compressor kernels.
+"""Pure-jnp oracle for the fused wire-compressor kernel.
 
-Bit-identical to both the pallas kernels and the unfused
+Bit-identical to both the pallas kernel and the unfused
 ``compressor.QSGDCompressor`` chain — the property tests pin all three
 to the same byte image.
 """
@@ -57,7 +57,3 @@ def qsgd_decode_ref(buf, shape, *, bits: int):
         flat = jnp.stack(parts, axis=1).reshape(-1)[:d] - int(s)
     return (norm / s) * flat.reshape(shape).astype(jnp.float32)
 
-
-def fixedk_gather_pack_ref(db, idx, *, scale: float):
-    """The unfused sender-side fixed-k pack: gather + contraction scale."""
-    return jnp.take(db, idx, axis=0) * scale

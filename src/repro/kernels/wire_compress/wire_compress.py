@@ -1,4 +1,4 @@
-"""Fused wire-compressor kernels (quantize+pack, gather+pack) per plane.
+"""Fused wire-compressor kernel (QSGD quantize+pack) per plane.
 
 The unfused QSGD wire path is a multi-launch XLA chain per plane —
 abs/scale/floor/stochastic-round/clamp/sign ~6 elementwise kernels, then
@@ -28,14 +28,6 @@ packs each with a matmul against a constant 0/2^(i*bits) matrix that
 routes lane t to byte lane ``j*LANE/k + t//k``. Every operand is a small
 integer or a power of two (sums <= 255), so the bf16 MXU product is
 exact and the image stays bit-identical to the unfused packer.
-
-``fixedk_gather_pack_pallas`` fuses the fixed-k sender-side payload
-packing (gather kept blocks + contraction scale) into one launch — the
-``jnp.take * scale`` pair in ``gossip._packed_selection``. The plane
-stays in HBM; each grid step DMAs a chunk of kept rows, addressed by
-indices read from SMEM, straight into its output block and scales them
-there. Bit-exact to the unfused ops, so trajectories are unchanged
-wherever it is enabled.
 """
 from __future__ import annotations
 
@@ -45,20 +37,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.plane import LANE
 from repro.kernels import resolve_interpret
 
-__all__ = ["qsgd_pack_pallas", "fixedk_gather_pack_pallas", "LANE",
-           "pack_factor"]
+__all__ = ["qsgd_pack_pallas", "LANE", "pack_factor"]
 
 # Output rows per qsgd grid step: 1 MiB f32 input blocks at k=4, and a
 # multiple of the (32, 128) u8 tile.
 QSGD_BLOCK_ROWS = 512
-# Kept rows gathered per fixed-k grid step (DMAs in flight per step).
-# A multiple of 1024, the HBM tile of a 1-D int32 array.
-GATHER_CHUNK = 1024
 
 
 def pack_factor(bits: int) -> int:
@@ -141,62 +128,3 @@ def qsgd_pack_pallas(xf: jax.Array, u: jax.Array, inv: jax.Array, *,
     )(*operands)
     return out.reshape(-1)[:rows * LANE // k]
 
-
-def _gather_kernel(idx_ref, db_hbm, out_ref, sem, *, scale: float,
-                   n_kept: int, chunk: int):
-    # kept rows in this step: the last step's block may run past kb
-    n = jnp.minimum(chunk, n_kept - pl.program_id(0) * chunk)
-
-    def row_copy(j):
-        return pltpu.make_async_copy(db_hbm.at[pl.ds(idx_ref[j], 1)],
-                                     out_ref.at[pl.ds(j, 1)], sem)
-
-    def start(j, carry):
-        row_copy(j).start()
-        return carry
-
-    def wait(j, carry):
-        row_copy(j).wait()
-        return carry
-
-    # every wait names the one-row copy it waits for, so the semaphore
-    # count matches whatever the block's row count (a single whole-block
-    # wait over a block of 565 rows never returned on a v5e)
-    jax.lax.fori_loop(0, n, start, 0)
-    jax.lax.fori_loop(0, n, wait, 0)
-    out_ref[...] = out_ref[...] * scale
-
-
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
-def fixedk_gather_pack_pallas(db: jax.Array, idx: jax.Array, *,
-                              scale: float,
-                              interpret: bool | None = None) -> jax.Array:
-    """(nb, block) plane view + (kb,) i32 indices -> (kb, block) payload.
-
-    One launch for the sender-side fixed-k pack: gather the kept blocks
-    and apply the (static, scalar-p) unbiasedness scale — bit-exact to
-    ``jnp.take(db, idx, axis=0) * scale``. ``block`` must be a multiple
-    of LANE (whole lane-dense rows). Grid step i DMAs kept rows
-    ``idx[i*chunk : (i+1)*chunk]`` from HBM into its output block; the
-    index chunk rides SMEM, since the whole index vector (kb int32) can
-    outgrow it at model scale.
-    """
-    nb, block = db.shape
-    assert block % LANE == 0, db.shape
-    kb = idx.shape[0]
-    rows = min(GATHER_CHUNK, kb)          # kept rows per grid step
-    n_steps = pl.cdiv(kb, rows)
-    idx = jnp.pad(idx.astype(jnp.int32),
-                  (0, n_steps * GATHER_CHUNK - kb))
-    return pl.pallas_call(
-        functools.partial(_gather_kernel, scale=scale, n_kept=kb,
-                          chunk=rows),
-        grid=(n_steps,),
-        in_specs=[pl.BlockSpec((GATHER_CHUNK,), lambda i: (i,),
-                               memory_space=pltpu.SMEM),
-                  pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec((rows, block), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((kb, block), db.dtype),
-        scratch_shapes=[pltpu.SemaphoreType.DMA(())],
-        interpret=resolve_interpret(interpret),
-    )(idx, db)

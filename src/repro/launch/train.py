@@ -45,12 +45,9 @@ def parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
     ap.add_argument("--gossip-mode", default="bernoulli",
                     choices=["bernoulli", "fixedk_packed", "fixedk_rows",
                              "qsgd"],
-                    help="fixedk_packed keeps single coordinates and packs "
-                         "them with XLA's gather; the fused Pallas pack "
-                         "moves whole 128-lane plane rows, so it runs only "
-                         "with --compressor block:<multiple of 128> "
-                         "(gossip.fused_pack_applies; the banner prints "
-                         "fixedk_pack=kernel|xla-gather)")
+                    help="legacy wire-format spelling: fixedk_packed keeps "
+                         "single coordinates (fixedk:1), fixedk_rows whole "
+                         "plane rows; --compressor overrides it")
     ap.add_argument("--compressor", default=None,
                     help="wire compressor spec (repro.core.compressor): "
                          "bernoulli | fixedk[:block] | block:<B> | rows | "
@@ -109,7 +106,7 @@ def train(args: argparse.Namespace, cfg) -> Optional[TrainRun]:
 
     from repro.checkpoint import save_checkpoint
     from repro.core import gossip, method as method_mod
-    from repro.core.sdm_dsgd import SDMConfig
+    from repro.core.sdm_dsgd import SDMConfig, compressor_of
     from repro.data import TokenStream
     from repro.launch.compile_cache import compile_counts
     from repro.launch.mesh import make_mesh_by_name, node_axis_names
@@ -141,16 +138,10 @@ def train(args: argparse.Namespace, cfg) -> Optional[TrainRun]:
         param_dtype=jnp.float32 if args.smoke else jnp.bfloat16)
     sched = steps_mod.gossip_schedule(tc, mesh)
     mcfg = tc.resolved()[1]
-    pack = ""
-    fixedk = isinstance(mcfg, SDMConfig) and mcfg.mode in (
-        "fixedk_packed", "fixedk_rows")
-    if fixedk and mcfg.mode == "fixedk_packed":
-        fused = gossip.fused_pack_applies(mcfg.pack_block, jnp.float32,
-                                          mcfg.p)
-        pack = " fixedk_pack=" + ("kernel" if fused else "xla-gather")
-    if fixedk:
-        # a node's own S(d) is a dense select over a mask-drawn keep set
-        pack += " own_sdm=mask-select"
+    fixedk = isinstance(mcfg, SDMConfig) and compressor_of(
+        mcfg).transport in ("blocks", "rows")
+    # a node's own S(d) is a dense select over a mask-drawn keep set
+    pack = " own_sdm=mask-select" if fixedk else ""
 
     banner = (f"arch={cfg.name} mesh={dict(zip(mesh.axis_names, mesh.devices.shape))} "
               f"nodes={n_nodes} method={meth_name} p={args.p} theta={args.theta} "
@@ -184,7 +175,7 @@ def train(args: argparse.Namespace, cfg) -> Optional[TrainRun]:
         return out
 
     # the banner follows the step's tracing, so that it can say how many
-    # sorted (top_k) and compacted wire index lists the built step holds
+    # compacted wire index lists the built step holds
     before = dict(compile_counts())
     drawn = dict(gossip.draw_counts())
     moe_before = dict(moe.layer_counts())
@@ -194,8 +185,7 @@ def train(args: argparse.Namespace, cfg) -> Optional[TrainRun]:
                       donate_argnums=0).lower(state, *step_args(0))
     if fixedk:
         now = gossip.draw_counts()
-        banner += (f" topk_draws={now['top_k'] - drawn['top_k']}"
-                   f" compact_draws={now['compact'] - drawn['compact']}")
+        banner += f" compact_draws={now['compact'] - drawn['compact']}"
     if cfg.has_moe:
         # traced MoE layer bodies (a scanned period's slot once), their
         # held and routed experts, and their grouped matmuls

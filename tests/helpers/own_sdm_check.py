@@ -10,7 +10,7 @@ Run with 4 fake host devices; prints one line per case
 
     CASE <id> EQUAL <0|1> NONZERO <i> NB_EQUAL <0|1> NB_NONZERO <i>
     RANDOM <id> <i>
-    DRAWS own_mask <i> compact <i> top_k <i> SORT <i>
+    DRAWS own_mask <i> compact <i> SORT <i>
 
 that tests/test_own_sdm_select.py asserts on. Must set XLA_FLAGS before
 the jax import.

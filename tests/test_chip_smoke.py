@@ -68,7 +68,9 @@ def test_parity_phase_four_nodes():
                          text=True, env=env, timeout=900)
     assert out.returncode == 0, out.stdout[-2000:] + out.stderr[-3000:]
     assert "nodes=4" in out.stdout and "gossip_rounds=2" in out.stdout
-    assert "fixedk_pack=kernel" in out.stdout
+    # the packed fixed-k transport: one plane, the sender's list and
+    # one list per sender compacted
+    assert "own_sdm=mask-select compact_draws=3" in out.stdout
     assert "[parity] 4 nodes" in out.stdout, out.stdout
 
 
@@ -76,7 +78,7 @@ def test_kernels_phase(smoke, cfg, monkeypatch):
     # several reference blocks over the smoke plane, and a ragged tail
     monkeypatch.setattr(smoke, "REF_ROWS", 96)
     smoke.phase_kernels(cfg, batch=3, max_seq=40, page_size=8,
-                        dtype=jnp.bfloat16, gather_rows=(45,))
+                        dtype=jnp.bfloat16)
 
 
 def test_serve_phase(smoke, cfg):

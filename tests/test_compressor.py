@@ -1,6 +1,13 @@
-"""The pluggable Compressor layer: registry/spec parsing, roundtrip
-unbiasedness, pad-to-max-k heterogeneous payloads, exact wire-bit
-accounting, accountant wiring, and the compressed push-sum invariants."""
+"""The pluggable Compressor layer: registry/spec parsing, the two
+SDMConfig spellings of a wire format, roundtrip unbiasedness,
+pad-to-max-k heterogeneous payloads, exact wire-bit accounting,
+accountant wiring, and the compressed push-sum invariants."""
+import dataclasses
+import os
+import pathlib
+import subprocess
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -54,6 +61,56 @@ def test_sdm_config_selects_compressor_by_name():
     c2 = sdm_dsgd.compressor_of(
         sdm_dsgd.SDMConfig(mode="fixedk_packed", pack_block=8))
     assert c1 == c2
+
+
+SPELLING_HELPER = (pathlib.Path(__file__).parent / "helpers"
+                   / "spelling_check.py")
+
+
+@pytest.fixture(scope="module")
+def spelling_jaxprs() -> dict:
+    """{spec: (legacy jaxpr hash, spec jaxpr hash)} of one 4-node step."""
+    out = subprocess.run(
+        [sys.executable, str(SPELLING_HELPER)], capture_output=True,
+        text=True, timeout=600,
+        env=dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=str(
+            pathlib.Path(__file__).parent.parent / "src")))
+    assert out.returncode == 0, out.stderr[-3000:]
+    return {toks[1]: (toks[2], toks[3]) for toks in
+            (ln.split() for ln in out.stdout.splitlines())
+            if toks and toks[0] == "SPELLING"}
+
+
+@pytest.mark.parametrize("spec,legacy", [
+    pytest.param(spec, legacy, id=spec) for spec, legacy in [
+        ("bernoulli", {"mode": "bernoulli"}),
+        ("fixedk", {"mode": "fixedk_packed"}),
+        ("block:128", {"mode": "fixedk_packed", "pack_block": 128}),
+        ("rows", {"mode": "fixedk_rows"}),
+        ("qsgd:8", {"mode": "qsgd"})]])
+def test_mode_and_compressor_spellings_resolve_alike(spec, legacy,
+                                                     spelling_jaxprs):
+    """A config resolves its Compressor once, from either spelling, to
+    the same object; == and hash still read only the declared fields;
+    and a 4-node step traces to the same jaxpr under both spellings."""
+    kw = dict(p=0.25, theta=0.5, gamma=0.01, sigma=0.5, clip_c=1.0)
+    old = sdm_dsgd.SDMConfig(**legacy, **kw)
+    new = sdm_dsgd.SDMConfig(compressor=spec, **kw)
+    assert sdm_dsgd.compressor_of(old) == sdm_dsgd.compressor_of(new) \
+        == compressor.make(spec, p=0.25)
+    assert sdm_dsgd.compressor_of(new) is new.wire   # built once
+    for cfg in (old, new):
+        # the hash of the fields a caller passes, as before the config
+        # kept its resolved Compressor
+        fields = tuple(getattr(cfg, f.name)
+                       for f in dataclasses.fields(cfg) if f.init)
+        assert hash(cfg) == hash(fields)
+        assert cfg == dataclasses.replace(cfg)
+    # the spec spelling, its spec dropped, is the legacy config
+    bare = dataclasses.replace(new, compressor=None)
+    assert bare == old and hash(bare) == hash(old)
+    legacy_jaxpr, spec_jaxpr = spelling_jaxprs[spec]
+    assert legacy_jaxpr == spec_jaxpr
 
 
 # ---------------------------------------------------------------------------

@@ -186,7 +186,7 @@ def test_flash_property_sweep(seed, sq, skv, causal):
 
 
 # --------------------------------------------------------------------------
-# wire_compress: fused quantize+pack / gather+pack vs oracles
+# wire_compress: fused quantize+pack vs oracles
 # --------------------------------------------------------------------------
 
 from repro.core.compressor import FusedQSGDCompressor, QSGDCompressor  # noqa: E402
@@ -280,31 +280,6 @@ def test_fused_compressor_wire_bits_inherited():
 def test_fused_compressor_rejects_odd_bits():
     with pytest.raises(ValueError):
         FusedQSGDCompressor(p=1.0, bits=3)
-
-
-@pytest.mark.parametrize("kb,scale", [(4, 2.5), (16, 1.0)])
-def test_fixedk_gather_pack_kernel_matches_ref(kb, scale):
-    rng = np.random.default_rng(kb)
-    db = jnp.asarray(rng.normal(size=(64, LANE)), jnp.float32)
-    idx = jnp.asarray(rng.choice(64, size=kb, replace=False), jnp.int32)
-    out_k = wire_compress.fixedk_gather_pack(db, idx, scale=scale,
-                                             use_kernel=True)
-    out_r = wire_compress.fixedk_gather_pack(db, idx, scale=scale,
-                                             use_kernel=False)
-    np.testing.assert_array_equal(np.asarray(out_k), np.asarray(out_r))
-
-
-@pytest.mark.parametrize("block,dtype,p,fused", [
-    (LANE, jnp.float32, 0.2, True),          # block:128, one plane row
-    (4 * LANE, jnp.float32, 0.2, True),
-    (1, jnp.float32, 0.2, False),            # element-granular fixedk
-    (64, jnp.float32, 0.2, False),
-    (LANE, jnp.bfloat16, 0.2, False),
-    (LANE, jnp.float32, (0.2, 0.5), False),  # het-p: traced scale mask
-])
-def test_fused_pack_applies(block, dtype, p, fused):
-    from repro.core import gossip
-    assert gossip.fused_pack_applies(block, dtype, p) is fused
 
 
 @given(seed=st.integers(0, 10_000), bits=st.sampled_from([2, 4, 8]),

@@ -12,6 +12,8 @@ index lists: counted masks compacted to ascending order
   index lists by compaction, with no ``top_k`` and no sort.
 * On one device: the step holds no sort, and no gather or scatter of
   the plane, and the launcher's banner says so.
+* The sender's payload is its ascending keep list's rows times the
+  scale, bit for bit, at every block width, dtype and budget.
 """
 import contextlib
 import io
@@ -21,7 +23,9 @@ import re
 import subprocess
 import sys
 
+import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro import configs
@@ -87,7 +91,6 @@ def test_four_node_step_keeps_wire_topk_draws(four_nodes):
     draws = four_nodes["DRAWS"]
     assert draws["own_mask"] >= 1, draws
     assert draws["compact"] == 3 * draws["own_mask"], draws
-    assert draws["top_k"] == 0, draws
     assert draws["SORT"] == 0, draws
 
 
@@ -100,11 +103,58 @@ def test_one_node_own_sdm_needs_no_wire_draw():
         one, d, axis_name="data", base_key=jnp.zeros(2, jnp.uint32),
         step=jnp.int32(0), p=0.3, node_index=jnp.int32(0))
     after = gossip.draw_counts()
-    assert after["top_k"] == before["top_k"]
     assert after["compact"] == before["compact"]
     assert after["own_mask"] == before["own_mask"] + 1
     assert int((own != 0).sum()) == sparsifier.num_kept(1000, 0.3)
     assert not nb_sum.any()
+
+
+# (block, dtype, p, plane rows of 128 coordinates): block widths on and
+# off the lane, a bf16 plane and a per-node budget; then kept rows 4 and
+# 16 of 64, scales 16 and 4
+PACK_CASES = [
+    pytest.param(128, jnp.float32, 0.2, 40, id="block128"),
+    pytest.param(512, jnp.float32, 0.2, 40, id="block512"),
+    pytest.param(1, jnp.float32, 0.2, 40, id="block1"),
+    pytest.param(64, jnp.float32, 0.2, 40, id="block64"),
+    pytest.param(128, jnp.bfloat16, 0.2, 40, id="bf16"),
+    pytest.param(128, jnp.float32, (0.2, 0.5), 40, id="per_node_p"),
+    pytest.param(128, jnp.float32, 0.0625, 64, id="kb4_scale16"),
+    pytest.param(128, jnp.float32, 0.25, 64, id="kb16_scale4"),
+]
+
+
+@pytest.mark.parametrize("block,dtype,p,rows", PACK_CASES)
+def test_sender_payload_is_its_keep_list_gathered(block, dtype, p, rows):
+    """``gossip._packed_selection``'s payload equals a NumPy gather of
+    the ascending keep list times the scale, bit for bit. With per-node
+    p (node 0 keeps 0.2 of a payload padded to 0.5) the rows past the
+    node's own budget are zero."""
+    plane = jax.random.normal(jax.random.PRNGKey(block), (rows, 128))
+    db = sparsifier.block_view(plane.astype(dtype).reshape(-1), block)
+    nb = db.shape[0]
+    kb, kb_me = gossip._kept(nb, p, 0)
+    keys = sparsifier.score_keys(jax.random.PRNGKey(7), nb)
+    keep = sparsifier.topk_mask(keys, kb_me, sparsifier.SCORE_BITS)
+    got = gossip._packed_selection(db, keys, keep, kb, kb_me, p)
+    assert got.shape == (kb, block) and got.dtype == dtype
+
+    # lax.top_k's ranking: larger keys first, ties to the lower index
+    order = np.argsort(-np.asarray(keys), kind="stable")
+    sent = np.sort(order[:kb])
+    rows_sent = np.asarray(db.astype(jnp.float32))[sent]
+    if isinstance(p, tuple):
+        own = np.isin(sent, order[:int(kb_me)])
+        assert 0 < own.sum() < kb
+        scale = np.float32(nb) / np.float32(int(kb_me))
+        want = np.where(own[:, None], rows_sent * scale, np.float32(0))
+        assert not np.asarray(got)[~own].any()
+    else:
+        # the scale as the payload's dtype holds it
+        scale = np.float32(jnp.asarray(nb / kb, dtype))
+        want = rows_sent * scale
+    want = np.asarray(jnp.asarray(want).astype(dtype).astype(jnp.float32))
+    np.testing.assert_array_equal(np.asarray(got.astype(jnp.float32)), want)
 
 
 @pytest.fixture(scope="module")
@@ -143,6 +193,5 @@ def test_one_node_step_has_no_sort_gather_or_scatter_of_plane(one_node_run):
 def test_banner_reports_own_side_and_topk_draws(one_node_run):
     _, out = one_node_run
     [banner] = [ln for ln in out.splitlines() if ln.startswith("arch=")]
-    assert ("fixedk_pack=xla-gather own_sdm=mask-select topk_draws=0"
-            " compact_draws=0") in banner, banner
+    assert "own_sdm=mask-select compact_draws=0" in banner, banner
     assert "gossip_rounds=0" in banner

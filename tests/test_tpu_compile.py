@@ -4,8 +4,9 @@ Compiled with ``interpret=False`` for a described (not attached) v5e
 chip: what Mosaic refuses here — an illegal block shape, an unsupported
 layout cast — would fail the chip run. Shapes are phi3-medium-14b's: a
 slice of its wire plane, and its KV heads (10 x 128, group 4) in 16-token
-pages. All compiles stay in this one file and in fixtures, because only
-one process may load the TPU compiler at a time.
+pages. The fixed-k sender pack compiles there too, as XLA's gather with
+no kernel. All compiles stay in this one file and in fixtures, because
+only one process may load the TPU compiler at a time.
 """
 import os
 
@@ -14,9 +15,9 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import gossip
 from repro.kernels.flash_attn.decode import paged_flash_decode_pallas
-from repro.kernels.wire_compress import (fixedk_gather_pack_pallas,
-                                         qsgd_pack_pallas)
+from repro.kernels.wire_compress import qsgd_pack_pallas
 
 PLANE_ROWS = 65_537          # odd: exercises the sub-byte row padding
 KV_HEADS, GROUP, HEAD_DIM, PAGE = 10, 4, 128, 16
@@ -59,19 +60,23 @@ def test_qsgd_pack_compiles_for_v5e(one_chip, bits):
     assert "tpu_custom_call" in text
 
 
-# 13,108 kept rows: several chunks and a partial last one; 565: one
-# block of fewer rows than a chunk, not a multiple of 8 (the kept count
-# of the phi3 smoke plane)
+# 13,108 kept rows (p = 0.2 of the plane) and 565, not a multiple of 8
 @pytest.mark.parametrize("kept", [13_108, 565])
-def test_fixedk_gather_pack_compiles_for_v5e(one_chip, kept):
-    plane = jax.ShapeDtypeStruct((PLANE_ROWS, 128), jnp.float32,
-                                 sharding=one_chip)
-    idx = jax.ShapeDtypeStruct((kept,), jnp.int32, sharding=one_chip)
+def test_fixedk_pack_compiles_for_v5e_without_kernel(one_chip, kept,
+                                                     monkeypatch):
+    """The sender's fixed-k pack of lane-dense rows (block:128) is XLA's
+    gather on the chip: no Pallas kernel. The backend reads as a TPU
+    here, so a kernel left to ``resolve_interpret`` would compile as one
+    instead of being interpreted."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
     text = _compiled_text(
-        lambda d, i: fixedk_gather_pack_pallas(d, i, scale=5.0,
-                                               interpret=False),
-        plane, idx)
-    assert "tpu_custom_call" in text
+        lambda db, keys, keep: gossip._packed_selection(
+            db, keys, keep, kept, kept, kept / PLANE_ROWS),
+        s((PLANE_ROWS, 128), jnp.float32), s((PLANE_ROWS,), jnp.int32),
+        s((PLANE_ROWS,), jnp.bool_))
+    assert "tpu_custom_call" not in text
+    assert " gather(" in text
 
 
 def test_paged_flash_decode_compiles_for_v5e(one_chip):
