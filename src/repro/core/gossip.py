@@ -734,36 +734,35 @@ def _batched_sender_indices(schedule: PermuteSchedule, me, *,
 
     Every shift round of a step exchanges the same leaf, so the draw is
     batched over the R senders: their (R, nb) score keys, each row's top
-    kb set ranked by counting (``sparsifier.topk_mask``, vmapped: the
-    search is traced once) and compacted to ascending order
-    (``sparsifier.mask_indices``). Vmapped PRNG draws equal the scalar
-    ones, so row i is bit for bit the list the shift-s sender of round i
-    packed its payload by (``_packed_selection``). Returns (n_rounds, kb)
-    indices.
+    kb set ranked by counting (``sparsifier.topk_mask`` over the rows:
+    the search is traced once, and its fallback stays one ``cond`` for
+    all rows) and compacted to ascending order
+    (``sparsifier.mask_indices``, vmapped). Vmapped PRNG draws equal the
+    scalar ones, so row i is bit for bit the list the shift-s sender of
+    round i packed its payload by (``_packed_selection``). Returns
+    (n_rounds, kb) indices.
     """
     n = schedule.n_nodes
     shifts = jnp.asarray([rnd.shift for rnd in schedule.rounds], jnp.int32)
     senders = jnp.mod(me - shifts, n)
-    keys = jax.vmap(lambda j: node_round_key(base_key, j, step))(senders)
-
-    def sender_list(key):
-        keep = sparsifier.topk_mask(sparsifier.score_keys(key, nb), kb,
-                                    sparsifier.SCORE_BITS)
-        return sparsifier.mask_indices(keep, kb)
-
+    keys = jax.vmap(lambda j: sparsifier.score_keys(
+        node_round_key(base_key, j, step), nb))(senders)
+    keep = sparsifier.topk_mask(keys, kb, sparsifier.SCORE_BITS)
     _DRAWS["compact"] += len(schedule.rounds)
-    return jax.vmap(sender_list)(keys)
+    return jax.vmap(lambda row: sparsifier.mask_indices(row, kb))(keep)
 
 
 def draw_counts() -> dict:
     """The fixed-k keep-set draws traced so far in this process:
-    ``{"own_mask": ..., "compact": ...}`` (see ``_DRAWS``).
-    Counted when a step is traced, per draw site, so a reading taken
-    before and after lowering a step says what that step holds: 0
-    ``compact`` on a schedule with no gossip rounds. The dict is live;
-    copy it to keep a reading.
+    ``{"own_mask": ..., "compact": ..., "bracket": ...}`` (see
+    ``_DRAWS``; ``bracket`` counts the threshold searches
+    ``sparsifier.topk_mask`` started from a window, each with its one
+    fallback ``cond``). Counted when a step is traced, per draw site, so
+    a reading taken before and after lowering a step says what that step
+    holds: 0 ``compact`` on a schedule with no gossip rounds. Each call
+    returns a new dict.
     """
-    return _DRAWS
+    return {**_DRAWS, "bracket": sparsifier._SEARCHES["bracket"]}
 
 
 def _kept(nb_blocks: int, p, me) -> Tuple[int, "int | jax.Array"]:

@@ -42,6 +42,7 @@ __all__ = [
     "fixedk_mask",
     "score_keys",
     "topk_mask",
+    "search_window",
     "mask_indices",
     "fixedk_pack",
     "fixedk_unpack",
@@ -100,10 +101,24 @@ def fixedk_indices(key: jax.Array, d: int, k: int) -> jax.Array:
 # (bits >> 9) / 2**23, exactly: the scores' order is that of the 23-bit
 # integer keys bits >> 9.
 SCORE_BITS = 23
+# ways of each counting pass of ``topk_mask``'s threshold search, as a
+# power of two: a pass counts the keys at or above 2**SEARCH_BITS - 1
+# thresholds. On a TPU v5e a pass of 7 thresholds over 475 M keys takes
+# 3.3 ms, as long as one compare and sum, and a pass of 15 takes 4.4 ms,
+# so ``topk_mask`` over those keys at k = d/5 costs 19.3 ms at 8 ways
+# (4 passes from ``search_window``) and 21.0 ms at 16 (3 passes).
+SEARCH_BITS = 3
+# half-width of ``topk_mask``'s first window, in standard deviations of
+# the k-th largest of d i.i.d. uniform keys
+WINDOW_SIGMAS = 12
 # keys per block of the tie count in ``topk_mask``
 TIE_BLOCK = 1024
 # entries per row of the in-row ranks in ``mask_indices``
 RANK_BLOCK = 128
+# Trace-time count of the threshold searches ``topk_mask`` built from a
+# window narrower than the keys' range, each with one fallback ``cond``;
+# ``gossip.draw_counts`` reports it as ``bracket``.
+_SEARCHES = {"bracket": 0}
 
 
 @jax.named_scope("sdm_draw")
@@ -124,54 +139,166 @@ def fixedk_mask(key: jax.Array, d: int, k) -> jax.Array:
     """(d,) bool mask of exactly the set ``fixedk_indices(key, d, k)`` holds.
 
     The same scores ranked by counting instead of sorting (see
-    ``topk_mask``); ``k`` may be a traced int32 scalar.
+    ``topk_mask``): the keys are i.i.d. uniform, so the threshold search
+    starts from a window about their (1 - k/d) quantile, ±12σ wide, and
+    falls back to the whole key range only if the window misses. ``k``
+    may be a traced int32 scalar.
     """
     return topk_mask(score_keys(key, d), k, SCORE_BITS)
+
+
+def search_window(d: int, k, bits: int) -> Tuple[jax.Array, int]:
+    """(lo, width): the window [lo, lo + width) of key values in which
+    ``topk_mask`` first looks for the k-th largest of d keys in
+    [0, 2**bits).
+
+    For i.i.d. uniform keys that threshold lies near the (1 - k/d)
+    quantile 2**bits (1 - k/d): the count of keys at or above a value
+    has standard deviation σ = sqrt(k (d - k) / d) keys there, which is
+    σ 2**bits / d key values. ``width`` is the smallest power of the
+    search's fan-out (``2**SEARCH_BITS``) that covers ±``WINDOW_SIGMAS``
+    σ, centred on the quantile and clamped into the range; a traced
+    ``k`` takes the largest σ, at k = d/2, and a traced ``lo``. Where
+    the window would cover the range, it is the range: (0, 2**bits).
+    """
+    full = 1 << bits
+    var = k * (d - k) / d if isinstance(k, int) else d / 4
+    half = WINDOW_SIGMAS * math.sqrt(var) * full / d
+    width = 1 << SEARCH_BITS
+    while width < 2 * half:
+        width <<= SEARCH_BITS
+    if width >= full:
+        return jnp.int32(0), full
+    centre = (d - jnp.asarray(k, jnp.int32)).astype(jnp.float32) * (full / d)
+    lo = jnp.clip(jnp.round(centre - width / 2), 0, full - width)
+    return lo.astype(jnp.int32), width
+
+
+def _count(pred: jax.Array) -> jax.Array:
+    return jnp.sum(pred, dtype=jnp.int32)
+
+
+def _narrow(m: jax.Array, k: jax.Array, lo: jax.Array, n_hi: jax.Array,
+            width_bits: int) -> Tuple[jax.Array, jax.Array]:
+    """(t, count(m > t)) for the k-th largest key t of (d,) ``m``, given
+    count(m >= lo) >= k > count(m >= lo + 2**width_bits) = n_hi: passes
+    of up to 2**SEARCH_BITS ways, each one pass over ``m`` that counts
+    the keys at or above the thresholds inside the interval and keeps
+    the part that holds t. A pass compares m - lo with constant
+    thresholds, not m with traced ones: on the TPU each traced
+    threshold costs the pass a broadcast.
+    """
+    while width_bits:
+        ways_bits = min(SEARCH_BITS, width_bits)
+        ways = 1 << ways_bits
+        width_bits -= ways_bits
+        step = 1 << width_bits
+        rel = m - lo
+        at_or_above = jnp.stack([_count(rel >= j * step)
+                                 for j in range(1, ways)])
+        j = _count(at_or_above >= k)      # thresholds that keep >= k keys
+        n_hi = jnp.where(j < ways - 1,
+                         at_or_above[jnp.minimum(j, ways - 2)], n_hi)
+        lo = lo + j * step
+    return lo, n_hi
+
+
+def _bracket(m: jax.Array, k: jax.Array, lo: jax.Array, width: int
+             ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """``_narrow`` over the window [lo, lo + width) of (d,) ``m``, and
+    whether the window holds t. Its first pass also counts the keys at
+    or above the window's two ends: 2**SEARCH_BITS + 1 thresholds. The
+    window holds t iff count(m >= lo) >= k > count(m >= lo + width);
+    where it does not, t and the count are meaningless.
+    """
+    ways = 1 << SEARCH_BITS
+    step = width >> SEARCH_BITS
+    rel = m - lo
+    at_or_above = jnp.stack([_count(rel >= j * step)
+                             for j in range(ways + 1)])
+    held = (at_or_above[0] >= k) & (at_or_above[ways] < k)
+    j = _count(at_or_above[1:ways] >= k)
+    t, n_hi = _narrow(m, k, lo + j * step, at_or_above[j + 1],
+                      (width // ways).bit_length() - 1)
+    return t, n_hi, held
+
+
+def _cut_ties(m: jax.Array, k: jax.Array, t: jax.Array, n_hi: jax.Array
+              ) -> jax.Array:
+    """The top-k mask of (d,) ``m`` from its threshold t and n_hi =
+    count(m > t): every key above t, and the r = k - n_hi >= 1
+    lowest-index keys equal to t. The index cut from one pass that
+    counts the ties in each block of ``TIE_BLOCK`` keys, then a running
+    count within the one block that holds the r-th tie.
+    """
+    d = m.shape[0]
+    r = k - n_hi
+    blocks = -(-d // TIE_BLOCK)
+    tie = jnp.pad(m == t, (0, blocks * TIE_BLOCK - d)).reshape(blocks, -1)
+    ties_through = jnp.cumsum(jnp.sum(tie, axis=1, dtype=jnp.int32))
+    b = _count(ties_through < r)          # the block that holds the r-th tie
+    before = jnp.where(b > 0, ties_through[jnp.maximum(b - 1, 0)], 0)
+    row = jax.lax.dynamic_index_in_dim(tie, b, keepdims=False)
+    c = b * TIE_BLOCK + _count(
+        before + jnp.cumsum(row, dtype=jnp.int32) < r) + 1
+    iota = jax.lax.iota(jnp.int32, d)
+    return (m > t) | ((m == t) & (iota < c))
+
+
+def _over_rows(batch_dims: int, f):
+    """``f`` of a (d,) row mapped over ``batch_dims`` leading axes."""
+    for _ in range(batch_dims):
+        f = jax.vmap(f)
+    return f
 
 
 @jax.named_scope("sdm_draw")
 def topk_mask(m: jax.Array, k, bits: int) -> jax.Array:
     """Mask of the set ``lax.top_k(m, k)`` selects, without a sort.
 
-    ``m`` holds (d,) int32 keys in [0, 2**bits). ``top_k`` keeps every key
+    ``m`` holds (..., d) int32 keys in [0, 2**bits), ranked along the
+    last axis with one ``k`` for every row. ``top_k`` keeps every key
     above the k-th largest key t, and of the keys equal to t the r
     lowest-index ones, r = k - count(m > t) (ties go to the lower index).
-    t is found by a 16-way search, each step one pass over ``m`` that
-    counts the keys at or above 15 thresholds (ceil(bits / 4) passes);
-    the index cut by one pass that counts the ties in each block of
-    ``TIE_BLOCK`` keys, then a running count within the one block that
-    holds the r-th tie. No sort, no full-length cumsum.
+
+    t is found by a 2**SEARCH_BITS-way search, each step one pass over
+    ``m`` that counts the keys at or above the thresholds inside the
+    interval that holds t. The search starts from ``search_window``: for
+    the i.i.d. uniform keys of ``score_keys`` a window of ±12σ about
+    their (1 - k/d) quantile, so 4 passes of 8 ways instead of the whole
+    range's 8 for 475 M 23-bit keys at k = d/5. The window's first pass also checks that it holds
+    t. If it misses in any row, a ``lax.cond`` (span
+    ``sdm_draw_fallback``) searches the whole range [0, 2**bits) for
+    every row, so the mask is ``top_k``'s set for any keys; the
+    predicate is one scalar over the rows, so the cond stays a cond
+    under a batch. Where the window is the whole range (small d), the
+    search is that range's, with no cond. The index cut by one pass that
+    counts the ties in each block of ``TIE_BLOCK`` keys, then a running
+    count within the one block that holds the r-th tie. No sort, no
+    full-length cumsum.
     """
-    d = m.shape[0]
+    lo, width = search_window(m.shape[-1], k, bits)
     k = jnp.asarray(k, jnp.int32)
+    rows = functools.partial(_over_rows, m.ndim - 1)
 
-    def count(pred):
-        return jnp.sum(pred, dtype=jnp.int32)
+    def whole_range(m):
+        return _narrow(m, k, jnp.int32(0), jnp.int32(0), bits)
 
-    # t: count(m >= lo) >= k > count(m >= lo + width) = n_hi
-    lo, n_hi, width_bits = jnp.int32(0), jnp.int32(0), bits
-    while width_bits:
-        ways = 1 << min(4, width_bits)
-        width_bits -= min(4, width_bits)
-        step = 1 << width_bits
-        at_or_above = jnp.stack([count(m >= lo + j * step)
-                                 for j in range(1, ways)])
-        j = count(at_or_above >= k)       # thresholds that keep >= k keys
-        n_hi = jnp.where(j < ways - 1,
-                         at_or_above[jnp.minimum(j, ways - 2)], n_hi)
-        lo = lo + j * step
-    t, r = lo, k - n_hi          # r >= 1: the ties at t that top_k keeps
-    # c: the index just past the r-th tie
-    blocks = -(-d // TIE_BLOCK)
-    tie = jnp.pad(m == t, (0, blocks * TIE_BLOCK - d)).reshape(blocks, -1)
-    ties_through = jnp.cumsum(jnp.sum(tie, axis=1, dtype=jnp.int32))
-    b = count(ties_through < r)          # the block that holds the r-th tie
-    before = jnp.where(b > 0, ties_through[jnp.maximum(b - 1, 0)], 0)
-    row = jax.lax.dynamic_index_in_dim(tie, b, keepdims=False)
-    c = b * TIE_BLOCK + count(
-        before + jnp.cumsum(row, dtype=jnp.int32) < r) + 1
-    iota = jax.lax.iota(jnp.int32, d)
-    return (m > t) | ((m == t) & (iota < c))
+    if width == 1 << bits:
+        t, n_hi = rows(whole_range)(m)
+    else:
+        _SEARCHES["bracket"] += 1
+        t, n_hi, held = rows(lambda m: _bracket(m, k, lo, width))(m)
+
+        @jax.named_scope("sdm_draw_fallback")
+        def fallback():
+            return rows(whole_range)(m)
+
+        # the barrier keeps XLA from sinking the users of t into the
+        # branches: it would broadcast t over every key inside each one
+        t, n_hi = jax.lax.optimization_barrier(
+            jax.lax.cond(jnp.all(held), lambda: (t, n_hi), fallback))
+    return rows(lambda m, t, n_hi: _cut_ties(m, k, t, n_hi))(m, t, n_hi)
 
 
 @jax.named_scope("sdm_draw")

@@ -10,7 +10,7 @@ Run with 4 fake host devices; prints one line per case
 
     CASE <id> EQUAL <0|1> NONZERO <i> NB_EQUAL <0|1> NB_NONZERO <i>
     RANDOM <id> <i>
-    DRAWS own_mask <i> compact <i> SORT <i>
+    DRAWS own_mask <i> compact <i> bracket <i> SORT <i> COND <i>
 
 that tests/test_own_sdm_select.py asserts on. Must set XLA_FLAGS before
 the jax import.
@@ -45,6 +45,10 @@ CASES = {
     "union_b1": ("union", (1000,), jnp.float32, 0.3, 1),
     "union_hetp_b128": ("union", (128 * 9 + 17,), jnp.float32, HET_P, 128),
     "union_rows": ("union_rows", (37, 24), jnp.float32, 0.3, None),
+    # planes wide enough that the keep-set search starts from a window
+    # (``sparsifier.search_window``) instead of the whole key range
+    "window_b1": ("static", (50000,), jnp.float32, 0.3, 1),
+    "window_union_hetp_b1": ("union", (50000,), jnp.float32, HET_P, 1),
 }
 
 
@@ -153,9 +157,10 @@ def random_draws(mesh, name):
 
 
 def step_draws():
-    """Draws the 4-node fixed-k train step holds, and its sort and top_k
-    ops: one own mask, one compacted payload list and one compacted list
-    per sender a plane."""
+    """Draws the 4-node fixed-k train step holds, its sort and top_k ops
+    and its conditionals: one own mask, one compacted payload list and
+    one compacted list per sender a plane; one conditional per search
+    started from a window (the receivers' batched search included)."""
     from repro import configs
     from repro.core.sdm_dsgd import SDMConfig
     from repro.train import steps
@@ -175,8 +180,9 @@ def step_draws():
     # XLA's CPU backend lowers top_k to a TopK custom call
     sorts = (hlo_analysis.instruction_counts(hlo).get("sort", 0)
              + hlo.count('custom_call_target="TopK"'))
+    conds = hlo_analysis.instruction_counts(hlo).get("conditional", 0)
     print("DRAWS " + " ".join(f"{k} {after[k] - before[k]}" for k in after)
-          + f" SORT {sorts}", flush=True)
+          + f" SORT {sorts} COND {conds}", flush=True)
 
 
 def main():

@@ -186,6 +186,97 @@ def test_topk_mask_tie_heavy_keys(d):
             _topk_set(jax.lax.top_k(m, k)[1], d), err_msg=f"k={k}")
 
 
+def _top_k_of(m, k):
+    return _topk_set(jax.lax.top_k(m, k)[1], m.shape[-1])
+
+
+def _window_holds(m, k, bits):
+    """Whether ``search_window``'s window holds the k-th largest key:
+    count(m >= lo) >= k > count(m >= lo + width)."""
+    lo, width = sparsifier.search_window(m.shape[-1], k, bits)
+    m = np.asarray(m)
+    return (m >= int(lo)).sum() >= k > (m >= int(lo) + width).sum()
+
+
+@pytest.mark.parametrize("d,k", [(65539, 13108), (100000, 1),
+                                 (100000, 20000), (100000, 50000),
+                                 (250007, 99999), (250007, 250006)])
+def test_topk_mask_windowed_uniform_keys(d, k):
+    """Uniform 23-bit keys: the window is narrower than the key range
+    and holds the threshold, and the mask is lax.top_k's set, for a
+    static and for a traced k."""
+    assert sparsifier.search_window(d, k, 23)[1] < 1 << 23
+    draw = jax.jit(lambda m, k: sparsifier.topk_mask(m, k, 23))
+    for seed in range(2):
+        m = sparsifier.score_keys(jax.random.PRNGKey(seed), d)
+        assert _window_holds(m, k, 23)
+        want = _top_k_of(m, k)
+        np.testing.assert_array_equal(
+            np.asarray(sparsifier.topk_mask(m, k, 23)), want)
+        np.testing.assert_array_equal(np.asarray(draw(m, jnp.int32(k))), want)
+
+
+def _missing_keys(d):
+    """23-bit keys that miss the window: bunched far below the expected
+    quantile, bunched at the top, all equal, and tie-heavy (8 values
+    1024 apart)."""
+    rng = np.random.default_rng(d)
+    return {"low": rng.integers(0, 1000, d),
+            "top": rng.integers((1 << 23) - 100, 1 << 23, d),
+            "equal": np.full(d, 5),
+            "ties": rng.integers(0, 8, d) << 10}
+
+
+@pytest.mark.parametrize("name", ["low", "top", "equal", "ties"])
+def test_topk_mask_fallback_when_window_misses(name):
+    """Keys the window misses for most k: the whole-range fallback still
+    gives lax.top_k's set, ties at the threshold to the lowest indices."""
+    d = 100000
+    m = jnp.asarray(_missing_keys(d)[name], jnp.int32)
+    ks = (1, 20000, 50000, d - 1, d)
+    assert sum(not _window_holds(m, k, 23) for k in ks) >= 2
+    for k in ks:
+        np.testing.assert_array_equal(
+            np.asarray(sparsifier.topk_mask(m, k, 23)), _top_k_of(m, k),
+            err_msg=f"k={k}")
+
+
+def test_topk_mask_batched_rows():
+    """(R, d) keys: each row ranked as alone, with one row that misses
+    the window, so the fallback runs for all, and with none."""
+    d, k = 100000, 20000
+    rows = jnp.stack([sparsifier.score_keys(jax.random.PRNGKey(s), d)
+                      for s in range(3)])
+    draw = jax.jit(lambda m: sparsifier.topk_mask(m, k, 23))
+    missed = rows.at[1].set(jnp.asarray(_missing_keys(d)["low"], jnp.int32))
+    for keys in (rows, missed):
+        got = np.asarray(draw(keys))
+        for i in range(3):
+            np.testing.assert_array_equal(got[i], _top_k_of(keys[i], k))
+    assert not _window_holds(missed[1], k, 23)
+    hlo = draw.lower(rows).compile().as_text()
+    assert hlo.count(" conditional(") == 1
+
+
+def test_search_window_of_randk():
+    """The chatglm3 random-k draw: d = 475,034,624 keys, k = d/5. The
+    window is 8**4 key values, 4 passes of 8 ways (the whole range
+    takes 8), and holds the (1 - k/d) quantile with at least 12 σ on
+    each side."""
+    d, k = 475_034_624, 95_006_925
+    lo, width = sparsifier.search_window(d, k, 23)
+    lo = int(lo)
+    ways = 1 << sparsifier.SEARCH_BITS
+    assert (ways, width) == (8, 8 ** 4)
+    quantile = 2 ** 23 * (1 - k / d)
+    sigma = (k * (d - k) / d) ** 0.5 * 2 ** 23 / d
+    assert lo + 12 * sigma <= quantile <= lo + width - 12 * sigma
+    # a traced k takes the widest σ (k = d/2): one pass more
+    assert sparsifier.search_window(d, jnp.int32(k), 23)[1] == 8 ** 5
+    # where the window would cover the keys' range, it is the range
+    assert sparsifier.search_window(5003, 1667, 3) == (0, 8)
+
+
 @pytest.mark.parametrize("d,k", [(1, 1), (127, 1), (333, 83), (4097, 819)])
 def test_mask_indices_is_sorted_fixedk_indices(d, k):
     """The wire's index list, a counted mask compacted, is fixedk_indices'

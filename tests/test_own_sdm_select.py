@@ -37,7 +37,8 @@ SRC = str(pathlib.Path(__file__).parent.parent / "src")
 
 OWN_CASES = ["packed_b1", "packed_b1_bf16", "packed_b128", "rows",
              "hetp_rows", "hetp_b1", "hetp_b128", "union_b1",
-             "union_hetp_b128", "union_rows"]
+             "union_hetp_b128", "union_rows", "window_b1",
+             "window_union_hetp_b1"]
 
 
 @pytest.fixture(scope="module")
@@ -92,6 +93,38 @@ def test_four_node_step_keeps_wire_topk_draws(four_nodes):
     assert draws["own_mask"] >= 1, draws
     assert draws["compact"] == 3 * draws["own_mask"], draws
     assert draws["SORT"] == 0, draws
+
+
+def test_four_node_step_keeps_one_cond_per_windowed_search(four_nodes):
+    """Each keep-set search the 4-node step starts from a window holds
+    one fallback ``conditional``, the receivers' batched search
+    included: vmapped with a per-row predicate it would lower to both
+    branches under a select, and the step would hold fewer."""
+    draws = four_nodes["DRAWS"]
+    assert draws["bracket"] >= 2, draws
+    assert draws["COND"] == draws["bracket"], draws
+
+
+def test_receivers_batched_draw_keeps_one_conditional():
+    """The receivers' draw over a 4-node ring's senders is one search
+    over their (R, nb) keys, started from a window: one ``conditional``
+    in the compiled draw, and each row the ascending list its sender
+    drew alone."""
+    sched = gossip.ring_schedule(4)
+    nb = 50000
+    kb = sparsifier.num_kept(nb, 0.3)
+    base, step, me = jax.random.PRNGKey(7), jnp.int32(3), 1
+    draw = jax.jit(lambda me: gossip._batched_sender_indices(
+        sched, me, base_key=base, step=step, nb=nb, kb=kb))
+    before = gossip.draw_counts()["bracket"]
+    hlo = draw.lower(jnp.int32(me)).compile().as_text()
+    assert gossip.draw_counts()["bracket"] == before + 1
+    assert hlo_analysis.instruction_counts(hlo).get("conditional") == 1
+    got = np.asarray(draw(jnp.int32(me)))
+    for i, rnd in enumerate(sched.rounds):
+        idx = sparsifier.fixedk_indices(
+            gossip.node_round_key(base, (me - rnd.shift) % 4, step), nb, kb)
+        np.testing.assert_array_equal(got[i], np.sort(np.asarray(idx)))
 
 
 def test_one_node_own_sdm_needs_no_wire_draw():
